@@ -28,9 +28,9 @@ codebase (or its reference lineage), rather than generic style:
         (``id``/``type``/``open``/...), the classic source of confusing
         NameErrors three edits later.
   HZ108 jit-outside-stage-cache   a bare ``jax.jit(`` constructed inside
-        a function body: a fresh jit object per call re-traces (and on
-        remote-compile backends re-COMPILES) the identical program every
-        query/batch.  Compilation on execution paths must go through
+        a function body: a fresh jit object per call re-traces the
+        identical program every query/batch.  Compilation on execution
+        paths must go through
         ``sql.stagecompile.StageCache.get_or_build``; intentional sites
         (the cache itself, one-shot model fits, the per-op bench
         baseline) carry waivers.

@@ -523,13 +523,14 @@ RECOVERY_MAX_STAGE_RETRIES = conf("spark.tpu.recovery.maxStageRetries").doc(
 
 SHUFFLE_ICI_ENABLED = conf("spark.tpu.shuffle.ici.enabled").doc(
     "Two-tier exchange: ship bucketed join columns HBM→HBM over ICI "
-    "(device collective under shard_map; Pallas remote-DMA ring on TPU) "
+    "(lax.all_to_all under shard_map) "
     "between peers the topology probe places in one ICI domain, keeping "
     "the wire-format host shuffle as the cross-pod DCN tier and the "
     "fault-tolerant fallback.  ALL control-plane rounds ({xid}-plan "
     "manifests, adaptive stats, decision traces, recovery agreement) "
-    "stay on the host path regardless; any device-tier failure folds "
-    "the spans back onto the host tier, counted, never partial."
+    "stay on the host path regardless; where the device tier is "
+    "unavailable (no spanning device world, too few devices) the spans "
+    "fold back onto the host tier, counted, never partial."
 ).boolean(False)
 
 SHUFFLE_ICI_MIN_BYTES = conf("spark.tpu.shuffle.ici.minBytes").doc(

@@ -45,7 +45,8 @@ from .columnar import ColumnBatch, ColumnVector
 
 HBM_BUDGET = C.conf("spark.tpu.memory.hbmBudget").doc(
     "Device HBM budget in bytes for execution+storage accounting; 0 = "
-    "discover from device memory_stats (fallback 16 GiB)."
+    "discover from device memory_stats (16 GiB on the CPU backend, which "
+    "reports none)."
 ).int(0)
 
 STORAGE_FRACTION = C.conf("spark.tpu.memory.storageFraction").doc(
@@ -130,13 +131,16 @@ def _device_budget(conf) -> int:
     fixed = conf.get(HBM_BUDGET)
     if fixed:
         return fixed
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
+    import jax
+    dev = jax.local_devices()[0]
+    stats = dev.memory_stats()
+    if stats and stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    if dev.platform != "cpu":
+        raise RuntimeError(
+            f"{dev} reports no memory_stats()['bytes_limit']; set "
+            f"{HBM_BUDGET.key} to its HBM size in bytes")
+    # the CPU backend reports no limit; tests still need a budget
     return 16 << 30
 
 

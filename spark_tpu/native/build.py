@@ -6,6 +6,7 @@ import ctypes
 import hashlib
 import os
 import subprocess
+import sys
 import tempfile
 import threading
 from typing import Optional
@@ -44,7 +45,12 @@ def load_library() -> Optional[ctypes.CDLL]:
             lib = ctypes.CDLL(so)
             _sign(lib)
             _lib = lib
-        except Exception:
+        except Exception as e:
+            # once (``_tried``): callers fall back to their numpy forms
+            detail = getattr(e, "stderr", b"") or b""
+            print(f"spark_tpu.native: library unavailable ({e})"
+                  + (f": {detail.decode(errors='replace')[-500:]}"
+                     if detail else ""), file=sys.stderr)
             _lib = None
         return _lib
 

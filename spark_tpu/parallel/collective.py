@@ -193,6 +193,26 @@ def psum_arrays(arrays: List[Array], axis: str = DATA_AXIS) -> List[Array]:
     return [lax.psum(a, axis) for a in arrays]
 
 
+def _extreme(op: str, x: Array, axis: str) -> Array:
+    # XLA:TPU rewrites 64-bit element types away and implements that
+    # rewrite for SUM all-reduces only ("Supported lowering only of Sum
+    # all reduce", first seen on four v5e chips in PR 23): a 64-bit max/min
+    # rides an all_gather and reduces locally — same value on every shard
+    if np.dtype(x.dtype).itemsize == 8:
+        return getattr(jnp, op)(lax.all_gather(x, axis), axis=0)
+    return (lax.pmax if op == "max" else lax.pmin)(x, axis)
+
+
+def pmax(x: Array, axis: str = DATA_AXIS) -> Array:
+    """``lax.pmax`` that also compiles on a TPU for int64/float64."""
+    return _extreme("max", x, axis)
+
+
+def pmin(x: Array, axis: str = DATA_AXIS) -> Array:
+    """``lax.pmin`` that also compiles on a TPU for int64/float64."""
+    return _extreme("min", x, axis)
+
+
 def sampled_splitters_multi(keys: List[Array], live: Array, n_shards: int,
                             samples_per_shard: int = 64,
                             axis: str = DATA_AXIS) -> List[Array]:
@@ -207,9 +227,11 @@ def sampled_splitters_multi(keys: List[Array], live: Array, n_shards: int,
     C = keys[0].shape[0]
     stride = max(C // samples_per_shard, 1)
     idx = xp.arange(samples_per_shard) * stride % C
-    big = np.int64(np.iinfo(np.int64).max)
     cols = []
     for k in keys:
+        # dead rows sample as the key dtype's maximum (int64 or float64)
+        big = np.float64(np.inf) if str(k.dtype).startswith("float") \
+            else np.int64(np.iinfo(np.int64).max)
         sample = k[idx]
         sample = xp.where(live[idx], sample, big)
         cols.append(lax.all_gather(sample, axis, tiled=True))

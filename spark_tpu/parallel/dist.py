@@ -37,7 +37,7 @@ from ..expressions import Col, EvalContext, Expression, Hash64
 from ..kernels import _scatter_starts, compact, multi_key_argsort, segment_reduce, sort_batch, sort_key_transform
 from ..sql import physical as P
 from ..sql.joins import PJoin
-from .collective import broadcast_all, hash_exchange
+from .collective import broadcast_all, hash_exchange, pmax, pmin
 from .mesh import DATA_AXIS
 
 Array = Any
@@ -230,14 +230,20 @@ class DExchangeRange(DNode):
             _, key = sort_key_transform(xp, v.data, v.valid,
                                         e.data_type(schema), asc, nf)
             if str(key.dtype).startswith("float"):
-                key64 = _float_to_ordered_int(xp, key)
+                # float keys route AS floats: a TPU emulates f64, so there
+                # is no IEEE bit pattern to reinterpret as an ordered int
+                # (XLA:TPU refuses the f64->s64 bitcast).  NaN rides with
+                # +inf into the last bucket — lax.sort's NaN-greatest.
+                key64 = key.astype(np.float64)
+                key64 = xp.where(xp.isnan(key64), np.float64(np.inf), key64)
+                lo, hi = np.float64(-np.inf), np.float64(np.inf)
             else:
                 key64 = key.astype(np.int64)
+                lo = np.int64(np.iinfo(np.int64).min)
+                hi = np.int64(np.iinfo(np.int64).max)
             if v.valid is not None:
                 # nulls route to the extreme bucket on their order side
-                extreme = np.int64(np.iinfo(np.int64).min) if nf \
-                    else np.int64(np.iinfo(np.int64).max)
-                key64 = xp.where(v.valid, key64, extreme)
+                key64 = xp.where(v.valid, key64, lo if nf else hi)
             keys64.append(key64)
         live = batch.row_valid_or_true()
         from .collective import lex_bucket, sampled_splitters_multi
@@ -253,14 +259,6 @@ class DExchangeRange(DNode):
         parts = [f"{e!r} {'ASC' if a else 'DESC'} {'NF' if nf else 'NL'}"
                  for e, a, nf in self.orders]
         return f"ExchangeRange [{', '.join(parts)}] x{self.n_shards} f={self.skew_factor}"
-
-
-def _float_to_ordered_int(xp, f):
-    """Order-preserving float64 → int64 (sign-flip trick, RadixSort.java)."""
-    bits = lax.bitcast_convert_type(f.astype(jnp.float64), jnp.int64) if xp is jnp \
-        else np.asarray(f, np.float64).view(np.int64)
-    mask = xp.where(bits < 0, np.int64(-1), np.int64(np.int64(1) << np.int64(63)))
-    return bits ^ mask
 
 
 class DBroadcast(DNode):
@@ -838,8 +836,7 @@ class DGlobalAggregate(DNode):
                              else (xp.min(s.data) if s.kind == "min" else xp.max(s.data))
                              for s in specs]
             reduced = [lax.psum(r, DATA_AXIS) if s.kind == "sum"
-                       else (lax.pmin(r, DATA_AXIS) if s.kind == "min"
-                             else lax.pmax(r, DATA_AXIS))
+                       else (pmin(r) if s.kind == "min" else pmax(r))
                        for r, s in zip(reduced_local, specs)]
             out = func.finish(xp, [xp.broadcast_to(r, (1,)) for r in reduced])
             dt = func.data_type(batch.schema)

@@ -297,51 +297,7 @@ class DistributedExecution:
 
         fn = self.session._jit_cache.get(key)
         if fn is None:
-            physical = pq.physical
-            mesh = self.mesh
-
-            def shard_fn(leaves):
-                ctx = P.ExecContext(jnp, list(leaves))
-                ctx.shard_offset = lax.axis_index(DATA_AXIS).astype(np.int64) << 48
-                out = physical.run(ctx)
-                out = compact(jnp, out)
-                n_rows = lax.psum(out.num_rows(), DATA_AXIS)
-                # per-kind worst overflow RATIO (lost rows / capacity),
-                # pmax'd over shards — sizes the adaptive retry
-                ex_r = jnp.zeros((), jnp.float32)
-                join_r = jnp.zeros((), jnp.float32)
-                # agg-shrink: absolute NEEDED capacity (lost + bound), 0
-                # when nothing overflowed — growth is a row count, not a
-                # factor
-                shr_need = jnp.zeros((), jnp.int64)
-                for f, kind, cap in zip(ctx.flags, ctx.flag_kinds,
-                                        ctx.flag_caps):
-                    if kind == "shrink":
-                        lost = f.astype(jnp.int64)
-                        shr_need = jnp.maximum(
-                            shr_need,
-                            jnp.where(lost > 0, lost + np.int64(cap),
-                                      np.int64(0)))
-                        continue
-                    r = f.astype(jnp.float32) / np.float32(max(cap, 1))
-                    if kind == "exchange":
-                        ex_r = jnp.maximum(ex_r, r)
-                    else:
-                        join_r = jnp.maximum(join_r, r)
-                ex_r = lax.pmax(ex_r, DATA_AXIS)
-                join_r = lax.pmax(join_r, DATA_AXIS)
-                shr_need = lax.pmax(shr_need, DATA_AXIS)
-                return out, n_rows, ex_r, join_r, shr_need
-
-            wrapped = shard_map(
-                shard_fn, mesh=mesh,
-                in_specs=(PartitionSpec(DATA_AXIS),),
-                out_specs=(PartitionSpec(DATA_AXIS), PartitionSpec(),
-                           PartitionSpec(), PartitionSpec(),
-                           PartitionSpec()),
-                check_vma=False,
-            )
-            fn = jax.jit(wrapped)
+            fn = jax.jit(shard_program(pq.physical, self.mesh))
             self.session._jit_cache[key] = fn
 
         dev_leaves = tuple(self._shard_leaf(b) for b in pq.leaves)
@@ -358,6 +314,52 @@ class DistributedExecution:
 
     def _shard_leaf(self, batch: ColumnBatch) -> ColumnBatch:
         return shard_leaf(self.mesh, self.n, batch)
+
+
+def shard_program(physical, mesh: Mesh):
+    """The ONE ``shard_map`` program a distributed query runs:
+    ``leaves -> (result, n_rows, exchange ratio, join ratio, agg need)``
+    with the three overflow readings reduced over the mesh.  Module-level
+    so a test can compile exactly this for a described mesh."""
+    from .collective import pmax
+
+    def shard_fn(leaves):
+        ctx = P.ExecContext(jnp, list(leaves))
+        ctx.shard_offset = lax.axis_index(DATA_AXIS).astype(np.int64) << 48
+        out = physical.run(ctx)
+        out = compact(jnp, out)
+        n_rows = lax.psum(out.num_rows(), DATA_AXIS)
+        # per-kind worst overflow RATIO (lost rows / capacity),
+        # pmax'd over shards — sizes the adaptive retry
+        ex_r = jnp.zeros((), jnp.float32)
+        join_r = jnp.zeros((), jnp.float32)
+        # agg-shrink: absolute NEEDED capacity (lost + bound), 0
+        # when nothing overflowed — growth is a row count, not a
+        # factor
+        shr_need = jnp.zeros((), jnp.int64)
+        for f, kind, cap in zip(ctx.flags, ctx.flag_kinds,
+                                ctx.flag_caps):
+            if kind == "shrink":
+                lost = f.astype(jnp.int64)
+                shr_need = jnp.maximum(
+                    shr_need,
+                    jnp.where(lost > 0, lost + np.int64(cap),
+                              np.int64(0)))
+                continue
+            r = f.astype(jnp.float32) / np.float32(max(cap, 1))
+            if kind == "exchange":
+                ex_r = jnp.maximum(ex_r, r)
+            else:
+                join_r = jnp.maximum(join_r, r)
+        return (out, n_rows, pmax(ex_r), pmax(join_r), pmax(shr_need))
+
+    return shard_map(
+        shard_fn, mesh=mesh,
+        in_specs=(PartitionSpec(DATA_AXIS),),
+        out_specs=(PartitionSpec(DATA_AXIS), PartitionSpec(),
+                   PartitionSpec(), PartitionSpec(), PartitionSpec()),
+        check_vma=False,
+    )
 
 
 def shard_leaf(mesh: Mesh, n: int, batch: ColumnBatch) -> ColumnBatch:

@@ -24,17 +24,18 @@ that share an ICI fabric.  This module adds the intra-pod tier:
   boundaries intact, so the range lane's presorted runs merge exactly
   as if they had crossed the host path.  The executable is built
   through ``stagecompile.StageCache`` (r11): the exchange fuses into a
-  cached stage program instead of being a fresh-jit host seam.  On TPU
-  the inner collective is a Pallas ``make_async_remote_copy`` direct
-  all-to-all (one remote DMA per peer, ICI-routed); everywhere else it
-  is ``lax.all_to_all`` under ``shard_map`` — the same traceable, so
-  the multi-device CPU mesh exercises the identical pack/exchange/
-  unpack logic in tier-1 and the Pallas kernel is a device
-  specialization, not an untested branch.
-* ``IciUnavailable`` — every device-tier failure (no spanning device
-  world, kernel failure, injected fault) folds the spans back onto the
-  host tier, counted, never partial rows; a peer death mid-copy
-  surfaces at the host barrier and takes the ordinary r12 recovery.
+  cached stage program instead of being a fresh-jit host seam.  The
+  collective is ``lax.all_to_all`` under ``shard_map`` on every
+  platform (XLA routes it over ICI on a TPU slice), so the multi-device
+  CPU mesh exercises the identical pack/exchange/unpack program in
+  tier-1.
+* ``IciUnavailable`` — the device tier cannot serve this exchange (no
+  spanning device world, too few devices, a shape the pack cannot
+  express, an injected fault): the spans fold back onto the host tier,
+  counted, never partial rows.  A lowering, compile or runtime error of
+  the collective itself is NOT unavailability and propagates; a peer
+  death mid-copy surfaces at the host barrier and takes the ordinary
+  r12 recovery.
 
 Control-plane rounds never move here: manifests, adaptive stats,
 decision traces and recovery agreement stay on the host path, so the
@@ -62,9 +63,11 @@ ICI_AXIS = "ici"
 
 class IciUnavailable(RuntimeError):
     """Structured signal: the device tier cannot serve this exchange
-    (no device world spanning the domain, kernel failure, injected
-    fault).  The caller folds the affected spans back into the host
-    routed dict and rides the DCN tier — degradation, not an error."""
+    (no device world spanning the domain, too few devices, a pack shape
+    it cannot express, an injected fault).  The caller folds the
+    affected spans back into the host routed dict and rides the DCN
+    tier — degradation, not an error.  A lowering, compile or runtime
+    failure of the collective is never reported under this name."""
 
 
 # ---------------------------------------------------------------------------
@@ -317,82 +320,14 @@ def _unpack_inbox(names, template: ColumnBatch, cols, masks, rowv,
 # the collective: one all-to-all over the exchange axis
 # ---------------------------------------------------------------------------
 
-def _shard_map():
-    try:                               # top-level export landed post-0.4
-        from jax import shard_map
-        return shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map
-
-
-def _a2a_arrays_traceable(n_m: int, use_pallas: bool):
+def _a2a_step(*planes):
     """The per-device body: all-to-all every packed plane over
     ``ICI_AXIS``.  Each local view is ``(n_m, ...)`` — row d outbound
     to peer slot d — and comes back as row s inbound from peer slot s
-    (``collective.hash_exchange``'s tiled split/concat idiom).  On TPU
-    the data planes move through the Pallas remote-DMA all-to-all; the
-    tiny run-length table always rides ``lax.all_to_all`` (scalar
-    metadata is not worth a DMA kernel's tiling constraints)."""
+    (``collective.hash_exchange``'s tiled split/concat idiom)."""
     from jax import lax
-
-    def a2a(x):
-        return lax.all_to_all(x, ICI_AXIS, split_axis=0, concat_axis=0,
-                              tiled=True)
-
-    def step(*planes):
-        if use_pallas:
-            head = [_pallas_a2a(x, n_m) for x in planes[:-1]]
-            return tuple(head) + (a2a(planes[-1]),)
-        return tuple(a2a(x) for x in planes)
-
-    return step
-
-
-def _pallas_a2a(x, n_m: int):
-    """Direct all-to-all as one Pallas kernel: peer-block d of the
-    local buffer DMAs straight into row ``my_id`` of peer d's output
-    buffer over ICI (``make_async_remote_copy``; multi-hop routing is
-    the fabric's job).  A barrier semaphore fences the buffers against
-    neighboring invocations, then one remote DMA per offset, started
-    and drained symmetrically — every device sends and receives exactly
-    one block per step, so the semaphore counts always match."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def kernel(in_ref, out_ref, send_sem, recv_sem):
-        my_id = lax.axis_index(ICI_AXIS)
-        barrier = pltpu.get_barrier_semaphore()
-        for d in range(n_m):
-            pltpu.semaphore_signal(barrier, device_id=(jnp.int32(d),),
-                                   device_id_type=pltpu.DeviceIdType.LOGICAL)
-        pltpu.semaphore_wait(barrier, n_m)
-        local = pltpu.make_async_copy(in_ref.at[my_id], out_ref.at[my_id],
-                                      recv_sem)
-        local.start()
-        local.wait()
-        for d in range(1, n_m):
-            dst = lax.rem(my_id + d, n_m)
-            rc = pltpu.make_async_remote_copy(
-                src_ref=in_ref.at[dst], dst_ref=out_ref.at[my_id],
-                send_sem=send_sem, recv_sem=recv_sem,
-                device_id=(dst,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL)
-            rc.start()
-            rc.wait()
-        return
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=0),
-    )(x)
+    return tuple(lax.all_to_all(x, ICI_AXIS, split_axis=0, concat_axis=0,
+                                tiled=True) for x in planes)
 
 
 def _exchange_stage(mesh, n_m: int, shapes, session=None):
@@ -402,23 +337,18 @@ def _exchange_stage(mesh, n_m: int, shapes, session=None):
     per exchange.  ``shapes`` is the canonical (dtype, shape) signature
     of every packed plane."""
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec
     from ..sql.stagecompile import stage_cache
 
-    use_pallas = any("TPU" in str(getattr(d, "device_kind", ""))
-                     for d in mesh.devices.flat)
-    key = (f"ici-a2a:{n_m}:{use_pallas}:"
+    key = (f"ici-a2a:{n_m}:"
            + ":".join(f"{dt}{tuple(sh)}" for dt, sh in shapes)
            + ":" + ",".join(str(d.id) for d in mesh.devices.flat))
 
     def make():
         spec = PartitionSpec(ICI_AXIS)
-        import inspect
-        sm = _shard_map()
-        ck = ("check_vma" if "check_vma"
-              in inspect.signature(sm).parameters else "check_rep")
-        fn = sm(_a2a_arrays_traceable(n_m, use_pallas), mesh=mesh,
-                in_specs=spec, out_specs=spec, **{ck: False})
+        fn = shard_map(_a2a_step, mesh=mesh, in_specs=spec, out_specs=spec,
+                       check_vma=False)
         return fn, None
 
     cache = stage_cache(session)
@@ -441,8 +371,8 @@ def local_device_exchange(outboxes: Sequence[Dict[int, List[ColumnBatch]]],
     each participant's inbox unpacks per sender.  This is the tier-1
     face of ``device_exchange`` — same pack, same traceable, same
     unpack — run with ``--xla_force_host_platform_device_count`` on CPU
-    (and on real chips in a TPU window), so the cross-process path is a
-    device specialization of tested logic.  Raises ``IciUnavailable``
+    (and on real chips by ``chip_smoke.py --chips 4``), so the
+    cross-process path is a device specialization of tested logic.  Raises ``IciUnavailable``
     when the local world has too few devices."""
     import jax
     from .mesh import Mesh
@@ -575,26 +505,14 @@ def device_exchange(svc, session, plan: SidePlan, exchange: str,
     mesh = Mesh(np.asarray(devs), (ICI_AXIS,))
     _names, cols, masks, rowv, runlens = pack
     planes, shapes = _plane_shapes(cols, masks, rowv, runlens)
-    try:
-        cache, entry, sharding = _exchange_stage(mesh, n_m, shapes,
-                                                 session)
-        make_global = getattr(jax, "make_array_from_process_local_data",
-                              None)
-        if make_global is None:
-            raise IciUnavailable(
-                "jax lacks make_array_from_process_local_data; host tier")
-        placed = [make_global(sharding, p) for p in planes]
-        received = cache.dispatch(entry, *placed)
-        my_slot = members.index(svc.pid)
-        n_cols = len(cols)
-        got = [np.asarray(r.addressable_shards[0].data)
-               for r in received]
-    except IciUnavailable:
-        raise
-    except Exception as e:
-        raise IciUnavailable(
-            f"device collective failed for {exchange}: "
-            f"{str(e)[:200]}") from e
+    # "unavailable" ended above (no spanning world, too few devices): a
+    # lowering, compile or runtime error of the collective propagates
+    cache, entry, sharding = _exchange_stage(mesh, n_m, shapes, session)
+    placed = [jax.make_array_from_process_local_data(sharding, p)
+              for p in planes]
+    received = cache.dispatch(entry, *placed)
+    n_cols = len(cols)
+    got = [np.asarray(r.addressable_shards[0].data) for r in received]
     inbox = _unpack_inbox(_names, template, got[:n_cols],
                           got[n_cols:2 * n_cols], got[2 * n_cols],
                           got[2 * n_cols + 1], members,
@@ -602,5 +520,4 @@ def device_exchange(svc, session, plan: SidePlan, exchange: str,
     with svc._lock:
         svc.counters["ici_exchanges"] += 1
         svc.counters["ici_bytes_moved"] += int(moved)
-    del my_slot
     return inbox

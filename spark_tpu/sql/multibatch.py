@@ -713,8 +713,7 @@ class MultiBatchExecution:
         stage-executable cache (``sql/stagecompile.py``), keyed by the
         structural fingerprint with filter/projection literals slotted
         out as runtime arguments: a fresh ``jax.jit`` object per
-        execution would re-trace — and on remote-compile backends
-        re-COMPILE — the identical program for every run of the same
+        execution would re-trace the identical program for every run of the same
         query, and a per-SESSION cache would still re-compile it once
         per server session."""
         from . import stagecompile as SC

@@ -266,8 +266,7 @@ class StageCache:
         ``make_fn`` returns ``(traceable, aux)`` — the pure step
         function to compile and any entry-owned metadata; the cache
         jits it, so call sites never construct jit objects themselves
-        (a fresh ``jax.jit`` per execution re-traces — and on
-        remote-compile backends re-COMPILES — the identical program)."""
+        (a fresh ``jax.jit`` per execution re-traces the identical program)."""
         if session is not None:
             try:
                 self.max_entries = int(

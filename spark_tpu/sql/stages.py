@@ -328,6 +328,7 @@ class _MappedStream(BatchStream):
 
             from jax import lax, shard_map
             from jax.sharding import PartitionSpec
+            from ..parallel.collective import pmax
             from ..parallel.mesh import DATA_AXIS
             n_extra = len(leaves) - 1
 
@@ -344,7 +345,7 @@ class _MappedStream(BatchStream):
                     meta[tuple(b.capacity for b in all_leaves)] = (
                         list(ctx.flag_caps), list(ctx.flag_kinds))
                     # worst per-shard overflow drives the adaptive retry
-                    flags = [lax.pmax(f, DATA_AXIS) for f in ctx.flags]
+                    flags = [pmax(f) for f in ctx.flags]
                     return c, lax.psum(c.num_rows(), DATA_AXIS), flags
                 finally:
                     E._slot_bindings.map = None

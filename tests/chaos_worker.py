@@ -10,11 +10,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 data_dir, ckpt_dir, marker, out_path = sys.argv[1:5]
 
-os.environ.setdefault("SPARK_TPU_PLATFORM", "cpu")
-# persistent jit cache (same dir + policy as conftest.py): worker
-# subprocesses otherwise recompile every program on every test run
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/spark_tpu_jax_cache_cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# persistent jit cache (the directory is spark_tpu's own default inside the
+# checkout; same policy as conftest.py): worker subprocesses otherwise
+# recompile every program on every test run
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
 from spark_tpu.sql.session import SparkSession          # noqa: E402

@@ -34,10 +34,9 @@ mode = sys.argv[4] if len(sys.argv) > 4 else "wagg"
 timeout_s = float(sys.argv[5]) if len(sys.argv) > 5 else 30.0
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-# persistent jit cache (same dir + policy as conftest.py): worker
-# subprocesses otherwise recompile every program on every test run
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/spark_tpu_jax_cache_cpu")
+# persistent jit cache (the directory is spark_tpu's own default inside the
+# checkout; same policy as conftest.py): worker subprocesses otherwise
+# recompile every program on every test run
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))

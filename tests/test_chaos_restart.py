@@ -33,7 +33,7 @@ def test_supervised_restart_resumes_from_checkpoint(tmp_path):
     out = tmp_path / "result.csv"
 
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env["SPARK_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-m", "spark_tpu.cli", "launch",
          "--processes", "1", "--max-restarts", "2",

@@ -14,10 +14,9 @@ beat_dir = sys.argv[3]
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-# persistent jit cache (same dir + policy as conftest.py): worker
-# subprocesses otherwise recompile every program on every test run
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/spark_tpu_jax_cache_cpu")
+# persistent jit cache (the directory is spark_tpu's own default inside the
+# checkout; same policy as conftest.py): worker subprocesses otherwise
+# recompile every program on every test run
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
 

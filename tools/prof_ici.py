@@ -1,19 +1,14 @@
-"""Profile the ICI device-exchange tier's pieces inside a TPU window
-(bench.py schedules this as a window probe next to prof_join.py; falls
-back to whatever backend jax gives).
+"""Profile the ICI device-exchange tier's pieces on whatever devices
+jax gives (2+ needed).
 
-Three groups, each isolated so one Mosaic/compile failure cannot abort
-the rest of a rare window's profile:
+Three groups, each isolated so one failure cannot abort the rest of
+the profile:
 
-1. the collective primitives over the exchange axis at pack-plane
-   shapes — ``lax.all_to_all`` (the portable path) vs the Pallas
-   ``make_async_remote_copy`` direct all-to-all (the TPU path), so a
-   window tells us what the remote-DMA kernel actually buys over XLA's
-   collective at each buffer size;
+1. the collective over the exchange axis at pack-plane shapes —
+   ``lax.all_to_all`` under ``shard_map``, the exchange's own step;
 2. the end-to-end ``local_device_exchange`` (pack → stage-cached
    collective → unpack) in host wall-clock MB/s — the figure the
-   distici bench lane's forced-CPU mesh approximates and a window
-   makes real;
+   distici bench lane's forced-CPU mesh approximates;
 3. the host wire plane (encode + decode of identical outboxes) as the
    DCN-tier baseline the device tier is meant to beat.
 
@@ -51,25 +46,20 @@ sharding = jax.sharding.NamedSharding(mesh, PartitionSpec(ici.ICI_AXIS))
 rng = np.random.default_rng(7)
 
 
-def coll_time(name, use_pallas, rows):
+def coll_time(name, rows):
     """One packed data plane ((n_m*n_m, rows) int64, device i holding
     its (n_m, rows) outbound block), ITERS exchanges inside a fori_loop
     with a carried perturbation, one scalar fetch."""
-    import inspect
     try:
-        sm = ici._shard_map()
-        ck = ("check_vma" if "check_vma"
-              in inspect.signature(sm).parameters else "check_rep")
-        step = ici._a2a_arrays_traceable(N_M, use_pallas)
-
         def body(x):
             def it(i, carry):
-                moved, = step(carry + i)
+                moved, = ici._a2a_step(carry + i)
                 return moved
             return jax.lax.fori_loop(0, ITERS, it, x)[0, 0]
 
-        fn = jax.jit(sm(body, mesh=mesh, in_specs=PartitionSpec(ici.ICI_AXIS),
-                        out_specs=PartitionSpec(), **{ck: False}))
+        fn = jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=PartitionSpec(ici.ICI_AXIS),
+            out_specs=PartitionSpec(), check_vma=False))
         x = jax.device_put(
             rng.integers(-99, 99, (N_M * N_M, rows)).astype(np.int64),
             sharding)
@@ -88,17 +78,9 @@ def coll_time(name, use_pallas, rows):
         return None
 
 
-ON_TPU = any("TPU" in str(getattr(d, "device_kind", ""))
-             for d in mesh.devices.flat)
-
 # 1. the collective at the pack-plane sizes the exchange actually ships
 for rows in (1 << 10, 1 << 14, 1 << 18):
-    coll_time(f"lax.all_to_all  rows/peer={rows}", False, rows)
-    if ON_TPU:
-        coll_time(f"pallas remote-DMA a2a rows/peer={rows}", True, rows)
-    else:
-        print(f"{'pallas remote-DMA a2a rows/peer=' + str(rows):44s} "
-              "SKIPPED (no TPU)", flush=True)
+    coll_time(f"lax.all_to_all  rows/peer={rows}", rows)
 
 
 # 2/3. end-to-end exchange vs the host wire plane on identical outboxes

@@ -1,9 +1,8 @@
-"""Profile the run-plane device lane inside a TPU window (bench.py
-schedules this as a window probe next to prof_ici.py; falls back to
-whatever backend jax gives).
+"""Profile the run-plane device lane on whatever backend jax gives
+(run by hand; bench.py no longer schedules probes).
 
 Three groups, each isolated so one compile failure cannot abort the
-rest of a rare window's profile:
+rest of the profile:
 
 1. plane expansion: the shape-stable searchsorted-gather
    (``run_expand``, the jit-lane form an untaught operator triggers)
