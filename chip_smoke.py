@@ -42,8 +42,10 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 FACTS = ("store_sales", "store_returns", "catalog_sales", "catalog_returns",
          "web_sales", "web_returns", "inventory")
 RTOL = 1e-9
-#: a float64 value's round trip through a TPU (emulated f64) is not bit-exact
-F64_DEVICE_RTOL = 1e-14
+#: ``--rows`` defaults: the real size on one chip; a tenth of it on four,
+#: where every second costs four chip-seconds and q17 alone took 686 s cold
+#: and 341 s warm at 10M rows (PERF.md, PR 23)
+ROWS_ONE_CHIP, ROWS_MESH = 10_000_000, 1_000_000
 
 # ---------------------------------------------------------------------------
 # statements (TPC-DS q3/q42/q55 with their literals as parameters, the two
@@ -314,22 +316,19 @@ def _compile_ms():
 
 
 def _agg_lowering(spark) -> str:
-    """Which keyed-aggregate lowering the last statement's plan takes on
-    this backend, read from the program text of the plan the engine
-    recorded for it (``session._last_qe``): the Mosaic kernel is a
-    ``tpu_custom_call``, the portable MXU form a ``dot_general``, the
+    """Which keyed-aggregate lowering the last statement RAN, read from the
+    program text of the stage-cache entry it dispatched (the jitted callable
+    the engine built and called, not a second program): the Mosaic kernel is
+    a ``tpu_custom_call``, the portable MXU form a ``dot_general``, the
     sort-based aggregate neither."""
-    import jax
-    import jax.numpy as jnp
-    from spark_tpu.kernels import compact
-    from spark_tpu.sql import physical as P
-    pq = spark._last_qe.planned
-
-    def step(leaves):
-        return compact(jnp, pq.physical.run(P.ExecContext(jnp, list(leaves))))
-
-    text = jax.jit(step).lower(
-        tuple(b.to_device() for b in pq.leaves)).as_text()
+    from spark_tpu.sql import stagecompile as SC
+    from spark_tpu.sql.planner import local_stage_key
+    key, slots, leaves = local_stage_key(spark, spark._last_qe.planned)
+    entry = SC.stage_cache(spark).peek(key)
+    assert entry is not None and not entry._first, \
+        "the statement did not dispatch its whole-plan stage"
+    text = entry.fn.lower(tuple(b.to_device() for b in leaves),
+                          SC.param_values(slots)).as_text()
     if "tpu_custom_call" in text:
         return "pallas"
     return "einsum" if "dot_general" in text else "sort"
@@ -428,6 +427,7 @@ def _cold_pass(spark, ds, emit, stmts):
         firsts = [f.result() for f in futures]
     emit({"phase": "session/cold", "statement": "all", "lanes": len(lanes),
           "at_once": _CACHED_LANES + n_streamed,
+          "host_cpus": os.cpu_count(),
           "wall_s": round(time.time() - t_pass, 3),
           "sum_first_s": round(sum(firsts), 3)})
     return {(ln[0], ln[1]): f for ln, f in zip(lanes, firsts)}
@@ -639,7 +639,7 @@ def _exchange_check(ds, n, emit, rows_per_sender=1 << 20):
 
     packs = [ici._pack_outbox(ob, members, tpl, cap, 1) for ob in outboxes]
     n_cols = len(kinds)
-    moved, f64_diff = 0, 0.0
+    moved = 0
     for r in members:
         cols = [np.stack([packs[s][1][j][r] for s in members])
                 for j in range(n_cols)]
@@ -656,17 +656,12 @@ def _exchange_check(ds, n, emit, rows_per_sender=1 << 20):
             gb, wb = got[r][s][0], want[s][0]
             assert gb.capacity == wb.capacity, (r, s)
             for gv, wv in zip(gb.vectors, wb.vectors):
-                if np.issubdtype(wv.data.dtype, np.floating):
-                    # a TPU emulates f64: a float64 plane does not come
-                    # back from the device bit for bit (1.8e-15 relative
-                    # on four v5e chips), every other plane must
-                    np.testing.assert_allclose(gv.data, wv.data,
-                                               rtol=F64_DEVICE_RTOL, atol=0)
-                    f64_diff = max(f64_diff, float(np.max(
-                        np.abs(gv.data - wv.data)
-                        / np.maximum(np.abs(wv.data), 1e-300))))
-                else:
-                    np.testing.assert_array_equal(gv.data, wv.data)
+                # bit for bit, floats too (a float64 plane crosses the
+                # device as its int64 view)
+                assert gv.data.dtype == wv.data.dtype, (r, s)
+                bits = np.dtype(f"u{wv.data.dtype.itemsize}")
+                np.testing.assert_array_equal(gv.data.view(bits),
+                                              wv.data.view(bits))
                 assert (gv.valid is None) == (wv.valid is None)
                 if wv.valid is not None:
                     np.testing.assert_array_equal(gv.valid, wv.valid)
@@ -676,7 +671,6 @@ def _exchange_check(ds, n, emit, rows_per_sender=1 << 20):
           "bytes_received_off_device": int(moved),
           "first_s": round(first, 3), "wall_s": round(wall, 3),
           "equal_to_host_pack_unpack": True,
-          "float64_max_rel_diff": f64_diff,
           "peak_bytes_in_use": _peak_bytes()})
 
 
@@ -727,8 +721,10 @@ def _emit(obj):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--rows", type=int, default=10_000_000,
-                    help="store_sales rows; the other facts scale off it")
+    ap.add_argument("--rows", type=int, default=0,
+                    help="store_sales rows; the other facts scale off it "
+                    f"(default {ROWS_ONE_CHIP:,}; {ROWS_MESH:,} with "
+                    "--chips 4)")
     ap.add_argument("--seed", type=int, default=20260730)
     ap.add_argument("--work-dir",
                     default=os.path.join(HERE, ".chip_smoke_work"))
@@ -756,7 +752,8 @@ def main(argv=None) -> int:
     spark.conf.set("spark.sql.warehouse.dir",
                    os.path.join(args.work_dir, "warehouse"))
     t0 = time.time()
-    ds = build_dataset(args.rows, args.seed, args.work_dir, _emit)
+    rows = args.rows or (ROWS_ONE_CHIP if args.chips == 1 else ROWS_MESH)
+    ds = build_dataset(rows, args.seed, args.work_dir, _emit)
     if args.chips == 1:
         phase_session(spark, ds, _emit, args.batch_rows or None)
         phase_server(spark, ds, _emit)
@@ -765,7 +762,7 @@ def main(argv=None) -> int:
     _emit({"phase": "total", "wall_s": round(time.time() - t0, 2)})
     _emit({"ok": True, "device": {"platform": devs[0].platform,
                                   "kind": devs[0].device_kind,
-                                  "count": len(devs)}})
+                                  "count": args.chips}})
     return 0
 
 

@@ -52,7 +52,12 @@ def multi_key_argsort(xp, keys: Sequence[Array], capacity: int) -> Array:
     steeply with its operand count (2^20 rows, compiled for a v5e in PR 23:
     7 keys + iota over 430 s as one sort, 88 s as seven single-key sorts;
     3 int64 keys 389 s), and every keyed aggregate, join build and ORDER BY
-    goes through here.
+    goes through here.  The chain pays at run time — each later pass gathers
+    its key through the running permutation: on a v5e at 2^22 rows 0.046 s
+    against 0.0105 s (2 int32 keys) and 0.090 s against 0.0121 s (int8, int8,
+    int32), for 24 s against 34 s and 27 s against 59 s of compile; on the
+    CPU backend, where compile is free, the variadic sort is 1.3-1.6x faster
+    (``tools/prof_sort.py``; PERF.md, PR 23).
     """
     if _is_np(xp):
         return np.lexsort(tuple(reversed([np.asarray(k) for k in keys])))

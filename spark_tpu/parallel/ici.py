@@ -241,20 +241,30 @@ def schema_eligible(batch: Optional[ColumnBatch]) -> bool:
 # pack / unpack: per-receiver spans <-> fixed-capacity per-peer buffers
 # ---------------------------------------------------------------------------
 
+def _wire_dtype(dtype) -> np.dtype:
+    """The dtype a data plane crosses the device in.  A TPU emulates
+    float64 (a value does not come back from the device bit for bit), and
+    the device tier moves bits, not numbers: a float64 plane ships as its
+    int64 view, taken and undone on the host.  Every other dtype ships as
+    itself."""
+    dtype = np.dtype(dtype)
+    return np.dtype(np.int64) if dtype == np.float64 else dtype
+
+
 def _pack_outbox(outbox: Dict[int, List[ColumnBatch]],
                  members: Sequence[int], template: ColumnBatch,
                  cap: int, max_runs: int):
     """Pack one participant's per-receiver batches into dense arrays:
-    per column a ``(n_m, cap)`` data buffer and a ``(n_m, cap)`` mask,
-    one ``(n_m, cap)`` row-validity plane, and a ``(n_m, max_runs)``
-    run-length table (run boundaries must survive the exchange — the
-    range lane merges presorted runs, not concatenations).  Peer slot
-    order is the sorted domain member list, identical on every
+    per column a ``(n_m, cap)`` data buffer (in its ``_wire_dtype``) and
+    a ``(n_m, cap)`` mask, one ``(n_m, cap)`` row-validity plane, and a
+    ``(n_m, max_runs)`` run-length table (run boundaries must survive the
+    exchange — the range lane merges presorted runs, not concatenations).
+    Peer slot order is the sorted domain member list, identical on every
     participant."""
     n_m = len(members)
     names = list(template.names)
-    cols = [np.zeros((n_m, cap), dtype=np.asarray(v.data).dtype)
-            for v in template.vectors]
+    dtypes = [np.asarray(v.data).dtype for v in template.vectors]
+    cols = [np.zeros((n_m, cap), dtype=_wire_dtype(dt)) for dt in dtypes]
     masks = [np.zeros((n_m, cap), dtype=bool) for _ in template.vectors]
     rowv = np.zeros((n_m, cap), dtype=bool)
     runlens = np.zeros((n_m, max_runs), dtype=np.int32)
@@ -271,7 +281,9 @@ def _pack_outbox(outbox: Dict[int, List[ColumnBatch]],
                     f"outbox rows exceed the agreed pack capacity "
                     f"({at + rows} > {cap})")
             for j, v in enumerate(b.vectors):
-                cols[j][slot, at:at + rows] = np.asarray(v.data)[:rows]
+                cols[j][slot, at:at + rows] = np.ascontiguousarray(
+                    np.asarray(v.data)[:rows], dtype=dtypes[j]
+                ).view(cols[j].dtype)
                 masks[j][slot, at:at + rows] = (
                     True if v.valid is None else np.asarray(v.valid)[:rows])
             rowv[slot, at:at + rows] = (
@@ -295,25 +307,31 @@ def _unpack_inbox(names, template: ColumnBatch, cols, masks, rowv,
     for slot, sender in enumerate(members):
         if sender == self_pid:
             continue
-        lens = [int(r) for r in np.asarray(runlens[slot]) if int(r) > 0]
-        if not lens:
-            continue
-        runs: List[ColumnBatch] = []
-        at = 0
-        for rows in lens:
-            vectors = []
-            for j, tv in enumerate(template.vectors):
-                data = np.asarray(cols[j][slot, at:at + rows])
-                mask = np.asarray(masks[j][slot, at:at + rows])
-                vectors.append(ColumnVector(
-                    data, tv.dtype,
-                    None if bool(mask.all()) else mask, None))
-            rv = np.asarray(rowv[slot, at:at + rows])
-            runs.append(ColumnBatch(list(names), vectors,
-                                    None if bool(rv.all()) else rv, rows))
-            at += rows
-        out[sender] = runs
+        runs = _slot_runs(template, names, cols, masks, rowv, runlens, slot)
+        if runs:
+            out[sender] = runs
     return out
+
+
+def _slot_runs(template, names, cols, masks, rowv, runlens, slot
+               ) -> List[ColumnBatch]:
+    """One slot of the received planes, split back into its runs, each
+    data plane viewed back from its wire dtype."""
+    runs: List[ColumnBatch] = []
+    at = 0
+    for rows in (int(r) for r in np.asarray(runlens[slot]) if int(r) > 0):
+        vectors = []
+        for j, tv in enumerate(template.vectors):
+            data = np.ascontiguousarray(cols[j][slot, at:at + rows]).view(
+                np.asarray(tv.data).dtype)
+            mask = np.asarray(masks[j][slot, at:at + rows])
+            vectors.append(ColumnVector(
+                data, tv.dtype, None if bool(mask.all()) else mask, None))
+        rv = np.asarray(rowv[slot, at:at + rows])
+        runs.append(ColumnBatch(list(names), vectors,
+                                None if bool(rv.all()) else rv, rows))
+        at += rows
+    return runs
 
 
 # ---------------------------------------------------------------------------
@@ -419,29 +437,10 @@ def local_device_exchange(outboxes: Sequence[Dict[int, List[ColumnBatch]]],
         # the local harness keeps the self slot too: parity checks want
         # the full routed view back (the real path's own share never
         # leaves the process, so device_exchange drops it)
-        inbox[i] = _self_runs(template, names, cols, masks, rowv,
+        inbox[i] = _slot_runs(template, names, cols, masks, rowv,
                               runlens, i)
         out.append(inbox)
     return out
-
-
-def _self_runs(template, names, cols, masks, rowv, runlens, slot):
-    lens = [int(r) for r in np.asarray(runlens[slot]) if int(r) > 0]
-    runs: List[ColumnBatch] = []
-    at = 0
-    for rows in lens:
-        vectors = []
-        for j, tv in enumerate(template.vectors):
-            data = np.asarray(cols[j][slot, at:at + rows])
-            mask = np.asarray(masks[j][slot, at:at + rows])
-            vectors.append(ColumnVector(data, tv.dtype,
-                                        None if bool(mask.all()) else mask,
-                                        None))
-        rv = np.asarray(rowv[slot, at:at + rows])
-        runs.append(ColumnBatch(list(names), vectors,
-                                None if bool(rv.all()) else rv, rows))
-        at += rows
-    return runs
 
 
 def _fault_point(svc, exchange: str, point: str) -> None:

@@ -417,6 +417,17 @@ class Planner:
         raise AnalysisException(f"no physical plan for {node!r}")
 
 
+def local_stage_key(session, pq):
+    """(stage-cache key, literal slots, stage leaves) of the one-device
+    whole-plan stage ``QueryExecution`` dispatches for ``pq``."""
+    from . import stagecompile as SC
+    stage_leaves = SC.plan_leaves(session, pq.leaves)
+    skey, slots = SC.stage_fingerprint(pq.physical)
+    skey = (f"local|{skey}|{SC.leaf_signature(stage_leaves)}"
+            f"|{SC._conf_component(session)}")
+    return skey, slots, stage_leaves
+
+
 class QueryExecution:
     """Carries one query through analyze → optimize → plan → execute."""
 
@@ -773,10 +784,7 @@ class QueryExecution:
         # markers in leaf_signature re-key the stage (a run-count bucket
         # overflow re-plans to a larger plane; an oversized run table
         # falls back to the counted to_device materialization below)
-        stage_leaves = SC.plan_leaves(self.session, pq.leaves)
-        skey, slots = SC.stage_fingerprint(pq.physical)
-        skey = (f"local|{skey}|{SC.leaf_signature(stage_leaves)}"
-                f"|{SC._conf_component(self.session)}")
+        skey, slots, stage_leaves = local_stage_key(self.session, pq)
 
         def make():
             from ..analysis import maybe_verify_stage_contract

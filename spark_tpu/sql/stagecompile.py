@@ -322,6 +322,11 @@ class StageCache:
         return entry.fn(*args)
 
     # -- introspection -------------------------------------------------
+    def peek(self, key: str) -> Optional[_CachedStage]:
+        """The entry under ``key`` or None; counts nothing, moves nothing."""
+        with self._lock:
+            return self._entries.get(key)
+
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             n = len(self._entries)

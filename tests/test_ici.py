@@ -180,6 +180,34 @@ def test_pack_transpose_unpack_round_trip():
                                                   wb.row_valid)
 
 
+def test_float64_plane_ships_as_its_int64_bits():
+    """A TPU emulates float64, so a float plane crosses the device as its
+    int64 view and comes back bit for bit: -0.0, a NaN payload and a
+    denormal included."""
+    odd = np.array([-0.0, 1.0 / 3.0, 5e-324, np.inf], np.float64)
+    odd = np.append(odd, np.array([0x7FF8DEADBEEF0001], np.uint64)
+                    .view(np.float64))
+
+    def fbatch(vals):
+        vec = ColumnVector(np.asarray(vals, np.float64), T.DoubleType(),
+                           None, None)
+        return ColumnBatch(["v"], [vec], None, len(vals))
+
+    tpl = fbatch([0.0])
+    _names, cols, masks, rowv, runl = ici._pack_outbox(
+        {1: [fbatch(odd)]}, [0, 1], tpl, cap=8, max_runs=1)
+    assert cols[0].dtype == np.int64
+    assert [dt for dt, _ in ici._plane_shapes(cols, masks, rowv, runl)[1]] \
+        == ["int64", "bool", "bool", "int32"]
+    # receiver 1's slot 0 is sender 0's slot 1
+    swap = lambda p: p[::-1]
+    inbox = ici._unpack_inbox(_names, tpl, [swap(cols[0])], [swap(masks[0])],
+                              swap(rowv), swap(runl), [0, 1], self_pid=1)
+    got = inbox[0][0].vectors[0].data
+    assert got.dtype == np.float64
+    np.testing.assert_array_equal(got.view(np.uint64), odd.view(np.uint64))
+
+
 def test_pack_overflow_degrades_structured():
     tpl = _batch([0])
     with pytest.raises(ici.IciUnavailable):
