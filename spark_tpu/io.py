@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from . import tracing
 from . import types as T
 from .columnar import ColumnBatch, ColumnVector, PrebuiltColumn as \
     _PrebuiltColumn
@@ -740,14 +741,22 @@ def scan_file_batches(rel: L.FileRelation, batch_rows: int):
             kw = {} if keep is None else {"row_groups": keep}
             if keep == []:
                 continue
-            for rb in pf.iter_batches(batch_size=batch_rows,
-                                      columns=present, **kw):
-                table = pa.Table.from_batches([rb])
-                SCAN_STATS["rows"] += table.num_rows
-                extra = {k: _infer_partition_column([v] * table.num_rows)
-                         for k, v in pvals.items()} or None
+            record_batches = pf.iter_batches(batch_size=batch_rows,
+                                             columns=present, **kw)
+            while True:
+                with tracing.span("scan.read") as sp:
+                    rb = next(record_batches, None)
+                    if rb is None:
+                        break
+                    sp.attrs.update(rows=rb.num_rows, bytes=rb.nbytes)
+                with tracing.span("scan.decode", rows=rb.num_rows):
+                    table = pa.Table.from_batches([rb])
+                    SCAN_STATS["rows"] += table.num_rows
+                    extra = {k: _infer_partition_column([v] * table.num_rows)
+                             for k, v in pvals.items()} or None
+                    batch = _table_to_batch(table, extra)
                 yielded = True
-                yield _table_to_batch(table, extra)
+                yield batch
         if not yielded:
             # every row group was skipped: emit one empty batch so stage
             # runners still see the (pruned) schema
@@ -800,6 +809,9 @@ def prefetch_iter(inner, prep=None, depth: int = 2):
 
     q: "_qmod.Queue" = _qmod.Queue(maxsize=depth)
     stop = threading.Event()
+    # the worker takes the constructing thread's statement over, so scan
+    # spans belong to their statement
+    sid = tracing.current_statement()
 
     def _put(msg) -> None:
         # bounded put that aborts when the consumer has gone away
@@ -813,11 +825,12 @@ def prefetch_iter(inner, prep=None, depth: int = 2):
     def worker() -> None:
         try:
             try:
-                for item in inner:
-                    out = prep(item) if prep is not None else item
-                    _put(("item", out))
-                    if stop.is_set():
-                        return
+                with tracing.adopt(sid):
+                    for item in inner:
+                        out = prep(item) if prep is not None else item
+                        _put(("item", out))
+                        if stop.is_set():
+                            return
             finally:
                 close = getattr(inner, "close", None)
                 if close is not None:
@@ -831,7 +844,8 @@ def prefetch_iter(inner, prep=None, depth: int = 2):
     th.start()
     try:
         while True:
-            kind, payload = q.get()
+            with tracing.span("scan.wait"):    # what the step waits for
+                kind, payload = q.get()
             if kind == "item":
                 yield payload
             elif kind == "raise":

@@ -19,10 +19,13 @@ All kernels take ``xp`` (numpy | jax.numpy) — the dual-path contract.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import tracing
 from . import types as T
 from .aggregates import AggregateFunction, First, IDENTITY
 from .columnar import (ColumnBatch, ColumnVector, PlaneColumnVector,
@@ -38,10 +41,30 @@ def _is_np(xp) -> bool:
     return xp is np
 
 
+def _scope(xp, name: str):
+    """``tracing.scope(name)`` on the traced lane, nothing on the numpy
+    lane: the device op names of PERF.md section 3."""
+    return contextlib.nullcontext() if xp is np else tracing.scope(name)
+
+
+def _scoped(name: str):
+    """A kernel ``fn(xp, ...)`` under ``_scope(xp, name)``."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def kernel(xp, *args, **kw):
+            if xp is np:
+                return fn(xp, *args, **kw)
+            with tracing.scope(name):
+                return fn(xp, *args, **kw)
+        return kernel
+    return deco
+
+
 # ---------------------------------------------------------------------------
 # sorting primitives
 # ---------------------------------------------------------------------------
 
+@_scoped("argsort")
 def multi_key_argsort(xp, keys: Sequence[Array], capacity: int) -> Array:
     """Stable lexicographic argsort by keys[0], then keys[1], ...
 
@@ -57,7 +80,8 @@ def multi_key_argsort(xp, keys: Sequence[Array], capacity: int) -> Array:
     against 0.0105 s (2 int32 keys) and 0.090 s against 0.0121 s (int8, int8,
     int32), for 24 s against 34 s and 27 s against 59 s of compile; on the
     CPU backend, where compile is free, the variadic sort is 1.3-1.6x faster
-    (``tools/prof_sort.py``; PERF.md, PR 23).
+    (``tools/prof_sort.py``; PERF.md, PR 23).  Each chained pass is its own
+    scope (``argsort.pass<i>``, least-significant key first).
     """
     if _is_np(xp):
         return np.lexsort(tuple(reversed([np.asarray(k) for k in keys])))
@@ -65,9 +89,10 @@ def multi_key_argsort(xp, keys: Sequence[Array], capacity: int) -> Array:
     iota = xp.arange(capacity, dtype=np.int32)
     if len(keys) > 1 and _on_tpu_device():
         perm = None
-        for k in reversed(keys):
-            kp, ip = (k, iota) if perm is None else (k[perm], perm)
-            _, perm = jax.lax.sort((kp, ip), num_keys=1, is_stable=True)
+        for i, k in enumerate(reversed(keys)):
+            with tracing.scope(f"argsort.pass{i}"):
+                kp, ip = (k, iota) if perm is None else (k[perm], perm)
+                _, perm = jax.lax.sort((kp, ip), num_keys=1, is_stable=True)
         return perm
     out = jax.lax.sort(tuple(keys) + (iota,), num_keys=len(keys),
                        is_stable=True)
@@ -97,8 +122,8 @@ def radix_argsort(xp, keys: Array, bits: int = 4) -> Array:
     compare network.  ``bits=4`` keeps the per-pass working set at
     n x 16 x 4B; 16 passes cover 64 bits.  CPU lane: np.argsort
     (XLA:CPU executes the dense formulation slower than its built-in
-    sort — this path exists for TPU, A/B'd by tools/prof_agg2.py in a
-    hardware window before it takes over any default)."""
+    sort — this path exists for TPU and takes over no default before a
+    chip trace, read by ``python -m spark_tpu.tracing``, says it should)."""
     if _is_np(xp):
         return np.argsort(np.asarray(keys), kind="stable")
     if 64 % bits != 0:
@@ -156,6 +181,7 @@ def sort_key_transform(xp, data: Array, valid: Optional[Array], dtype: T.DataTyp
     return [null_rank, key]
 
 
+@_scoped("sort_batch")
 def sort_batch(xp, batch: ColumnBatch,
                keys: Sequence[Tuple[Array, Optional[Array], T.DataType, bool, bool]],
                ) -> ColumnBatch:
@@ -186,6 +212,7 @@ def range_bucket(xp, keys: Array, cuts: Array) -> Array:
     return searchsorted(xp, cuts, keys, side="right").astype(np.int32)
 
 
+@_scoped("partition_bucket")
 def partition_bucket(xp, batch: ColumnBatch, part_ids: Array,
                      n_parts: int,
                      tie_keys: Optional[Sequence[Array]] = None,
@@ -257,6 +284,7 @@ def slice_rows(batch: ColumnBatch, start: int, count: int) -> ColumnBatch:
     return ColumnBatch(list(batch.names), vectors, None, count)
 
 
+@_scoped("take_batch")
 def take_batch(xp, batch: ColumnBatch, perm: Array) -> ColumnBatch:
     """Gather all columns (and masks) through an index array.
 
@@ -273,6 +301,7 @@ def take_batch(xp, batch: ColumnBatch, perm: Array) -> ColumnBatch:
     return ColumnBatch(batch.names, vectors, rv, out_cap)
 
 
+@_scoped("compact")
 def compact(xp, batch: ColumnBatch) -> ColumnBatch:
     """Move live rows to the front, preserving order (stable).
 
@@ -619,6 +648,10 @@ def grouped_aggregate(
         out = _plane_global_aggregate(xp, batch, agg_slots)
         if out is not None:
             return out
+    if not _is_np(xp) and key_exprs:
+        # which lowering a keyed aggregate took, noted at trace time with
+        # the stage being built: tracing.last_statement()["notes"]
+        tracing.note("agg_lowering", "sort")
     return _sorted_grouped_aggregate(xp, batch, key_exprs, agg_slots)
 
 
@@ -771,6 +804,7 @@ def _plane_global_aggregate(
     return ColumnBatch(names, vectors, None, 1)
 
 
+@_scoped("agg.sort")
 def _sorted_grouped_aggregate(
     xp,
     batch: ColumnBatch,
@@ -808,27 +842,32 @@ def _sorted_grouped_aggregate(
     # reduces original-row indices).  The sort was the dominant cost of
     # every global aggregate — a full O(n log^2 n) bitonic pass on TPU
     # for a single output row.
-    perm = multi_key_argsort(xp, sort_cols, capacity) if key_exprs else None
+    with _scope(xp, "agg.sort.argsort"):
+        perm = multi_key_argsort(xp, sort_cols, capacity) \
+            if key_exprs else None
 
-    sorted_cols = sort_cols if perm is None else [c[perm] for c in sort_cols]
-    live_s = live if perm is None else live[perm]
+    with _scope(xp, "agg.sort.permute"):
+        sorted_cols = sort_cols if perm is None \
+            else [c[perm] for c in sort_cols]
+        live_s = live if perm is None else live[perm]
 
     # ---- segment boundaries --------------------------------------------
     if key_exprs:
-        change = xp.zeros(capacity, bool)
-        for c in sorted_cols:
-            shifted = xp.concatenate([c[:1], c[:-1]])
-            change = change | (c != shifted)
-        is_start = change
-        if _is_np(xp):
-            is_start = is_start.copy()
-            is_start[0] = True
-        else:
-            is_start = is_start.at[0].set(True)
-        is_start = is_start & live_s
-        seg_ids = xp.cumsum(is_start.astype(np.int64)) - 1
-        seg_ids = xp.where(live_s, seg_ids, np.int64(capacity - 1))
-        num_groups = xp.sum(is_start.astype(np.int64))
+        with _scope(xp, "agg.sort.segment"):
+            change = xp.zeros(capacity, bool)
+            for c in sorted_cols:
+                shifted = xp.concatenate([c[:1], c[:-1]])
+                change = change | (c != shifted)
+            is_start = change
+            if _is_np(xp):
+                is_start = is_start.copy()
+                is_start[0] = True
+            else:
+                is_start = is_start.at[0].set(True)
+            is_start = is_start & live_s
+            seg_ids = xp.cumsum(is_start.astype(np.int64)) - 1
+            seg_ids = xp.where(live_s, seg_ids, np.int64(capacity - 1))
+            num_groups = xp.sum(is_start.astype(np.int64))
     else:
         seg_ids = xp.zeros(capacity, np.int64)
         is_start = None
@@ -842,11 +881,13 @@ def _sorted_grouped_aggregate(
     group_pos = xp.arange(capacity, dtype=np.int64)
     for k, v in zip(key_exprs, key_vals):
         dt = k.data_type(schema)
-        data_s = ctx.broadcast(v).data[perm]
-        valid_s = None if v.valid is None else v.valid[perm]
-        kdata = _scatter_starts(xp, data_s, seg_ids, is_start, capacity)
-        kvalid = None if valid_s is None else _scatter_starts(
-            xp, valid_s, seg_ids, is_start, capacity)
+        with _scope(xp, "agg.sort.permute"):
+            data_s = ctx.broadcast(v).data[perm]
+            valid_s = None if v.valid is None else v.valid[perm]
+        with _scope(xp, "agg.sort.segment"):
+            kdata = _scatter_starts(xp, data_s, seg_ids, is_start, capacity)
+            kvalid = None if valid_s is None else _scatter_starts(
+                xp, valid_s, seg_ids, is_start, capacity)
         out_names.append(k.name)
         out_vectors.append(ColumnVector(kdata.astype(dt.np_dtype), dt, kvalid,
                                         v.dictionary))
@@ -881,9 +922,11 @@ def _sorted_grouped_aggregate(
             reduced = [_global_reduce(xp, s.data, s.kind, capacity)
                        for s in specs]
         else:
-            sorted_bufs = [s.data[perm] for s in specs]
-            reduced = [segment_reduce(xp, b, seg_ids, capacity, s.kind)
-                       for b, s in zip(sorted_bufs, specs)]
+            with _scope(xp, "agg.sort.permute"):
+                sorted_bufs = [s.data[perm] for s in specs]
+            with _scope(xp, "agg.sort.segment"):
+                reduced = [segment_reduce(xp, b, seg_ids, capacity, s.kind)
+                           for b, s in zip(sorted_bufs, specs)]
         dt = func.data_type(schema)
         if isinstance(func, First):
             # argmin/argmax of row index → gather the value column
@@ -1170,6 +1213,7 @@ def _masked_minmax64(xp, lo, hi, mask):
     return comb(min_hi, min_lo), comb(max_hi, max_lo), min_lo
 
 
+@_scoped("agg.mxu")
 def _mxu_grouped_aggregate(xp, batch, key_exprs, agg_slots, bucket_cap):
     import jax
     import jax.numpy as jnp
@@ -1185,113 +1229,118 @@ def _mxu_grouped_aggregate(xp, batch, key_exprs, agg_slots, bucket_cap):
     L = int(min(_MXU_TILE, capacity))
     n_pad = ((capacity + L - 1) // L) * L
 
-    # ---- composite bucket codes (mixed radix over keys, NULL = 0) -------
-    # All O(n) arithmetic is 32-bit native (see _i64_halves): codes come
-    # from low-half differences, exact whenever `fits` holds; the slow
-    # branch owns every other execution, so garbage codes are harmless.
-    key_vals: List[ExprValue] = [ctx.broadcast(k.eval(ctx)) for k in key_exprs]
-    key_dts = [k.data_type(schema) for k in key_exprs]
-    codes = []          # per-key (code32 in [0, r), r32, kmin_i64, nullable)
-    prod = xp.ones((), np.float64)   # overflow-safe fit check in f64
-    for v in key_vals:
-        data = v.data
-        if data.dtype == np.bool_:
-            data = data.astype(np.int8)
-        lo, hi = _i64_halves(xp, data)
-        mask = live if v.valid is None else (live & v.valid)
-        kmin, kmax, kmin_lo = _masked_minmax64(xp, lo, hi, mask)
-        # the authoritative range estimate is f64 (int64 spans can exceed
-        # any 32-bit arithmetic); only trusted when `fits` proves it small
-        rangef = xp.maximum(kmax.astype(np.float64) - kmin.astype(np.float64)
-                            + 1.0, 0.0)
-        r32 = xp.clip(rangef, 0.0, np.float64(B + 2)).astype(np.int32)
-        diff = (lo - kmin_lo).astype(np.int32)   # mod-2^32; exact iff fits
-        nullable = v.valid is not None
-        if nullable:
-            code = xp.where(mask, diff + 1, 0)
-            r32 = r32 + 1
-            prod = prod * (rangef + 1.0)
-        else:
-            code = diff
-            r32 = xp.maximum(r32, 1)
-            prod = prod * xp.maximum(rangef, 1.0)
-        codes.append((code, r32, kmin, nullable))
-
-    bucket = xp.zeros(capacity, np.int32)
-    for code, r32, _, _ in codes:
-        bucket = bucket * r32 + code   # wraps only when not fits
-    fits = prod <= np.float64(B)
-    bucket32 = xp.clip(bucket, 0, B - 1)
-
-    def fast_branch(_):
-        # ---- plane assembly (fast branch only: fallback executions must
-        # not pay the O(n·P) limb extraction) ------------------------------
-        # plane 0: live-row count; per Sum/Avg: limb planes + own count
-        # plane; per Count: count plane.  All bf16 {0..255}-valued.
-        planes: List[Array] = [live.astype(jnp.bfloat16)]
-        agg_plane_info = []  # (func, name, kind, first_plane, offset, n_limbs)
-        for func, name in agg_slots:
-            if isinstance(func, CountStar):
-                agg_plane_info.append((func, name, "countstar", None, 0, 0))
-                continue
-            v = ctx.broadcast(func.children[0].eval(ctx))
-            m = live if v.valid is None else (live & v.valid)
-            if isinstance(func, Count):
-                start = len(planes)
-                planes.append(m.astype(jnp.bfloat16))
-                agg_plane_info.append((func, name, "count", start, 0, 0))
-                continue
-            # Sum / Avg over integral input
+    with tracing.scope("agg.mxu.limbs"):
+        # ---- composite bucket codes (mixed radix over keys, NULL = 0) -------
+        # All O(n) arithmetic is 32-bit native (see _i64_halves): codes come
+        # from low-half differences, exact whenever `fits` holds; the slow
+        # branch owns every other execution, so garbage codes are harmless.
+        key_vals: List[ExprValue] = [ctx.broadcast(k.eval(ctx)) for k in key_exprs]
+        key_dts = [k.data_type(schema) for k in key_exprs]
+        codes = []          # per-key (code32 in [0, r), r32, kmin_i64, nullable)
+        prod = xp.ones((), np.float64)   # overflow-safe fit check in f64
+        for v in key_vals:
             data = v.data
             if data.dtype == np.bool_:
                 data = data.astype(np.int8)
-            n_limbs, offset = _limb_plan(data.dtype)
-            # 32-bit-native limb extraction: the +offset sign shift is a
-            # top-bit flip for 8-byte values (no carry: 2^63 IS the top
-            # bit) and a mod-2^32 low-word add for narrower ones (only the
-            # low 8*n_limbs bits are read, which the wrap cannot touch)
             lo, hi = _i64_halves(xp, data)
-            if n_limbs == 8:
-                words = (lo, hi ^ np.uint32(0x80000000))
+            mask = live if v.valid is None else (live & v.valid)
+            kmin, kmax, kmin_lo = _masked_minmax64(xp, lo, hi, mask)
+            # the authoritative range estimate is f64 (int64 spans can exceed
+            # any 32-bit arithmetic); only trusted when `fits` proves it small
+            rangef = xp.maximum(kmax.astype(np.float64) - kmin.astype(np.float64)
+                                + 1.0, 0.0)
+            r32 = xp.clip(rangef, 0.0, np.float64(B + 2)).astype(np.int32)
+            diff = (lo - kmin_lo).astype(np.int32)   # mod-2^32; exact iff fits
+            nullable = v.valid is not None
+            if nullable:
+                code = xp.where(mask, diff + 1, 0)
+                r32 = r32 + 1
+                prod = prod * (rangef + 1.0)
             else:
-                words = (lo + np.uint32(offset),)
-            start = len(planes)
-            for i in range(n_limbs):
-                w = words[i // 4]
-                limb = (w >> np.uint32(8 * (i % 4))) & np.uint32(0xFF)
-                limb = xp.where(m, limb, np.uint32(0))
-                planes.append(limb.astype(jnp.bfloat16))
-            planes.append(m.astype(jnp.bfloat16))   # per-agg count
-            agg_plane_info.append((func, name, "sum", start, offset, n_limbs))
+                code = diff
+                r32 = xp.maximum(r32, 1)
+                prod = prod * xp.maximum(rangef, 1.0)
+            codes.append((code, r32, kmin, nullable))
 
-        P = len(planes)
-        plane_mat = xp.stack(planes, axis=-1)                # (n, P)
+        bucket = xp.zeros(capacity, np.int32)
+        for code, r32, _, _ in codes:
+            bucket = bucket * r32 + code   # wraps only when not fits
+        fits = prod <= np.float64(B)
+        bucket32 = xp.clip(bucket, 0, B - 1)
+
+    def fast_branch(_):
+        with tracing.scope("agg.mxu.limbs"):
+            # ---- plane assembly (fast branch only: fallback executions must
+            # not pay the O(n·P) limb extraction) ------------------------------
+            # plane 0: live-row count; per Sum/Avg: limb planes + own count
+            # plane; per Count: count plane.  All bf16 {0..255}-valued.
+            planes: List[Array] = [live.astype(jnp.bfloat16)]
+            agg_plane_info = []  # (func, name, kind, first_plane, offset, n_limbs)
+            for func, name in agg_slots:
+                if isinstance(func, CountStar):
+                    agg_plane_info.append((func, name, "countstar", None, 0, 0))
+                    continue
+                v = ctx.broadcast(func.children[0].eval(ctx))
+                m = live if v.valid is None else (live & v.valid)
+                if isinstance(func, Count):
+                    start = len(planes)
+                    planes.append(m.astype(jnp.bfloat16))
+                    agg_plane_info.append((func, name, "count", start, 0, 0))
+                    continue
+                # Sum / Avg over integral input
+                data = v.data
+                if data.dtype == np.bool_:
+                    data = data.astype(np.int8)
+                n_limbs, offset = _limb_plan(data.dtype)
+                # 32-bit-native limb extraction: the +offset sign shift is a
+                # top-bit flip for 8-byte values (no carry: 2^63 IS the top
+                # bit) and a mod-2^32 low-word add for narrower ones (only the
+                # low 8*n_limbs bits are read, which the wrap cannot touch)
+                lo, hi = _i64_halves(xp, data)
+                if n_limbs == 8:
+                    words = (lo, hi ^ np.uint32(0x80000000))
+                else:
+                    words = (lo + np.uint32(offset),)
+                start = len(planes)
+                for i in range(n_limbs):
+                    w = words[i // 4]
+                    limb = (w >> np.uint32(8 * (i % 4))) & np.uint32(0xFF)
+                    limb = xp.where(m, limb, np.uint32(0))
+                    planes.append(limb.astype(jnp.bfloat16))
+                planes.append(m.astype(jnp.bfloat16))   # per-agg count
+                agg_plane_info.append((func, name, "sum", start, offset, n_limbs))
+
+            P = len(planes)
+            plane_mat = xp.stack(planes, axis=-1)                # (n, P)
 
         if pallas_agg.supported(B) and _on_tpu_device():
             # Pallas accumulate: one-hot tiles built in VMEM, (B, P) int32
             # accumulator in scratch, bucket chunks beyond the runtime key
             # range skipped — HBM traffic is one pass over the planes
             n_active = pallas_agg.n_active_chunks(xp, prod, B)
+            tracing.note("agg_lowering", "pallas")
             tot = pallas_agg.grouped_accumulate(bucket32, plane_mat,
                                                 n_active, B)
         else:
-            bucket_pad = bucket32
-            if n_pad != capacity:
-                plane_mat = xp.concatenate(
-                    [plane_mat, xp.zeros((n_pad - capacity, P), jnp.bfloat16)])
-                bucket_pad = xp.concatenate(
-                    [bucket32, xp.zeros(n_pad - capacity, np.int32)])
-            T_tiles = n_pad // L
+            tracing.note("agg_lowering", "einsum")
+            with tracing.scope("agg.onehot"):
+                bucket_pad = bucket32
+                if n_pad != capacity:
+                    plane_mat = xp.concatenate(
+                        [plane_mat, xp.zeros((n_pad - capacity, P), jnp.bfloat16)])
+                    bucket_pad = xp.concatenate(
+                        [bucket32, xp.zeros(n_pad - capacity, np.int32)])
+                T_tiles = n_pad // L
 
-            bb = bucket_pad.reshape(T_tiles, L)
-            pp = plane_mat.reshape(T_tiles, L, P)
-            oh = jax.nn.one_hot(bb, B, dtype=jnp.bfloat16)        # (T, L, B)
-            per_tile = jnp.einsum("tlb,tlp->tbp", oh, pp,
-                                  preferred_element_type=jnp.float32)
-            # exact integer accumulation across tiles; int32 is enough while
-            # total counts/limb-sums stay < 2^31 (n·255), halving HBM traffic
-            acc_dt = jnp.int32 if n_pad * 255 < (1 << 31) else jnp.int64
-            tot = per_tile.astype(acc_dt).sum(0).astype(jnp.int64)  # (B, P)
+                bb = bucket_pad.reshape(T_tiles, L)
+                pp = plane_mat.reshape(T_tiles, L, P)
+                oh = jax.nn.one_hot(bb, B, dtype=jnp.bfloat16)        # (T, L, B)
+                per_tile = jnp.einsum("tlb,tlp->tbp", oh, pp,
+                                      preferred_element_type=jnp.float32)
+                # exact integer accumulation across tiles; int32 is enough while
+                # total counts/limb-sums stay < 2^31 (n·255), halving HBM traffic
+                acc_dt = jnp.int32 if n_pad * 255 < (1 << 31) else jnp.int64
+                tot = per_tile.astype(acc_dt).sum(0).astype(jnp.int64)  # (B, P)
         live_count = tot[:, 0]
         grow = live_count > 0                                 # real groups
 

@@ -108,6 +108,7 @@ def _accumulate_chunk(bucket32: Array, planes: Array, n_active: Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B_pad, P), jnp.int32),
         interpret=interpret,
+        name="pallas_agg",
     )(n_active.reshape(1).astype(jnp.int32),
       bucket32.reshape(1, n_pad),
       planes.astype(jnp.bfloat16))

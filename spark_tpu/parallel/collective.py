@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import tracing
 from ..columnar import ColumnBatch, ColumnVector
 from ..kernels import multi_key_argsort, searchsorted, take_batch
 from .mesh import DATA_AXIS
@@ -45,45 +46,47 @@ def hash_exchange(batch: ColumnBatch, bucket: Array, n_shards: int,
     Rows beyond a destination's ``cap_out`` are dropped and counted.
     """
     xp = jnp
-    C = batch.capacity
-    live = batch.row_valid_or_true()
-    b = xp.where(live, bucket.astype(np.int32), np.int32(n_shards))
+    with tracing.scope("exchange.pack"):
+        C = batch.capacity
+        live = batch.row_valid_or_true()
+        b = xp.where(live, bucket.astype(np.int32), np.int32(n_shards))
 
-    perm = multi_key_argsort(xp, [b], C)
-    bs = b[perm]
-    sorted_batch = take_batch(xp, batch, perm)
+        perm = multi_key_argsort(xp, [b], C)
+        bs = b[perm]
+        sorted_batch = take_batch(xp, batch, perm)
 
-    starts = searchsorted(xp, bs, xp.arange(n_shards, dtype=np.int32))
-    slot = xp.arange(C) - starts[xp.clip(bs, 0, n_shards - 1)]
-    ok = (bs < n_shards) & (slot < cap_out)
-    overflow = xp.sum((bs < n_shards).astype(np.int64)) - xp.sum(ok.astype(np.int64))
+        starts = searchsorted(xp, bs, xp.arange(n_shards, dtype=np.int32))
+        slot = xp.arange(C) - starts[xp.clip(bs, 0, n_shards - 1)]
+        ok = (bs < n_shards) & (slot < cap_out)
+        overflow = xp.sum((bs < n_shards).astype(np.int64)) - xp.sum(ok.astype(np.int64))
 
-    dest = xp.where(ok, bs, np.int32(n_shards))      # n_shards row → dropped
-    slot_c = xp.clip(slot, 0, cap_out - 1)
+        dest = xp.where(ok, bs, np.int32(n_shards))      # n_shards row → dropped
+        slot_c = xp.clip(slot, 0, cap_out - 1)
 
-    def scatter(data, fill):
-        buf = xp.full((n_shards, cap_out), fill, dtype=data.dtype)
-        return buf.at[dest, slot_c].set(data, mode="drop")
+        def scatter(data, fill):
+            buf = xp.full((n_shards, cap_out), fill, dtype=data.dtype)
+            return buf.at[dest, slot_c].set(data, mode="drop")
 
-    vectors: List[Tuple[Array, Optional[Array], ColumnVector]] = []
-    for v in sorted_batch.vectors:
-        data2 = scatter(v.data, 0)
-        valid2 = None if v.valid is None else scatter(v.valid, False)
-        vectors.append((data2, valid2, v))
-    rv_live = sorted_batch.row_valid_or_true() & ok
-    rv2 = scatter(rv_live, False)
+        vectors: List[Tuple[Array, Optional[Array], ColumnVector]] = []
+        for v in sorted_batch.vectors:
+            data2 = scatter(v.data, 0)
+            valid2 = None if v.valid is None else scatter(v.valid, False)
+            vectors.append((data2, valid2, v))
+        rv_live = sorted_batch.row_valid_or_true() & ok
+        rv2 = scatter(rv_live, False)
 
-    # ONE all_to_all moves every bucket to its destination over ICI
-    received = []
-    for data2, valid2, v in vectors:
-        rd = lax.all_to_all(data2, axis, split_axis=0, concat_axis=0, tiled=True)
-        rvd = None if valid2 is None else lax.all_to_all(
-            valid2, axis, split_axis=0, concat_axis=0, tiled=True)
-        received.append(ColumnVector(rd.reshape(-1), v.dtype,
-                                     None if rvd is None else rvd.reshape(-1),
-                                     v.dictionary))
-    rv_recv = lax.all_to_all(rv2, axis, split_axis=0, concat_axis=0,
-                             tiled=True).reshape(-1)
+    with tracing.scope("exchange.all_to_all"):
+        # ONE all_to_all moves every bucket to its destination over ICI
+        received = []
+        for data2, valid2, v in vectors:
+            rd = lax.all_to_all(data2, axis, split_axis=0, concat_axis=0, tiled=True)
+            rvd = None if valid2 is None else lax.all_to_all(
+                valid2, axis, split_axis=0, concat_axis=0, tiled=True)
+            received.append(ColumnVector(rd.reshape(-1), v.dtype,
+                                         None if rvd is None else rvd.reshape(-1),
+                                         v.dictionary))
+        rv_recv = lax.all_to_all(rv2, axis, split_axis=0, concat_axis=0,
+                                 tiled=True).reshape(-1)
     out = ColumnBatch(batch.names, received, rv_recv, n_shards * cap_out)
     return out, overflow
 
@@ -100,7 +103,8 @@ def fine_bucket_histogram(h: Array, live: Array, n_fine: int,
     fine = (h.astype(np.uint64) % np.uint64(n_fine)).astype(np.int32)
     local = xp.zeros(n_fine, np.int64).at[fine].add(
         live.astype(np.int64), mode="drop")
-    return fine, lax.psum(local, axis)
+    with tracing.scope("exchange.psum"):
+        return fine, lax.psum(local, axis)
 
 
 def balanced_assignment(counts: Array, n_shards: int) -> Tuple[Array, Array]:
@@ -180,17 +184,19 @@ def broadcast_all(batch: ColumnBatch, axis: str = DATA_AXIS) -> ColumnBatch:
     def gather(x):
         return lax.all_gather(x, axis, tiled=True)
 
-    vectors = []
-    for v in batch.vectors:
-        data = gather(v.data)
-        valid = None if v.valid is None else gather(v.valid)
-        vectors.append(ColumnVector(data, v.dtype, valid, v.dictionary))
-    rv = gather(batch.row_valid_or_true())
+    with tracing.scope("exchange.all_gather"):
+        vectors = []
+        for v in batch.vectors:
+            data = gather(v.data)
+            valid = None if v.valid is None else gather(v.valid)
+            vectors.append(ColumnVector(data, v.dtype, valid, v.dictionary))
+        rv = gather(batch.row_valid_or_true())
     return ColumnBatch(batch.names, vectors, rv, batch.capacity * n)
 
 
 def psum_arrays(arrays: List[Array], axis: str = DATA_AXIS) -> List[Array]:
-    return [lax.psum(a, axis) for a in arrays]
+    with tracing.scope("exchange.psum"):
+        return [lax.psum(a, axis) for a in arrays]
 
 
 def _extreme(op: str, x: Array, axis: str) -> Array:
@@ -198,9 +204,10 @@ def _extreme(op: str, x: Array, axis: str) -> Array:
     # rewrite for SUM all-reduces only ("Supported lowering only of Sum
     # all reduce", first seen on four v5e chips in PR 23): a 64-bit max/min
     # rides an all_gather and reduces locally — same value on every shard
-    if np.dtype(x.dtype).itemsize == 8:
-        return getattr(jnp, op)(lax.all_gather(x, axis), axis=0)
-    return (lax.pmax if op == "max" else lax.pmin)(x, axis)
+    with tracing.scope("exchange.psum"):       # the all-reduce class
+        if np.dtype(x.dtype).itemsize == 8:
+            return getattr(jnp, op)(lax.all_gather(x, axis), axis=0)
+        return (lax.pmax if op == "max" else lax.pmin)(x, axis)
 
 
 def pmax(x: Array, axis: str = DATA_AXIS) -> Array:

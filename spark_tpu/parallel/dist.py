@@ -34,10 +34,13 @@ from .. import types as T
 from ..aggregates import AggregateFunction, First
 from ..columnar import ColumnBatch, ColumnVector, pad_capacity
 from ..expressions import Col, EvalContext, Expression, Hash64
-from ..kernels import _scatter_starts, compact, multi_key_argsort, segment_reduce, sort_batch, sort_key_transform
+from .. import tracing
+from ..kernels import (_scatter_starts, _scope, compact, multi_key_argsort,
+                       segment_reduce, sort_batch, sort_key_transform)
 from ..sql import physical as P
 from ..sql.joins import PJoin
-from .collective import broadcast_all, hash_exchange, pmax, pmin
+from .collective import (broadcast_all, hash_exchange, pmax, pmin,
+                         psum_arrays)
 from .mesh import DATA_AXIS
 
 Array = Any
@@ -422,17 +425,24 @@ def _group_by_keys(xp, key_vals, live, capacity):
         else:
             sort_cols += [xp.where(v.valid, np.int8(0), np.int8(-1)),
                           xp.where(v.valid, data, xp.zeros((), data.dtype))]
-    perm = multi_key_argsort(xp, sort_cols, capacity)
-    sorted_cols = [c[perm] for c in sort_cols]
-    live_s = live[perm]
-    change = xp.zeros(capacity, bool)
-    for c in sorted_cols:
-        change = change | (c != xp.concatenate([c[:1], c[:-1]]))
-    is_start = change.at[0].set(True) if xp is jnp else _np_set0(change)
-    is_start = is_start & live_s
-    seg_ids = xp.cumsum(is_start.astype(np.int64)) - 1
-    seg_ids = xp.where(live_s, seg_ids, np.int64(capacity - 1))
-    num_groups = xp.sum(is_start.astype(np.int64))
+    if xp is jnp:
+        tracing.note("agg_lowering", "sort")   # kernels.grouped_aggregate's
+    with _scope(xp, "agg.sort"):               # scopes, on this copy of it
+        with _scope(xp, "agg.sort.argsort"):
+            perm = multi_key_argsort(xp, sort_cols, capacity)
+        with _scope(xp, "agg.sort.permute"):
+            sorted_cols = [c[perm] for c in sort_cols]
+            live_s = live[perm]
+        with _scope(xp, "agg.sort.segment"):
+            change = xp.zeros(capacity, bool)
+            for c in sorted_cols:
+                change = change | (c != xp.concatenate([c[:1], c[:-1]]))
+            is_start = change.at[0].set(True) if xp is jnp \
+                else _np_set0(change)
+            is_start = is_start & live_s
+            seg_ids = xp.cumsum(is_start.astype(np.int64)) - 1
+            seg_ids = xp.where(live_s, seg_ids, np.int64(capacity - 1))
+            num_groups = xp.sum(is_start.astype(np.int64))
     return perm, seg_ids, is_start, num_groups
 
 
@@ -443,7 +453,11 @@ def _reduce_buf(xp, data, perm, seg_ids, capacity, kind):
     if perm is None:
         from ..kernels import _global_reduce
         return _global_reduce(xp, data, kind, capacity)
-    return segment_reduce(xp, data[perm], seg_ids, capacity, kind)
+    with _scope(xp, "agg.sort"):
+        with _scope(xp, "agg.sort.permute"):
+            data_s = data[perm]
+        with _scope(xp, "agg.sort.segment"):
+            return segment_reduce(xp, data_s, seg_ids, capacity, kind)
 
 
 def _emit_group_keys(xp, keys, key_dts, key_vals, perm, seg_ids, is_start,
@@ -452,9 +466,11 @@ def _emit_group_keys(xp, keys, key_dts, key_vals, perm, seg_ids, is_start,
     (names, vectors) for the output key columns."""
     names, vectors = [], []
     for k, dt, v in zip(keys, key_dts, key_vals):
-        kd = _scatter_starts(xp, v.data[perm], seg_ids, is_start, capacity)
-        kv = None if v.valid is None else _scatter_starts(
-            xp, v.valid[perm], seg_ids, is_start, capacity)
+        with _scope(xp, "agg.sort"), _scope(xp, "agg.sort.segment"):
+            kd = _scatter_starts(xp, v.data[perm], seg_ids, is_start,
+                                 capacity)
+            kv = None if v.valid is None else _scatter_starts(
+                xp, v.valid[perm], seg_ids, is_start, capacity)
         names.append(k.name)
         vectors.append(ColumnVector(kd.astype(dt.np_dtype), dt, kv,
                                     v.dictionary))
@@ -835,7 +851,7 @@ class DGlobalAggregate(DNode):
             reduced_local = [xp.sum(s.data) if s.kind == "sum"
                              else (xp.min(s.data) if s.kind == "min" else xp.max(s.data))
                              for s in specs]
-            reduced = [lax.psum(r, DATA_AXIS) if s.kind == "sum"
+            reduced = [psum_arrays([r])[0] if s.kind == "sum"
                        else (pmin(r) if s.kind == "min" else pmax(r))
                        for r, s in zip(reduced_local, specs)]
             out = func.finish(xp, [xp.broadcast_to(r, (1,)) for r in reduced])

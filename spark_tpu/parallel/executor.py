@@ -19,9 +19,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from jax import shard_map
 
 from .. import config as C
+from .. import tracing
 from ..columnar import ColumnBatch, ColumnVector, pad_capacity
 from ..expressions import Col
 from ..kernels import compact
+from ..memory import batch_nbytes
 from ..sql import physical as P
 from ..sql.joins import PJoin, plan_join_raw, _JoinOutput
 from ..sql.logical import Aggregate, Distinct, FileRelation, Filter, Join, Limit, LocalRelation, LogicalPlan, Project, RangeRelation, Sample, Sort, SubqueryAlias
@@ -286,7 +288,8 @@ class DistributedExecution:
                                      skew_override=skew,
                                      join_factor_override=jf,
                                      agg_shrink_override=shrink)
-        pq = planner.plan(optimized)
+        with tracing.span("plan"):
+            pq = planner.plan(optimized)
         if check_caps:
             # exact per-join allocation guard after growth in THIS
             # execution (attributes the violation to the join owning the
@@ -295,21 +298,29 @@ class DistributedExecution:
                                           "distributed join")
         key = f"dist{self.n}:" + pq.physical.key()
 
-        fn = self.session._jit_cache.get(key)
+        # the distributed jit cache, and beside it what each trace noted
+        with tracing.span("stage.lookup", hit=True) as sp:
+            fn = self.session._jit_cache.get(key)
+            sp.attrs["hit"] = fn is not None
+        notes = self.session._jit_notes.setdefault(key, {})
+        dev_leaves = tuple(self._shard_leaf(b) for b in pq.leaves)
         if fn is None:
             fn = jax.jit(shard_program(pq.physical, self.mesh))
             self.session._jit_cache[key] = fn
-
-        dev_leaves = tuple(self._shard_leaf(b) for b in pq.leaves)
-        result, n_rows, ex_r, join_r, shr_need = fn(dev_leaves)
-        ex_ratio = float(np.asarray(ex_r))
-        join_ratio = float(np.asarray(join_r))
-        shrink_need = int(np.asarray(shr_need))
-        if ex_ratio > 0.0 or join_ratio > 0.0 or shrink_need > 0:
-            return result, ex_ratio, join_ratio, shrink_need
-        host = result.to_host()
+            timed = tracing.fresh_jit("executor.jit_cache")
+        else:
+            timed = tracing.span("stage.dispatch")
+        with timed, tracing.collecting(notes):
+            result, n_rows, ex_r, join_r, shr_need = fn(dev_leaves)
+        with tracing.span("d2h") as sp:  # the ratio fetch waits for the step
+            ex_ratio = float(np.asarray(ex_r))
+            join_ratio = float(np.asarray(join_r))
+            shrink_need = int(np.asarray(shr_need))
+            if ex_ratio > 0.0 or join_ratio > 0.0 or shrink_need > 0:
+                return result, ex_ratio, join_ratio, shrink_need
+            host = result.to_host()
+            sp.attrs["bytes"] = batch_nbytes(host)
         return compact(np, host), 0.0, 0.0, 0
-
 
 
     def _shard_leaf(self, batch: ColumnBatch) -> ColumnBatch:
@@ -324,34 +335,35 @@ def shard_program(physical, mesh: Mesh):
     from .collective import pmax
 
     def shard_fn(leaves):
-        ctx = P.ExecContext(jnp, list(leaves))
-        ctx.shard_offset = lax.axis_index(DATA_AXIS).astype(np.int64) << 48
-        out = physical.run(ctx)
-        out = compact(jnp, out)
-        n_rows = lax.psum(out.num_rows(), DATA_AXIS)
-        # per-kind worst overflow RATIO (lost rows / capacity),
-        # pmax'd over shards — sizes the adaptive retry
-        ex_r = jnp.zeros((), jnp.float32)
-        join_r = jnp.zeros((), jnp.float32)
-        # agg-shrink: absolute NEEDED capacity (lost + bound), 0
-        # when nothing overflowed — growth is a row count, not a
-        # factor
-        shr_need = jnp.zeros((), jnp.int64)
-        for f, kind, cap in zip(ctx.flags, ctx.flag_kinds,
-                                ctx.flag_caps):
-            if kind == "shrink":
-                lost = f.astype(jnp.int64)
-                shr_need = jnp.maximum(
-                    shr_need,
-                    jnp.where(lost > 0, lost + np.int64(cap),
-                              np.int64(0)))
-                continue
-            r = f.astype(jnp.float32) / np.float32(max(cap, 1))
-            if kind == "exchange":
-                ex_r = jnp.maximum(ex_r, r)
-            else:
-                join_r = jnp.maximum(join_r, r)
-        return (out, n_rows, pmax(ex_r), pmax(join_r), pmax(shr_need))
+        with tracing.scope("stage.step"):
+            ctx = P.ExecContext(jnp, list(leaves))
+            ctx.shard_offset = lax.axis_index(DATA_AXIS).astype(np.int64) << 48
+            out = physical.run(ctx)
+            out = compact(jnp, out)
+            n_rows = lax.psum(out.num_rows(), DATA_AXIS)
+            # per-kind worst overflow RATIO (lost rows / capacity),
+            # pmax'd over shards — sizes the adaptive retry
+            ex_r = jnp.zeros((), jnp.float32)
+            join_r = jnp.zeros((), jnp.float32)
+            # agg-shrink: absolute NEEDED capacity (lost + bound), 0
+            # when nothing overflowed — growth is a row count, not a
+            # factor
+            shr_need = jnp.zeros((), jnp.int64)
+            for f, kind, cap in zip(ctx.flags, ctx.flag_kinds,
+                                    ctx.flag_caps):
+                if kind == "shrink":
+                    lost = f.astype(jnp.int64)
+                    shr_need = jnp.maximum(
+                        shr_need,
+                        jnp.where(lost > 0, lost + np.int64(cap),
+                                  np.int64(0)))
+                    continue
+                r = f.astype(jnp.float32) / np.float32(max(cap, 1))
+                if kind == "exchange":
+                    ex_r = jnp.maximum(ex_r, r)
+                else:
+                    join_r = jnp.maximum(join_r, r)
+            return (out, n_rows, pmax(ex_r), pmax(join_r), pmax(shr_need))
 
     return shard_map(
         shard_fn, mesh=mesh,
@@ -365,6 +377,11 @@ def shard_program(physical, mesh: Mesh):
 def shard_leaf(mesh: Mesh, n: int, batch: ColumnBatch) -> ColumnBatch:
     """Pad a host batch so rows split evenly over shards, then device_put
     with row sharding."""
+    with tracing.span("h2d", bytes=batch_nbytes(batch)):
+        return _shard_leaf(mesh, n, batch)
+
+
+def _shard_leaf(mesh: Mesh, n: int, batch: ColumnBatch) -> ColumnBatch:
     per = pad_capacity(max(-(-batch.capacity // n), 1))
     total = per * n
     sharding = NamedSharding(mesh, PartitionSpec(DATA_AXIS))

@@ -80,6 +80,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional
 
 from . import config as C
+from . import tracing
 from .metrics import Source
 from .serving import AdmissionController, AdmissionRejected, PlanCache
 
@@ -525,9 +526,17 @@ class SQLServer:
     # -- statement execution ---------------------------------------------
     def _run_sql(self, text: str, sid: Optional[str],
                  stmt_id: Optional[str]) -> dict:
+        ss = self._resolve(sid)          # unknown session → 404, nothing
+        # where an HTTP statement first enters the program: the pool
+        # thread that runs it takes this id over
+        with tracing.statement() as trace_id:
+            return self._run_traced(ss, text, sid, stmt_id, trace_id)
+
+    def _run_traced(self, ss: "_ServerSession", text: str,
+                    sid: Optional[str], stmt_id: Optional[str],
+                    trace_id: int) -> dict:
         from .parallel.hostshuffle import ExchangeFetchFailed
 
-        ss = self._resolve(sid)          # unknown session → 404, nothing
         cost_key = _cost_key(text)
         # admission BEFORE registration: a rejected statement leaves no
         # trace — no registry entry, no queue slot, no partial execution
@@ -536,11 +545,12 @@ class SQLServer:
                 (1 if (ss.running_stmt or ss.draining) else 0)
         # raises AdmissionRejected → 429; a known shape's Retry-After
         # comes from ITS duration history, not the global EWMA
-        self._admission.admit(depth, cost_key=cost_key)
+        with tracing.span("admission.wait", statement=trace_id):
+            self._admission.admit(depth, cost_key=cost_key)
         admit_t = time.time()
         try:
             try:
-                return self._run_admitted(ss, text, sid, stmt_id)
+                return self._run_admitted(ss, text, sid, stmt_id, trace_id)
             except ExchangeFetchFailed:
                 # a worker died and the in-query lineage recovery
                 # exhausted its budget (or was disabled): the exchange
@@ -552,7 +562,7 @@ class SQLServer:
                 # fetch failure surfaces to the client.
                 with self._reg_lock:
                     self._statement_readmits += 1
-                return self._run_admitted(ss, text, sid, stmt_id)
+                return self._run_admitted(ss, text, sid, stmt_id, trace_id)
         finally:
             # release feeds the EWMAs behind Retry-After with end-to-end
             # (queue + execute) latency — what a retrying client sees
@@ -573,7 +583,8 @@ class SQLServer:
         return text.strip().lower().startswith("select")
 
     def _run_admitted(self, ss: _ServerSession, text: str,
-                      sid: Optional[str], stmt_id: Optional[str]) -> dict:
+                      sid: Optional[str], stmt_id: Optional[str],
+                      trace_id: int) -> dict:
         from .sql.session import QueryCancelled
 
         if self._offloadable(ss, text):
@@ -597,6 +608,11 @@ class SQLServer:
         ss.last_used = time.time()
 
         def work() -> dict:
+            with tracing.adopt(trace_id), \
+                    tracing.span("http.statement", statement=trace_id):
+                return run_locked()
+
+        def run_locked() -> dict:
             with ss.lock:                # session state is single-writer
                 # order matters vs /cancel: the flag clears BEFORE the
                 # status becomes observable as "running", and a cancel
@@ -640,8 +656,10 @@ class SQLServer:
                     ss.session._last_plan_cache_info = None
                     df = ss.session.sql(stmt.query)
                     columns = list(df.schema.names)
-                    rows = [[_json_safe(v) for v in r]
-                            for r in df.collect()]
+                    collected = df.collect()
+                    with tracing.span("http.encode", rows=len(collected)):
+                        rows = [[_json_safe(v) for v in r]
+                                for r in collected]
                     info = getattr(ss.session,
                                    "_last_plan_cache_info", None) or {}
                     return {"columns": columns, "rows": rows,
@@ -797,6 +815,7 @@ class SQLServer:
             "iciActivity": ici,
             "runActivity": runact,
             "metrics": self.session.metricsSystem.snapshots(),
+            "trace": tracing.summary(),
         }
         if self._plan_cache is not None:
             out["planCache"] = self._plan_cache.stats()

@@ -60,6 +60,7 @@ import numpy as np
 
 from .. import config as C
 from .. import expressions as E
+from .. import tracing
 from .. import types as T
 
 __all__ = ["PlanCache", "PlanFingerprint", "fingerprint"]
@@ -235,7 +236,7 @@ class _Entry:
 
     __slots__ = ("key", "physical", "recipes", "leaf_schemas", "slots",
                  "fn", "meta", "paths", "conf_snapshot", "nbytes",
-                 "planning_ms", "hits", "built_at")
+                 "planning_ms", "hits", "built_at", "notes", "first")
 
     def __init__(self, key: str, physical, recipes, leaf_schemas, slots,
                  fn, meta, paths, conf_snapshot, nbytes):
@@ -252,6 +253,8 @@ class _Entry:
         self.planning_ms = 0.0
         self.hits = 0
         self.built_at = time.time()
+        self.notes: Dict[str, List] = {}   # what its trace noted (tracing)
+        self.first = True               # not called yet: the call compiles
 
 
 class _StageEntry:
@@ -457,12 +460,14 @@ class PlanCache:
         if plan_has_slow_udf(qe.optimized) \
                 and not backend_supports_callbacks():
             return None                  # interpreted lane: nothing to cache
-        fp = fingerprint(session, qe.optimized)
+        with tracing.span("plancache.lookup", hit=False) as sp:
+            fp = fingerprint(session, qe.optimized)
+            entry = None if fp is None else self._get(fp.key)
+            sp.attrs["hit"] = entry is not None
         if fp is None:
             with self._lock:
                 self.uncacheable += 1
             return None
-        entry = self._get(fp.key)
         if entry is None:
             with self._lock:
                 if fp.key in self._poisoned:
@@ -517,16 +522,20 @@ class PlanCache:
         session._last_plan_cache_info = info
         if not session.conf.get(C.CODEGEN_ENABLED):
             return thunk()
-        fp = fingerprint(session, qe.optimized)
+        with tracing.span("plancache.lookup", hit=False) as sp:
+            fp = fingerprint(session, qe.optimized)
+            entry = None
+            if fp is not None:
+                key = f"stage|{kind}|{fp.key}"
+                with self._lock:
+                    entry = self._stage_entries.get(key)
+                    if entry is not None:
+                        self._stage_entries.move_to_end(key)
+            sp.attrs["hit"] = entry is not None
         if fp is None:
             with self._lock:
                 self.uncacheable += 1
             return thunk()
-        key = f"stage|{kind}|{fp.key}"
-        with self._lock:
-            entry = self._stage_entries.get(key)
-            if entry is not None:
-                self._stage_entries.move_to_end(key)
         if entry is not None:
             out = thunk()
             with self._lock:
@@ -584,6 +593,7 @@ class PlanCache:
             return None
         import jax.numpy as jnp
         physical = pq.physical
+        stage_scope = qe._stage_scope
         slots = fp.slots                 # entry owns THIS plan's literals
         meta: Dict[Tuple, Tuple] = {}
 
@@ -591,9 +601,10 @@ class PlanCache:
             E._slot_bindings.map = {
                 id(lit): p for lit, p in zip(slots, params)}
             try:
-                ctx = P.ExecContext(jnp, list(leaves))
-                out = physical.run(ctx)
-                c = compact(jnp, out)
+                with tracing.scope(stage_scope):
+                    ctx = P.ExecContext(jnp, list(leaves))
+                    out = physical.run(ctx)
+                    c = compact(jnp, out)
                 shape_key = tuple(b.capacity for b in leaves)
                 meta[shape_key] = (list(ctx.flag_caps),
                                    list(ctx.flag_kinds),
@@ -636,8 +647,9 @@ class PlanCache:
 
     def _run_entry(self, qe, entry: _Entry, fp: PlanFingerprint,
                    first_leaves=None) -> Optional[Any]:
-        from ..sql.planner import (PlannedQuery, _overflow_ratio,
-                                   _plan_reserve_bytes, _slice_to_host)
+        from ..sql.planner import (PlannedQuery, _leaves_nbytes,
+                                   _overflow_ratio, _plan_reserve_bytes,
+                                   _slice_to_host)
         session = qe.session
         if first_leaves is not None:
             leaves = first_leaves
@@ -655,16 +667,25 @@ class PlanCache:
         if mem is not None:
             mem.acquire_execution(owner, _plan_reserve_bytes(pq))
         try:
-            dev_leaves = tuple(b.to_device() for b in leaves)
-            result, n_rows, flags, metric_vals = entry.fn(dev_leaves, params)
+            with tracing.span("h2d", bytes=_leaves_nbytes(leaves)):
+                dev_leaves = tuple(b.to_device() for b in leaves)
+            # a fresh ``jax.jit`` object per entry, outside the stage
+            # cache: its first call traces and compiles
+            timed = tracing.fresh_jit("plancache._build_and_run") \
+                if entry.first else tracing.span("stage.dispatch")
+            with timed, tracing.collecting(entry.notes):
+                result, n_rows, flags, metric_vals = entry.fn(dev_leaves,
+                                                              params)
+            entry.first = False
             shape_key = tuple(b.capacity for b in leaves)
             caps, kinds, mkeys = entry.meta.get(shape_key, ([], [], []))
-            int_flags = [int(np.asarray(f)) for f in flags]
-            if _overflow_ratio(int_flags, caps) > 0.0:
-                return None              # needs adaptive replan: fall back
-            qe.metrics = {k: int(np.asarray(v))
-                          for k, v in zip(mkeys, metric_vals)}
-            return _slice_to_host(result, int(np.asarray(n_rows)))
+            with tracing.span("d2h"):    # the flag fetch waits for the step
+                int_flags = [int(np.asarray(f)) for f in flags]
+                if _overflow_ratio(int_flags, caps) > 0.0:
+                    return None          # needs adaptive replan: fall back
+                qe.metrics = {k: int(np.asarray(v))
+                              for k, v in zip(mkeys, metric_vals)}
+                return _slice_to_host(result, int(np.asarray(n_rows)))
         finally:
             if mem is not None:
                 mem.release_execution(owner)

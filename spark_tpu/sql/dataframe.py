@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Any, List, Optional, Tuple, Union
 
+from .. import tracing
 from .. import types as T
 from ..aggregates import Avg, Count, CountStar, Max, Min, Sum
 from ..columnar import ColumnBatch
@@ -39,6 +40,13 @@ class DataFrame:
         self.session = session
         self._plan = plan
         self._cached: Optional[str] = None   # device-cache key
+        #: the statement ``session.sql`` parsed this plan under; the first
+        #: action takes it (0: the QueryExecution allots one)
+        self._statement_id = 0
+
+    def _take_statement(self) -> int:
+        sid, self._statement_id = self._statement_id, 0
+        return sid
 
     # -- metadata ---------------------------------------------------------
     @property
@@ -47,7 +55,8 @@ class DataFrame:
 
     def _qe_analyzed(self) -> L.LogicalPlan:
         from .analyzer import Analyzer
-        return Analyzer(self.session.catalog).analyze(self._plan)
+        with tracing.span("analyze"):
+            return Analyzer(self.session.catalog).analyze(self._plan)
 
     @property
     def columns(self) -> List[str]:
@@ -341,7 +350,8 @@ class DataFrame:
             if hit is not None:
                 return hit
         from .planner import QueryExecution
-        return QueryExecution(self.session, self._plan).execute()
+        with tracing.statement(self._take_statement()):
+            return QueryExecution(self.session, self._plan).execute()
 
     # -- complex-type output (maps/structs) -------------------------------
     def _flatten_complex(self):
@@ -423,11 +433,16 @@ class DataFrame:
         return [Row([build(s, r) for s in spec], names) for r in rows]
 
     def collect(self) -> List[Row]:
-        flat, spec = self._flatten_complex()
-        batch = flat._execute()
-        if spec is None:
-            return [Row(r, batch.names) for r in batch.to_pylist()]
-        return self._assemble_rows(batch.to_pylist(), spec)
+        with tracing.statement(self._take_statement()):
+            flat, spec = self._flatten_complex()
+            batch = flat._execute()
+            with tracing.span("collect.rows") as sp:
+                if spec is None:
+                    rows = [Row(r, batch.names) for r in batch.to_pylist()]
+                else:
+                    rows = self._assemble_rows(batch.to_pylist(), spec)
+                sp.attrs["rows"] = len(rows)
+            return rows
 
     def count(self) -> int:
         agg = L.Aggregate([], [(CountStar(), "count")], self._plan)

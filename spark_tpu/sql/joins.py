@@ -37,8 +37,8 @@ from .. import types as T
 from ..columnar import (ColumnBatch, ColumnVector, bump_run_aware,
                         pad_capacity, unmaterialized_runs)
 from ..expressions import AnalysisException, Col, EQ, EvalContext, Expression, Hash64
-from ..kernels import (_POSITIONAL_EXPRS, multi_key_argsort, searchsorted,
-                       take_batch)
+from ..kernels import (_POSITIONAL_EXPRS, _scope, multi_key_argsort,
+                       searchsorted, take_batch)
 from .logical import Join
 from . import physical as P
 
@@ -375,147 +375,172 @@ class PJoin(P.PhysicalPlan):
         probe_live = probe.row_valid_or_true()
         build_live = build.row_valid_or_true()
 
-        # exact int64 encodings per key pair (None → hashB fallback for
-        # that pair's verification).  A single probe key riding an
-        # unmaterialized run vector encodes at RUN-HEAD granularity —
-        # one binary search per run of identical keys, expanded below.
-        run_rid = None
-        encs = None
-        if xp is np and len(self.key_pairs) == 1:
-            rh = self._run_head_encode(probe, bctx)
-            if rh is not None:
-                encs, run_rid = rh
-        if encs is None:
-            encs = [_exact_encode_pair(pctx, bctx, l, r)
-                    for l, r in self.key_pairs]
+        # the phases below are the device scopes of a join (tracing.py):
+        # join.keys, join.build_sort, join.probe, join.expand, join.gather
+        with _scope(xp, "join.keys"):
+            # exact int64 encodings per key pair (None → hashB fallback for
+            # that pair's verification).  A single probe key riding an
+            # unmaterialized run vector encodes at RUN-HEAD granularity —
+            # one binary search per run of identical keys, expanded below.
+            run_rid = None
+            encs = None
+            if xp is np and len(self.key_pairs) == 1:
+                rh = self._run_head_encode(probe, bctx)
+                if rh is not None:
+                    encs, run_rid = rh
+            if encs is None:
+                encs = [_exact_encode_pair(pctx, bctx, l, r)
+                        for l, r in self.key_pairs]
 
-        if len(encs) == 1 and encs[0] is not None:
-            # EXACT search path: sort/search the encoded value itself —
-            # no hash, collisions impossible by construction
-            p_enc, p_val, b_enc, b_val = encs[0]
-            b_ok = build_live if b_val is None else (build_live & b_val)
-            # lexicographic (flag, key) sort puts valid keys first sorted
-            # by value; null/dead rows sink into an INT64_MAX-keyed suffix
-            b_flag = xp.where(b_ok, np.int8(0), np.int8(1))
-            if self.presorted_build:
+            exact = len(encs) == 1 and encs[0] is not None
+            if exact:
+                # EXACT search path: sort/search the encoded value itself —
+                # no hash, collisions impossible by construction
+                p_enc, p_val, b_enc, b_val = encs[0]
+                b_ok = build_live if b_val is None else (build_live & b_val)
+                # lexicographic (flag, key) sort puts valid keys first
+                # sorted by value; null/dead rows sink into an
+                # INT64_MAX-keyed suffix
+                b_flag = xp.where(b_ok, np.int8(0), np.int8(1))
+                sort_keys = [b_flag, b_enc]
+            else:
+                # multi-key / unencodable: combined-hash search with
+                # sentinels.  Mixed int/float pairs hash BOTH sides as
+                # float64 — int64(-7) and float64(-7.0) have different
+                # hashes otherwise, silently dropping every cross-typed
+                # match
+                from ..expressions import Cast
+                from .. import types as _T
+                lks, rks = [], []
+                for l, r in self.key_pairs:
+                    try:
+                        ldt = l.data_type(probe.schema)
+                        rdt = r.data_type(build.schema)
+                        if ldt.is_numeric and rdt.is_numeric \
+                                and ldt.is_fractional != rdt.is_fractional:
+                            l, r = Cast(l, _T.float64), Cast(r, _T.float64)
+                    except Exception:
+                        pass
+                    lks.append(l)
+                    rks.append(r)
+                pa, _pb = _join_keys(pctx, lks, _NULL_PROBE, None)
+                ba, _bb = _join_keys(bctx, rks, _NULL_BUILD, _DEAD_BUILD)
+                sort_keys = [ba]
+
+        with _scope(xp, "join.build_sort"):
+            if exact and self.presorted_build:
                 # range exchange delivered the build side already merged
                 # into (flag, key) order — identity perm, no device sort
                 perm = xp.arange(build.capacity, dtype=np.int32)
             else:
-                perm = multi_key_argsort(xp, [b_flag, b_enc],
-                                         build.capacity)
-            b_flag_s = b_flag[perm]
-            ba_s = xp.where(b_flag_s == 0, b_enc[perm], _DEAD_BUILD)
-            pa = p_enc
-            if run_rid is not None and p_val is not None:
-                p_val = p_val[run_rid]       # head-sized → row-sized
-            p_ok = probe_live if p_val is None else (probe_live & p_val)
-        else:
-            # multi-key / unencodable: combined-hash search with sentinels.
-            # Mixed int/float pairs hash BOTH sides as float64 — int64(-7)
-            # and float64(-7.0) have different hashes otherwise, silently
-            # dropping every cross-typed match
-            from ..expressions import Cast
-            from .. import types as _T
-            lks, rks = [], []
-            for l, r in self.key_pairs:
-                try:
-                    ldt = l.data_type(probe.schema)
-                    rdt = r.data_type(build.schema)
-                    if ldt.is_numeric and rdt.is_numeric \
-                            and ldt.is_fractional != rdt.is_fractional:
-                        l, r = Cast(l, _T.float64), Cast(r, _T.float64)
-                except Exception:
-                    pass
-                lks.append(l)
-                rks.append(r)
-            pa, _pb = _join_keys(pctx, lks, _NULL_PROBE, None)
-            ba, _bb = _join_keys(bctx, rks, _NULL_BUILD, _DEAD_BUILD)
-            perm = multi_key_argsort(xp, [ba], build.capacity)
-            ba_s = ba[perm]
-            p_ok = probe_live
-        build_s = take_batch(xp, build, perm)
+                perm = multi_key_argsort(xp, sort_keys, build.capacity)
+            if exact:
+                b_flag_s = b_flag[perm]
+                ba_s = xp.where(b_flag_s == 0, b_enc[perm], _DEAD_BUILD)
+            else:
+                ba_s = ba[perm]
+        # (the probe side's key validity sits between the two halves of the
+        # build sort so that the traced program keeps its order of ops: a
+        # compiled program in a persistent cache stays valid)
+        with _scope(xp, "join.keys"):
+            if exact:
+                pa = p_enc
+                if run_rid is not None and p_val is not None:
+                    p_val = p_val[run_rid]       # head-sized → row-sized
+                p_ok = probe_live if p_val is None else (probe_live & p_val)
+            else:
+                p_ok = probe_live
+        with _scope(xp, "join.build_sort"):
+            build_s = take_batch(xp, build, perm)
 
-        lo = searchsorted(xp, ba_s, pa, side="left")
-        hi = searchsorted(xp, ba_s, pa, side="right")
-        if run_rid is not None:
-            # expand the per-run search results (and the verification
-            # arrays) to row granularity: every row of a run shares its
-            # key, so the gather reproduces dense execution exactly
-            lo, hi = lo[run_rid], hi[run_rid]
-            pe0, pv0, be0, bv0 = encs[0]
-            encs[0] = (pe0[run_rid],
-                       None if pv0 is None else pv0[run_rid], be0, bv0)
-        counts = xp.where(p_ok, (hi - lo).astype(np.int64), 0)
-        matched_hash = counts > 0
+        with _scope(xp, "join.probe"):
+            lo = searchsorted(xp, ba_s, pa, side="left")
+            hi = searchsorted(xp, ba_s, pa, side="right")
+            if run_rid is not None:
+                # expand the per-run search results (and the verification
+                # arrays) to row granularity: every row of a run shares its
+                # key, so the gather reproduces dense execution exactly
+                lo, hi = lo[run_rid], hi[run_rid]
+                pe0, pv0, be0, bv0 = encs[0]
+                encs[0] = (pe0[run_rid],
+                           None if pv0 is None else pv0[run_rid], be0, bv0)
+            counts = xp.where(p_ok, (hi - lo).astype(np.int64), 0)
+            matched_hash = counts > 0
 
         out_cap = pad_capacity(int(probe.capacity * max(self.factor, 0.1)))
-        if how in ("left", "full"):
-            counts_eff = xp.where(probe_live, xp.maximum(counts, 1), 0)
-        else:
-            counts_eff = counts
+        with _scope(xp, "join.expand"):
+            if how in ("left", "full"):
+                counts_eff = xp.where(probe_live, xp.maximum(counts, 1), 0)
+            else:
+                counts_eff = counts
 
-        offsets = xp.cumsum(counts_eff) - counts_eff   # exclusive prefix
-        total = xp.sum(counts_eff)
+            offsets = xp.cumsum(counts_eff) - counts_eff   # exclusive prefix
+            total = xp.sum(counts_eff)
 
-        # output slot j → probe row i and duplicate index d
-        slot = xp.arange(out_cap, dtype=np.int64)
-        i = searchsorted(xp, offsets + counts_eff, slot, side="right")
-        i = xp.clip(i, 0, probe.capacity - 1)
-        d = slot - offsets[i]
-        in_range = slot < total
-        has_match = matched_hash[i]
-        b_row = xp.clip(lo[i] + d, 0, build.capacity - 1)
+            # output slot j → probe row i and duplicate index d
+            slot = xp.arange(out_cap, dtype=np.int64)
+            i = searchsorted(xp, offsets + counts_eff, slot, side="right")
+            i = xp.clip(i, 0, probe.capacity - 1)
+            d = slot - offsets[i]
+            in_range = slot < total
+            has_match = matched_hash[i]
+            b_row = xp.clip(lo[i] + d, 0, build.capacity - 1)
 
-        # EXACT per-pair verification (null-aware): a pair survives only
-        # if every key column compares equal with both sides valid
-        build_live_s = build_live[perm]
-        verify = in_range & has_match & build_live_s[b_row]
-        hashb_needed = any(e is None for e in encs)
-        for e in encs:
-            if e is not None:
-                pe, pv, be, bv = e
-                be_s = be[perm]
-                ok = pe[i] == be_s[b_row]
-                if pv is not None:
-                    ok = ok & pv[i]
-                if bv is not None:
-                    ok = ok & bv[perm][b_row]
-                verify = verify & ok
-        if hashb_needed:
-            # unencodable pairs: fall back to the independent second hash
-            # over exactly those pairs (collision ~2^-64, documented)
-            exprs_l = [l for (l, _), e in zip(self.key_pairs, encs) if e is None]
-            exprs_r = [r for (_, r), e in zip(self.key_pairs, encs) if e is None]
-            pb2 = pctx.broadcast(_Hash64B(*exprs_l).eval(pctx)).data
-            bb2 = bctx.broadcast(_Hash64B(*exprs_r).eval(bctx)).data[perm]
-            verify = verify & (pb2[i] == bb2[b_row])
+            # EXACT per-pair verification (null-aware): a pair survives only
+            # if every key column compares equal with both sides valid
+            build_live_s = build_live[perm]
+            verify = in_range & has_match & build_live_s[b_row]
+            hashb_needed = any(e is None for e in encs)
+            for e in encs:
+                if e is not None:
+                    pe, pv, be, bv = e
+                    be_s = be[perm]
+                    ok = pe[i] == be_s[b_row]
+                    if pv is not None:
+                        ok = ok & pv[i]
+                    if bv is not None:
+                        ok = ok & bv[perm][b_row]
+                    verify = verify & ok
+            if hashb_needed:
+                # unencodable pairs: fall back to the independent second
+                # hash over exactly those pairs (collision ~2^-64,
+                # documented)
+                exprs_l = [l for (l, _), e in zip(self.key_pairs, encs)
+                           if e is None]
+                exprs_r = [r for (_, r), e in zip(self.key_pairs, encs)
+                           if e is None]
+                pb2 = pctx.broadcast(_Hash64B(*exprs_l).eval(pctx)).data
+                bb2 = bctx.broadcast(
+                    _Hash64B(*exprs_r).eval(bctx)).data[perm]
+                verify = verify & (pb2[i] == bb2[b_row])
 
-        # assemble the combined (probe row, build row) batch for each slot;
-        # needed before existence when a residual ON conjunct participates
-        # in the match decision
-        left_out = take_batch(xp, probe, i)
-        right_out = take_batch(xp, build_s, b_row)
-        names: List[str] = list(left_out.names) + list(right_out.names)
-        raw_vectors: List[ColumnVector] = \
-            list(left_out.vectors) + list(right_out.vectors)
+        with _scope(xp, "join.gather"):
+            # assemble the combined (probe row, build row) batch for each
+            # slot; needed before existence when a residual ON conjunct
+            # participates in the match decision
+            left_out = take_batch(xp, probe, i)
+            right_out = take_batch(xp, build_s, b_row)
+            names: List[str] = list(left_out.names) + list(right_out.names)
+            raw_vectors: List[ColumnVector] = \
+                list(left_out.vectors) + list(right_out.vectors)
 
-        if self.residual is not None:
-            # non-equi ON conjuncts are part of the MATCH CONDITION
-            # (ExtractEquiJoinKeys keeps them as the join's `condition`):
-            # a pair that fails them is not a match — it does not satisfy
-            # semi-existence and DOES null-extend in outer joins
-            rctx = EvalContext(
-                ColumnBatch(names, raw_vectors, verify, out_cap), xp)
-            rv_res = rctx.broadcast(self.residual.eval(rctx))
-            res_ok = rv_res.data.astype(bool)
-            if rv_res.valid is not None:
-                res_ok = res_ok & rv_res.valid   # NULL condition → no match
-            verify = verify & res_ok
+            if self.residual is not None:
+                # non-equi ON conjuncts are part of the MATCH CONDITION
+                # (ExtractEquiJoinKeys keeps them as the join's
+                # `condition`): a pair that fails them is not a match — it
+                # does not satisfy semi-existence and DOES null-extend in
+                # outer joins
+                rctx = EvalContext(
+                    ColumnBatch(names, raw_vectors, verify, out_cap), xp)
+                rv_res = rctx.broadcast(self.residual.eval(rctx))
+                res_ok = rv_res.data.astype(bool)
+                if rv_res.valid is not None:
+                    res_ok = res_ok & rv_res.valid   # NULL → no match
+                verify = verify & res_ok
 
-        # exact existence per probe row — drives semi/anti and outer
-        # null-extension (never hash-range counts alone)
-        exact_m = _scatter_or(xp, probe.capacity, i, verify)
+            # exact existence per probe row — drives semi/anti and outer
+            # null-extension (never hash-range counts alone)
+            exact_m = _scatter_or(xp, probe.capacity, i, verify)
 
         if hasattr(ctx, "add_flag"):
             ctx.add_flag(xp.maximum(total - out_cap, 0), "join", out_cap)

@@ -99,11 +99,14 @@ def plan_cache_key(node: "LogicalPlan", _memo: Optional[dict] = None) -> str:
     ``repr``/``id`` (recyclable addresses): LocalRelation keys on a
     monotonic batch uid and callables (flatMapGroupsWithState functions)
     on a uid attached the same way.  Pass one ``_memo`` dict across many
-    calls over a shared tree to stay O(n)."""
+    calls over a shared tree to stay O(n): it maps ``id(node)`` to ``(node,
+    key)``, and the node kept beside its key stays alive, so a rewrite that
+    frees nodes between lookups (``transform_up``) cannot hand its address
+    to another subtree."""
     if _memo is not None:
         hit = _memo.get(id(node))
-        if hit is not None:
-            return hit
+        if hit is not None and hit[0] is node:
+            return hit[1]
     if isinstance(node, LocalRelation):
         key = f"LocalRelation#{_batch_uid(node.batch)}"
     else:
@@ -123,7 +126,7 @@ def plan_cache_key(node: "LogicalPlan", _memo: Optional[dict] = None) -> str:
         inner = ",".join(plan_cache_key(c, _memo) for c in node.children)
         key = f"{type(node).__name__}[{';'.join(fields)}]({inner})"
     if _memo is not None:
-        _memo[id(node)] = key
+        _memo[id(node)] = (node, key)
     return key
 
 

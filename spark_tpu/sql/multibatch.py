@@ -33,6 +33,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from .. import config as C
+from .. import tracing
 from .. import types as T
 from .. import wire
 from ..aggregates import First, Max, Min
@@ -46,7 +47,7 @@ from ..kernels import (
 )
 from . import logical as L
 from . import physical as P
-from .planner import Planner, _slice_to_host
+from .planner import Planner, _leaves_nbytes, _slice_to_host
 from .window import WindowNode
 
 _log = logging.getLogger("spark_tpu.multibatch")
@@ -736,16 +737,18 @@ class MultiBatchExecution:
                 E._slot_bindings.map = {
                     id(l): p for l, p in zip(entry_slots, params)}
                 try:
-                    ctx = P.ExecContext(jnp, [leaf])
-                    out = phys.run(ctx)
-                    # compact = a full sort; skip it when the spine
-                    # provably emits live rows as a prefix already
-                    # (aggregation stages scatter groups to slots
-                    # 0..k-1; sorted/limited outputs are prefix-
-                    # compacted by construction) — on TPU this sort was
-                    # the single largest cost of every streamed step
-                    c = out if skip_compact else compact(jnp, out)
-                    return c, c.num_rows()
+                    with tracing.scope("stage.step"):
+                        ctx = P.ExecContext(jnp, [leaf])
+                        out = phys.run(ctx)
+                        # compact = a full sort; skip it when the spine
+                        # provably emits live rows as a prefix already
+                        # (aggregation stages scatter groups to slots
+                        # 0..k-1; sorted/limited outputs are prefix-
+                        # compacted by construction) — on TPU this sort
+                        # was the single largest cost of every streamed
+                        # step
+                        c = out if skip_compact else compact(jnp, out)
+                        return c, c.num_rows()
                 finally:
                     E._slot_bindings.map = None
 
@@ -769,11 +772,13 @@ class MultiBatchExecution:
         """Device placement for one prepared scan batch.  Runs on the
         prefetch thread so the H2D copy overlaps the previous batch's
         device step."""
-        return b.to_device()
+        with tracing.span("h2d", bytes=_leaves_nbytes([b])):
+            return b.to_device()
 
     def _run_batch(self, jstep, leaf) -> List[ColumnBatch]:
         out_dev, n = jstep(leaf)
-        return [_slice_to_host(out_dev, int(np.asarray(n)))]
+        with tracing.span("d2h"):        # the row count waits for the step
+            return [_slice_to_host(out_dev, int(np.asarray(n)))]
 
     # -- merger selection ------------------------------------------------
     def _make_merger(self, spine_schema: T.StructType,
@@ -910,8 +915,9 @@ class MultiBatchExecution:
             # transfer (scan order is deterministic, idx == n_batches-1).
             idx = prep_idx[0]
             prep_idx[0] += 1
-            b = normalize_valids(pad_to_capacity(
-                reencode_strings(raw, fixed_dicts), self.capacity))
+            with tracing.span("scan.prep", rows=raw.capacity):
+                b = normalize_valids(pad_to_capacity(
+                    reencode_strings(raw, fixed_dicts), self.capacity))
             return (b if idx == 0 else None,
                     self._place(b) if idx >= skip else None)
 
@@ -929,11 +935,9 @@ class MultiBatchExecution:
                     continue             # already folded into the merger
                 if hasattr(merger, "next_batch"):
                     merger.next_batch()
-                more = True
-                for host in self._run_batch(jstep, leaf):
-                    if not merger.add(host):
-                        more = False
-                        break
+                runs = self._run_batch(jstep, leaf)
+                with tracing.span("merge", runs=len(runs)):
+                    more = all(merger.add(host) for host in runs)
                 if not more:
                     _log.info("multi-batch scan early exit after %d batches",
                               n_batches)
@@ -944,7 +948,8 @@ class MultiBatchExecution:
                 raise RuntimeError(f"empty file relation {rel!r}")
             _log.info("multi-batch scan: %d batches of <=%d rows merged",
                       n_batches, self.batch_rows)
-            result = merger.finish()
+            with tracing.span("merge", finish=True):
+                result = merger.finish()
             completed = True
         finally:
             # with checkpointing ON, spill run files referenced by the
@@ -1040,14 +1045,15 @@ class DistributedMultiBatchExecution(MultiBatchExecution):
                 E._slot_bindings.map = {
                     id(l): p for l, p in zip(entry_slots, params)}
                 try:
-                    ctx = P.ExecContext(jnp, [leaf])
-                    out = phys.run(ctx)
-                    # same skip as the local step: per-shard outputs of
-                    # the aggregation stages are prefix-live by
-                    # construction, and _run_batch passes whole shard
-                    # slices (mergers consume row_valid), so layout
-                    # requirements are unchanged
-                    return out if skip_compact else compact(jnp, out)
+                    with tracing.scope("stage.step"):
+                        ctx = P.ExecContext(jnp, [leaf])
+                        out = phys.run(ctx)
+                        # same skip as the local step: per-shard outputs
+                        # of the aggregation stages are prefix-live by
+                        # construction, and _run_batch passes whole shard
+                        # slices (mergers consume row_valid), so layout
+                        # requirements are unchanged
+                        return out if skip_compact else compact(jnp, out)
                 finally:
                     E._slot_bindings.map = None
 
@@ -1078,7 +1084,9 @@ class DistributedMultiBatchExecution(MultiBatchExecution):
 
     def _run_batch(self, jstep, leaf) -> List[ColumnBatch]:
         from ..io import _slice_rows
-        out = jstep(leaf).to_host()
+        out = jstep(leaf)
+        with tracing.span("d2h"):        # the fetch waits for the step
+            out = out.to_host()
         per = out.capacity // self.n
         runs = []
         for i in range(self.n):

@@ -15,10 +15,12 @@ baked into the program as constants.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import tracing
 from .. import types as T
 from ..aggregates import AggregateFunction
 from ..columnar import ColumnBatch, ColumnVector, merge_dictionaries, pad_capacity
@@ -55,6 +57,24 @@ class ExecContext:
         self.metrics.append((op_id, label, value))
 
 
+def _under_operator_scope(run):
+    """``run`` under ``scope("<class>#<op_id>")`` on the traced lane, so an
+    HLO op's ``op_name`` names the operator it came from (metadata only:
+    nothing of it enters ``key()`` or a stage fingerprint).  An operator
+    whose ``run`` calls its base class's opens one scope, not two."""
+    @functools.wraps(run)
+    def scoped(self, ctx):
+        if ctx.xp is np or getattr(ctx, "_scoped_op", None) is self:
+            return run(self, ctx)
+        outer, ctx._scoped_op = getattr(ctx, "_scoped_op", None), self
+        try:
+            with tracing.scope(f"{type(self).__name__}#{self.op_id}"):
+                return run(self, ctx)
+        finally:
+            ctx._scoped_op = outer
+    return scoped
+
+
 class PhysicalPlan:
     children: Tuple["PhysicalPlan", ...] = ()
     #: stable preorder position, assigned by the planner; shifted into the
@@ -62,6 +82,11 @@ class PhysicalPlan:
     #: decorrelate across operators (MonotonicallyIncreasingID's partition-id
     #: trick, reapplied to operator identity)
     op_id: int = 0
+
+    def __init_subclass__(cls, **kw):
+        super().__init_subclass__(**kw)
+        if "run" in cls.__dict__:
+            cls.run = _under_operator_scope(cls.__dict__["run"])
 
     @property
     def row_offset(self) -> int:

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import config as C
+from .. import tracing
 from .. import types as T
 from ..columnar import ColumnBatch
 from ..expressions import AnalysisException
@@ -110,6 +111,13 @@ def _slice_to_host(result: ColumnBatch, n: int) -> ColumnBatch:
         vectors.append(ColumnVector(data, v.dtype, valid, v.dictionary))
     rv = None if result.row_valid is None else np.asarray(result.row_valid[:cap])
     return ColumnBatch(result.names, vectors, rv, cap)
+
+
+def _leaves_nbytes(batches) -> int:
+    """Bytes of the batches as the memory ledger counts them (a span's
+    ``bytes``)."""
+    from ..memory import batch_nbytes
+    return sum(batch_nbytes(b) for b in batches)
 
 
 def _row_nbytes(schema: T.StructType) -> int:
@@ -437,6 +445,13 @@ class QueryExecution:
         self._analyzed: Optional[LogicalPlan] = None
         self._optimized: Optional[LogicalPlan] = None
         self._planned: Optional[PlannedQuery] = None
+        #: the statement this execution belongs to (``tracing``), set by
+        #: ``execute``, and its root span while it runs
+        self.statement_id = 0
+        self._root_span: Optional[tracing.span] = None
+        #: the scope of what the stage program does outside any operator:
+        #: ``stage.merge`` where the stage runner materializes a sub-plan
+        self._stage_scope = "stage.step"
         #: per-operator metrics of the last execution:
         #: {(op_id, operator label): output row count}
         self.metrics: Dict[Tuple[int, str], int] = {}
@@ -445,8 +460,9 @@ class QueryExecution:
     def analyzed(self) -> LogicalPlan:
         if self._analyzed is None:
             from .analyzer import Analyzer
-            plan = Analyzer(self.session.catalog).analyze(self.logical)
-            self._analyzed = self._use_cached_data(plan)
+            with tracing.span("analyze"):
+                plan = Analyzer(self.session.catalog).analyze(self.logical)
+                self._analyzed = self._use_cached_data(plan)
         return self._analyzed
 
     def _use_cached_data(self, plan: LogicalPlan) -> LogicalPlan:
@@ -472,13 +488,18 @@ class QueryExecution:
     def optimized(self) -> LogicalPlan:
         if self._optimized is None:
             from .optimizer import Optimizer
-            self._optimized = Optimizer(self.session.conf).optimize(self.analyzed)
+            analyzed = self.analyzed
+            with tracing.span("optimize"):
+                self._optimized = Optimizer(self.session.conf).optimize(
+                    analyzed)
         return self._optimized
 
     @property
     def planned(self) -> PlannedQuery:
         if self._planned is None:
-            self._planned = Planner(self.session).plan(self.optimized)
+            optimized = self.optimized
+            with tracing.span("plan"):
+                self._planned = Planner(self.session).plan(optimized)
         return self._planned
 
     # ------------------------------------------------------------------
@@ -491,6 +512,23 @@ class QueryExecution:
         output buffer) triggers an automatic replan with a factor sized
         from the MEASURED overflow, instead of erroring — the dynamic-shape
         answer to ExchangeCoordinator-style adaptation."""
+        with tracing.statement() as sid:
+            self.statement_id = sid
+            # the root span: ``path`` is the executor ``_execute_inner``
+            # takes (local / multibatch / stages / dist / crossproc)
+            with tracing.span("statement", path="local") as root:
+                self._root_span = root
+                try:
+                    result, end_event = self._execute_posting()
+                finally:
+                    self._root_span = None
+            end_event["phases"] = tracing.statement_phases(sid)
+            self.session._post_event(end_event)
+            return result
+
+    def _execute_posting(self):
+        """(result, the ``SQLExecutionEnd`` event still to post); a failed
+        execution posts its own and raises."""
         import time as _time
         t0 = _time.time()
         self.session._post_event({
@@ -517,12 +555,11 @@ class QueryExecution:
         finally:
             cls._set_thread_active(prev_active)
             self._leak_check()
-        self.session._post_event({
+        return result, {
             "event": "SQLExecutionEnd", "time": _time.time(),
             "durationMs": (_time.time() - t0) * 1000,
             "metrics": {f"{oid}:{lbl}": v
-                        for (oid, lbl), v in self.metrics.items()}})
-        return result
+                        for (oid, lbl), v in self.metrics.items()}}
 
     def _leak_check(self) -> None:
         """Post-query reservation leak check (`Executor.scala:342-357`
@@ -546,6 +583,8 @@ class QueryExecution:
         server session — reports ``cacheHit`` and skips the stage
         compiles (the executables live in the process-local stage
         cache).  Without an attached plan cache this is the thunk."""
+        if self._root_span is not None:
+            self._root_span.attrs["path"] = kind
         plan_cache = getattr(self.session, "_plan_cache", None)
         if plan_cache is None:
             return thunk()
@@ -789,6 +828,7 @@ class QueryExecution:
         def make():
             from ..analysis import maybe_verify_stage_contract
             physical = pq.physical
+            stage_scope = self._stage_scope
             entry_slots = slots          # entry owns THIS plan's literals
             maybe_verify_stage_contract(
                 self.session, SC.Stage(
@@ -801,9 +841,10 @@ class QueryExecution:
                 E._slot_bindings.map = {
                     id(l): p for l, p in zip(entry_slots, params)}
                 try:
-                    ctx = P.ExecContext(jnp, list(leaves))
-                    out = physical.run(ctx)
-                    c = compact(jnp, out)
+                    with tracing.scope(stage_scope):
+                        ctx = P.ExecContext(jnp, list(leaves))
+                        out = physical.run(ctx)
+                        c = compact(jnp, out)
                     # host-side capture at trace time, KEYED BY INPUT
                     # SHAPE: different leaf capacities retrace and may
                     # produce different static flag caps / metric keys
@@ -823,13 +864,19 @@ class QueryExecution:
                                    n_ops=SC.count_ops(pq.physical),
                                    session=self.session)
         meta = entry.aux
-        dev_leaves = tuple(b.to_device() for b in stage_leaves)
+        with tracing.span("h2d", bytes=_leaves_nbytes(stage_leaves)):
+            dev_leaves = tuple(b.to_device() for b in stage_leaves)
         result, n_rows, flags, metric_vals = cache.dispatch(
             entry, dev_leaves, SC.param_values(slots))
         shape_key = tuple(b.capacity for b in stage_leaves)
         flag_caps, flag_kinds, metric_keys = meta.get(shape_key,
                                                       ([], [], []))
-        int_flags = [int(np.asarray(f)) for f in flags]
+        with tracing.span("d2h") as sp:  # the flag fetch waits for the step
+            int_flags = [int(np.asarray(f)) for f in flags]
+            self.metrics = {k: int(np.asarray(v))
+                            for k, v in zip(metric_keys, metric_vals)}
+            host = _slice_to_host(result, int(np.asarray(n_rows)))
+            sp.attrs["bytes"] = _leaves_nbytes([host])
         ratio = _overflow_ratio(int_flags, flag_caps)
         self._last_join_ratios = [
             f / max(c, 1)
@@ -838,9 +885,7 @@ class QueryExecution:
         self._last_shrink = [
             (f, c) for f, c, k in zip(int_flags, flag_caps, flag_kinds)
             if k == "shrink"]
-        self.metrics = {k: int(np.asarray(v))
-                        for k, v in zip(metric_keys, metric_vals)}
-        return _slice_to_host(result, int(np.asarray(n_rows))), ratio
+        return host, ratio
 
     def planned_preview(self) -> PlannedQuery:
         """Side-effect-free plan for explain(): lazy checkpoints are NOT

@@ -12,6 +12,7 @@ from typing import Any, Dict, List, Optional, Union
 
 
 from .. import config as C
+from .. import tracing
 from .. import types as T
 from ..columnar import ColumnBatch
 from ..expressions import AnalysisException
@@ -344,6 +345,7 @@ class SparkSession:
         self._listener_manager = _ListenerManager()
         self._last_qe = None              # most recent QueryExecution
         self._jit_cache: Dict[str, Any] = {}
+        self._jit_notes: Dict[str, dict] = {}     # tracing.note, by key
         # learned capacity factors from adaptive overflow retries, keyed by
         # the pre-adaptation plan key — later executions of the same query
         # shape start at the factor that worked (no repeat overflow+recompile)
@@ -354,10 +356,9 @@ class SparkSession:
         self._cache = DeviceCacheManager(self._memory, self.conf_obj)
         self._query_count = 0
         from ..metrics import MetricsSystem, default_sources
-        self._metrics_system = MetricsSystem(self.conf_obj)
+        self._metrics_system = MetricsSystem()
         for src in default_sources(self):
             self._metrics_system.register_source(src)
-        self._metrics_system.start()
         if self.conf_obj.get(C.DEBUG_NANS):
             import jax
             jax.config.update("jax_debug_nans", True)
@@ -371,8 +372,8 @@ class SparkSession:
 
     @property
     def metricsSystem(self):
-        """Process-gauge sources × sinks (`metrics/MetricsSystem.scala`
-        analog); `report()` snapshots on demand."""
+        """Process-gauge sources (`metrics/MetricsSystem.scala` analog);
+        `snapshots()` reads them on demand."""
         return self._metrics_system
 
     @property
@@ -451,8 +452,8 @@ class SparkSession:
 
     def stop(self) -> None:
         SparkSession._active = None
-        self._metrics_system.stop()
         self._jit_cache.clear()
+        self._jit_notes.clear()
         self._adapted_factors.clear()
         self._cache.clear()
 
@@ -511,10 +512,16 @@ class SparkSession:
 
     def sql(self, query: str) -> DataFrame:
         from . import parser as P
-        st = P.parse_statement(query)
-        if not isinstance(st, P.Command):
-            return DataFrame(self, st)
-        return self._run_command(st)
+        # where a statement first enters the program: its id goes with
+        # the DataFrame to the action that runs it
+        with tracing.statement() as sid:
+            with tracing.span("parse"):
+                st = P.parse_statement(query)
+            if not isinstance(st, P.Command):
+                df = DataFrame(self, st)
+                df._statement_id = sid
+                return df
+            return self._run_command(st)
 
     @staticmethod
     def _unwrap_aliases(node):

@@ -40,6 +40,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from .. import config as C
+from .. import tracing
 
 __all__ = [
     "Stage", "StageCache", "stage_cache", "stage_fingerprint",
@@ -227,12 +228,15 @@ class _CachedStage:
     (shape-keyed trace metadata, slot literals)."""
 
     __slots__ = ("fn", "aux", "n_ops", "compile_ms", "hits", "built_at",
-                 "_first", "_lock")
+                 "notes", "_first", "_lock")
 
     def __init__(self, fn, aux, n_ops: int):
         self.fn = fn
         self.aux = aux
         self.n_ops = n_ops
+        #: trace-time facts of this stage (``tracing.note``), shown with
+        #: every statement that dispatches it
+        self.notes: Dict[str, List] = {}
         self.compile_ms = 0.0
         self.hits = 0
         self.built_at = time.time()
@@ -273,14 +277,17 @@ class StageCache:
                     session.conf.get(C.STAGE_CACHE_MAX_ENTRIES))
             except Exception:
                 pass
-        with self._lock:
-            entry = self._entries.get(key)
-            if entry is not None:
-                self._entries.move_to_end(key)
-                self.hits += 1
-                entry.hits += 1
-                return entry
-            build_lock = self._building.setdefault(key, threading.Lock())
+        with tracing.span("stage.lookup", hit=True, n_ops=n_ops,
+                          key=hash(key) & 0xFFFFFFFF) as sp:
+            with self._lock:
+                entry = self._entries.get(key)
+                if entry is not None:
+                    self._entries.move_to_end(key)
+                    self.hits += 1
+                    entry.hits += 1
+                    return entry
+                build_lock = self._building.setdefault(key, threading.Lock())
+            sp.attrs["hit"] = False
         with build_lock:
             with self._lock:
                 entry = self._entries.get(key)
@@ -290,8 +297,9 @@ class StageCache:
                     entry.hits += 1
                     return entry
             import jax
-            fn, aux = make_fn()
-            entry = _CachedStage(jax.jit(fn), aux, n_ops)
+            with tracing.span("stage.build", n_ops=n_ops):
+                fn, aux = make_fn()
+                entry = _CachedStage(jax.jit(fn), aux, n_ops)
             with self._lock:
                 self.misses += 1
                 self.builds += 1
@@ -308,18 +316,24 @@ class StageCache:
         (jax traces lazily at first call)."""
         with self._lock:
             self.dispatches += 1
-        if entry._first:
-            with entry._lock:
-                if entry._first:
-                    t0 = time.perf_counter()
-                    out = entry.fn(*args)
-                    ms = (time.perf_counter() - t0) * 1000.0
-                    entry.compile_ms = round(ms, 2)
-                    with self._lock:
-                        self.compile_ms += ms
-                    entry._first = False
-                    return out
-        return entry.fn(*args)
+        # a jitted stage traces at its first call and at each new input
+        # shape: what the trace notes is the stage's, whichever call it is
+        with tracing.collecting(entry.notes):
+            if entry._first:
+                with entry._lock:
+                    if entry._first:
+                        t0 = time.perf_counter()
+                        with tracing.span("stage.first_call",
+                                          n_ops=entry.n_ops):
+                            out = entry.fn(*args)
+                        ms = (time.perf_counter() - t0) * 1000.0
+                        entry.compile_ms = round(ms, 2)
+                        with self._lock:
+                            self.compile_ms += ms
+                        entry._first = False
+                        return out
+            with tracing.span("stage.dispatch", n_ops=entry.n_ops):
+                return entry.fn(*args)
 
     # -- introspection -------------------------------------------------
     def peek(self, key: str) -> Optional[_CachedStage]:
@@ -471,7 +485,8 @@ def run_per_op(physical, leaves
 
         n_dispatch += 1
         # deliberately uncached: this IS the per-op re-trace baseline
-        out, flags = jax.jit(step)(dev)
+        with tracing.fresh_jit("stagecompile.run_per_op"):
+            out, flags = jax.jit(step)(dev)
         caps, kinds = cap_box[-1]
         int_flags.extend(int(np.asarray(f)) for f in flags)
         flag_caps.extend(caps)
@@ -485,6 +500,7 @@ def run_per_op(physical, leaves
         return c, c.num_rows()
 
     n_dispatch += 1
-    c, n = jax.jit(fin)(out)
+    with tracing.fresh_jit("stagecompile.run_per_op"):
+        c, n = jax.jit(fin)(out)
     return c, int(np.asarray(n)), n_dispatch, int_flags, flag_caps, \
         flag_kinds

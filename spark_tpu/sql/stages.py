@@ -46,6 +46,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from .. import config as C
+from .. import tracing
 from .. import types as T
 from ..columnar import (
     ColumnBatch, ColumnVector, normalize_valids, pad_capacity,
@@ -151,6 +152,7 @@ def _eager(session, plan: L.LogicalPlan) -> ColumnBatch:
     qe = QueryExecution(session, plan)
     qe._analyzed = plan
     qe._optimized = plan
+    qe._stage_scope = "stage.merge"
     return qe._execute_inner()
 
 
@@ -197,8 +199,9 @@ class _FileStream(BatchStream):
         )
 
         def _prep(raw):
-            b = reencode_strings(raw, self._dicts)
-            return normalize_valids(pad_to_capacity(b, self.capacity))
+            with tracing.span("scan.prep", rows=raw.capacity):
+                b = reencode_strings(raw, self._dicts)
+                return normalize_valids(pad_to_capacity(b, self.capacity))
 
         # decode/pad batch N+1 on a background thread while the stage's
         # device step runs on batch N (double-buffered scan)
@@ -314,9 +317,10 @@ class _MappedStream(BatchStream):
                     E._slot_bindings.map = {
                         id(l): p for l, p in zip(entry_slots, params)}
                     try:
-                        ctx = P.ExecContext(jnp, list(all_leaves))
-                        out = phys.run(ctx)
-                        c = compact(jnp, out)
+                        with tracing.scope("stage.step"):
+                            ctx = P.ExecContext(jnp, list(all_leaves))
+                            out = phys.run(ctx)
+                            c = compact(jnp, out)
                         # host-side capture at trace time, by capacities
                         meta[tuple(b.capacity for b in all_leaves)] = (
                             list(ctx.flag_caps), list(ctx.flag_kinds))
@@ -337,16 +341,17 @@ class _MappedStream(BatchStream):
                 E._slot_bindings.map = {
                     id(l): p for l, p in zip(entry_slots, params)}
                 try:
-                    ctx = P.ExecContext(jnp, list(all_leaves))
-                    ctx.shard_offset = lax.axis_index(DATA_AXIS).astype(
-                        np.int64) << 48
-                    out = phys.run(ctx)
-                    c = compact(jnp, out)
-                    meta[tuple(b.capacity for b in all_leaves)] = (
-                        list(ctx.flag_caps), list(ctx.flag_kinds))
-                    # worst per-shard overflow drives the adaptive retry
-                    flags = [pmax(f) for f in ctx.flags]
-                    return c, lax.psum(c.num_rows(), DATA_AXIS), flags
+                    with tracing.scope("stage.step"):
+                        ctx = P.ExecContext(jnp, list(all_leaves))
+                        ctx.shard_offset = lax.axis_index(
+                            DATA_AXIS).astype(np.int64) << 48
+                        out = phys.run(ctx)
+                        c = compact(jnp, out)
+                        meta[tuple(b.capacity for b in all_leaves)] = (
+                            list(ctx.flag_caps), list(ctx.flag_kinds))
+                        # worst per-shard overflow drives the adaptive retry
+                        flags = [pmax(f) for f in ctx.flags]
+                        return c, lax.psum(c.num_rows(), DATA_AXIS), flags
                 finally:
                     E._slot_bindings.map = None
 
@@ -388,7 +393,9 @@ class _MappedStream(BatchStream):
 
     def _leaf_to_device(self, b: ColumnBatch):
         if self.mesh is None:
-            return b.to_device()
+            from .planner import _leaves_nbytes
+            with tracing.span("h2d", bytes=_leaves_nbytes([b])):
+                return b.to_device()
         from ..parallel.executor import shard_leaf
         from ..parallel.mesh import mesh_shards
         return shard_leaf(self.mesh, mesh_shards(self.mesh), b)
@@ -413,9 +420,12 @@ class _MappedStream(BatchStream):
         for _attempt in range(6):
             out, n, flags = jstep([self._leaf_to_device(b)] + extra)
             caps, kinds = meta.get(self._meta_key(b, extra), ([], []))
-            int_flags = [int(np.asarray(f)) for f in flags]
-            if not any(f > 0 for f in int_flags):
-                return self._to_runs(out, n), (jstep, extra, meta)
+            with tracing.span("d2h"):    # the flag fetch waits for the step
+                int_flags = [int(np.asarray(f)) for f in flags]
+                runs = None if any(f > 0 for f in int_flags) \
+                    else self._to_runs(out, n)
+            if runs is not None:
+                return runs, (jstep, extra, meta)
             cur = list(self._factors) if self._factors else []
             n_joins = sum(1 for k in kinds if k == "join")
             while len(cur) < n_joins:
@@ -1213,11 +1223,8 @@ def _run_breaker(session, stream: BatchStream, breaker: L.LogicalPlan,
             if hasattr(merger, "next_batch"):
                 merger.next_batch()
             runs, compiled = mapped._run_step(compiled, b, phys_wrap)
-            more = True
-            for host in runs:
-                if not merger.add(host):
-                    more = False
-                    break
+            with tracing.span("merge", runs=len(runs)):
+                more = all(merger.add(host) for host in runs)
             if not more:
                 _log.info("stage breaker early exit")
                 break
@@ -1234,8 +1241,9 @@ def _run_breaker(session, stream: BatchStream, breaker: L.LogicalPlan,
             if topk is not None:
                 plan = L.Limit(topk, plan)
             return _eager(session, plan)
-        result = merger.finish()
-        return compact(np, result.to_host())
+        with tracing.span("merge", finish=True):
+            result = merger.finish()
+            return compact(np, result.to_host())
     finally:
         if merger is not None:
             spill = getattr(merger, "spill", None)

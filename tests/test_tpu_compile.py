@@ -90,6 +90,7 @@ def test_pallas_agg_kernel_compiles(one_chip, B):
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
         B=B, L=pallas_agg._L, BB=pallas_agg._BB, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "pallas_agg" in compiled.as_text()      # the kernel's trace name
 
 
 def test_fused_mxu_aggregate_step_compiles(one_chip, on_tpu):
