@@ -311,15 +311,17 @@ class DistributedExecution:
         else:
             timed = tracing.span("stage.dispatch")
         with timed, tracing.collecting(notes):
-            result, n_rows, ex_r, join_r, shr_need = fn(dev_leaves)
+            result, n_rows, ex_r, join_r, shr_need, paths = fn(dev_leaves)
         with tracing.span("d2h") as sp:  # the ratio fetch waits for the step
             ex_ratio = float(np.asarray(ex_r))
             join_ratio = float(np.asarray(join_r))
             shrink_need = int(np.asarray(shr_need))
+            paths = [int(np.asarray(p)) for p in paths]
             if ex_ratio > 0.0 or join_ratio > 0.0 or shrink_need > 0:
                 return result, ex_ratio, join_ratio, shrink_need
             host = result.to_host()
             sp.attrs["bytes"] = batch_nbytes(host)
+        P.record_join_paths(paths, [P.JOIN_PATH] * len(paths))
         return compact(np, host), 0.0, 0.0, 0
 
 
@@ -329,8 +331,9 @@ class DistributedExecution:
 
 def shard_program(physical, mesh: Mesh):
     """The ONE ``shard_map`` program a distributed query runs:
-    ``leaves -> (result, n_rows, exchange ratio, join ratio, agg need)``
-    with the three overflow readings reduced over the mesh.  Module-level
+    ``leaves -> (result, n_rows, exchange ratio, join ratio, agg need,
+    join paths)`` with the three overflow readings and each join's path
+    (``ExecContext.add_join_path``) reduced over the mesh.  Module-level
     so a test can compile exactly this for a described mesh."""
     from .collective import pmax
 
@@ -349,8 +352,12 @@ def shard_program(physical, mesh: Mesh):
             # when nothing overflowed — growth is a row count, not a
             # factor
             shr_need = jnp.zeros((), jnp.int64)
+            paths = []
             for f, kind, cap in zip(ctx.flags, ctx.flag_kinds,
                                     ctx.flag_caps):
+                if kind == P.JOIN_PATH:
+                    paths.append(pmax(f))
+                    continue
                 if kind == "shrink":
                     lost = f.astype(jnp.int64)
                     shr_need = jnp.maximum(
@@ -363,13 +370,15 @@ def shard_program(physical, mesh: Mesh):
                     ex_r = jnp.maximum(ex_r, r)
                 else:
                     join_r = jnp.maximum(join_r, r)
-            return (out, n_rows, pmax(ex_r), pmax(join_r), pmax(shr_need))
+            return (out, n_rows, pmax(ex_r), pmax(join_r), pmax(shr_need),
+                    paths)
 
     return shard_map(
         shard_fn, mesh=mesh,
         in_specs=(PartitionSpec(DATA_AXIS),),
         out_specs=(PartitionSpec(DATA_AXIS), PartitionSpec(),
-                   PartitionSpec(), PartitionSpec(), PartitionSpec()),
+                   PartitionSpec(), PartitionSpec(), PartitionSpec(),
+                   PartitionSpec()),
         check_vma=False,
     )
 

@@ -647,6 +647,7 @@ class PlanCache:
 
     def _run_entry(self, qe, entry: _Entry, fp: PlanFingerprint,
                    first_leaves=None) -> Optional[Any]:
+        from ..sql import physical as P
         from ..sql.planner import (PlannedQuery, _leaves_nbytes,
                                    _overflow_ratio, _plan_reserve_bytes,
                                    _slice_to_host)
@@ -685,7 +686,9 @@ class PlanCache:
                     return None          # needs adaptive replan: fall back
                 qe.metrics = {k: int(np.asarray(v))
                               for k, v in zip(mkeys, metric_vals)}
-                return _slice_to_host(result, int(np.asarray(n_rows)))
+                host = _slice_to_host(result, int(np.asarray(n_rows)))
+            P.record_join_paths(int_flags, kinds)
+            return host
         finally:
             if mem is not None:
                 mem.release_execution(owner)

@@ -18,7 +18,11 @@ sorted-build + binary-search probe:
    output capacity (``spark.sql.join.outputCapacityFactor`` × probe
    capacity); the true total is returned as an overflow flag that triggers
    the executor's adaptive capacity retry — the honest dynamic-shape
-   escape hatch;
+   escape hatch.  A build side whose matchable keys are all distinct —
+   a dimension's — needs none of that: the program reads it from the
+   sorted build keys (``_build_unique``) and, where the output has the
+   probe's capacity, takes output slot ``j`` for probe row ``j`` — no
+   second search, no slot search, no gather through the probe side;
 5. every candidate pair is verified by EXACT per-key value comparison
    (null-aware), so result rows are exact even on the hash search path;
    existence for semi/anti and outer null-extension derives from a
@@ -312,6 +316,18 @@ def _scatter_or(xp, size: int, idx, values):
     return jnp.zeros(size, bool).at[idx].max(values, mode="drop")
 
 
+def _build_unique(xp, ba_s, b_flag_s):
+    """True where no two MATCHABLE entries of the sorted build search keys
+    are equal: every probe row then has at most one candidate.  Exact path
+    (``b_flag_s`` given): the rows with flag 0.  Hash path: the rows that
+    carry neither the NULL nor the dead sentinel — a hash-A collision of
+    two live build rows reads as "not unique"."""
+    nxt = ba_s[1:]
+    matchable = (b_flag_s[1:] == 0) if b_flag_s is not None \
+        else ((nxt != _NULL_BUILD) & (nxt != _DEAD_BUILD))
+    return ~xp.any((nxt == ba_s[:-1]) & matchable)
+
+
 def _join_keys(ctx: EvalContext, exprs: Sequence[Expression],
                null_sentinel: np.int64, dead_sentinel: Optional[np.int64]
                ) -> Tuple[Array, Array]:
@@ -376,7 +392,9 @@ class PJoin(P.PhysicalPlan):
         build_live = build.row_valid_or_true()
 
         # the phases below are the device scopes of a join (tracing.py):
-        # join.keys, join.build_sort, join.probe, join.expand, join.gather
+        # join.keys, join.build_sort, join.probe (the searches and the match
+        # counts), then join.expand + join.gather on the general path or
+        # join.unique on the unique-build path
         with _scope(xp, "join.keys"):
             # exact int64 encodings per key pair (None → hashB fallback for
             # that pair's verification).  A single probe key riding an
@@ -439,9 +457,15 @@ class PJoin(P.PhysicalPlan):
                 ba_s = xp.where(b_flag_s == 0, b_enc[perm], _DEAD_BUILD)
             else:
                 ba_s = ba[perm]
-        # (the probe side's key validity sits between the two halves of the
-        # build sort so that the traced program keeps its order of ops: a
-        # compiled program in a persistent cache stays valid)
+            build_s = take_batch(xp, build, perm)
+
+        out_cap = pad_capacity(int(probe.capacity * max(self.factor, 0.1)))
+        # the slot search below exists for builds that repeat a key; where
+        # the output has the probe's capacity the program reads from the
+        # sorted build keys whether it needs it (full's unmatched-build
+        # append and a grown or shrunk output keep the general path)
+        skippable = out_cap == probe.capacity \
+            and how in ("inner", "left", "left_semi", "left_anti")
         with _scope(xp, "join.keys"):
             if exact:
                 pa = p_enc
@@ -450,58 +474,15 @@ class PJoin(P.PhysicalPlan):
                 p_ok = probe_live if p_val is None else (probe_live & p_val)
             else:
                 p_ok = probe_live
-        with _scope(xp, "join.build_sort"):
-            build_s = take_batch(xp, build, perm)
-
-        with _scope(xp, "join.probe"):
-            lo = searchsorted(xp, ba_s, pa, side="left")
-            hi = searchsorted(xp, ba_s, pa, side="right")
             if run_rid is not None:
-                # expand the per-run search results (and the verification
-                # arrays) to row granularity: every row of a run shares its
-                # key, so the gather reproduces dense execution exactly
-                lo, hi = lo[run_rid], hi[run_rid]
+                # the verification arrays at row granularity: every row of
+                # a run shares its key, so the gather reproduces dense
+                # execution exactly
                 pe0, pv0, be0, bv0 = encs[0]
                 encs[0] = (pe0[run_rid],
                            None if pv0 is None else pv0[run_rid], be0, bv0)
-            counts = xp.where(p_ok, (hi - lo).astype(np.int64), 0)
-            matched_hash = counts > 0
-
-        out_cap = pad_capacity(int(probe.capacity * max(self.factor, 0.1)))
-        with _scope(xp, "join.expand"):
-            if how in ("left", "full"):
-                counts_eff = xp.where(probe_live, xp.maximum(counts, 1), 0)
-            else:
-                counts_eff = counts
-
-            offsets = xp.cumsum(counts_eff) - counts_eff   # exclusive prefix
-            total = xp.sum(counts_eff)
-
-            # output slot j → probe row i and duplicate index d
-            slot = xp.arange(out_cap, dtype=np.int64)
-            i = searchsorted(xp, offsets + counts_eff, slot, side="right")
-            i = xp.clip(i, 0, probe.capacity - 1)
-            d = slot - offsets[i]
-            in_range = slot < total
-            has_match = matched_hash[i]
-            b_row = xp.clip(lo[i] + d, 0, build.capacity - 1)
-
-            # EXACT per-pair verification (null-aware): a pair survives only
-            # if every key column compares equal with both sides valid
-            build_live_s = build_live[perm]
-            verify = in_range & has_match & build_live_s[b_row]
-            hashb_needed = any(e is None for e in encs)
-            for e in encs:
-                if e is not None:
-                    pe, pv, be, bv = e
-                    be_s = be[perm]
-                    ok = pe[i] == be_s[b_row]
-                    if pv is not None:
-                        ok = ok & pv[i]
-                    if bv is not None:
-                        ok = ok & bv[perm][b_row]
-                    verify = verify & ok
-            if hashb_needed:
+            hashb = None
+            if any(e is None for e in encs):
                 # unencodable pairs: fall back to the independent second
                 # hash over exactly those pairs (collision ~2^-64,
                 # documented)
@@ -509,75 +490,183 @@ class PJoin(P.PhysicalPlan):
                            if e is None]
                 exprs_r = [r for (_, r), e in zip(self.key_pairs, encs)
                            if e is None]
-                pb2 = pctx.broadcast(_Hash64B(*exprs_l).eval(pctx)).data
-                bb2 = bctx.broadcast(
-                    _Hash64B(*exprs_r).eval(bctx)).data[perm]
-                verify = verify & (pb2[i] == bb2[b_row])
+                hashb = (pctx.broadcast(_Hash64B(*exprs_l).eval(pctx)).data,
+                         bctx.broadcast(
+                             _Hash64B(*exprs_r).eval(bctx)).data[perm])
+            build_live_s = build_live[perm]
+            build_unique = _build_unique(
+                xp, ba_s, b_flag_s if exact else None) if skippable else False
 
-        with _scope(xp, "join.gather"):
-            # assemble the combined (probe row, build row) batch for each
-            # slot; needed before existence when a residual ON conjunct
-            # participates in the match decision
-            left_out = take_batch(xp, probe, i)
-            right_out = take_batch(xp, build_s, b_row)
-            names: List[str] = list(left_out.names) + list(right_out.names)
-            raw_vectors: List[ColumnVector] = \
-                list(left_out.vectors) + list(right_out.vectors)
+        def choose(unique_fn, general_fn):
+            """The unique-build path or the general one, by what the sorted
+            build keys say: a ``lax.cond`` on the traced lane (the program
+            decides, no host sync), a Python branch on the numpy lane."""
+            if not skippable:
+                return general_fn()
+            if xp is np:
+                return unique_fn() if build_unique else general_fn()
+            from jax import lax
+            return lax.cond(build_unique, unique_fn, general_fn)
 
-            if self.residual is not None:
-                # non-equi ON conjuncts are part of the MATCH CONDITION
-                # (ExtractEquiJoinKeys keeps them as the join's
-                # `condition`): a pair that fails them is not a match — it
-                # does not satisfy semi-existence and DOES null-extend in
-                # outer joins
-                rctx = EvalContext(
-                    ColumnBatch(names, raw_vectors, verify, out_cap), xp)
-                rv_res = rctx.broadcast(self.residual.eval(rctx))
-                res_ok = rv_res.data.astype(bool)
-                if rv_res.valid is not None:
-                    res_ok = res_ok & rv_res.valid   # NULL → no match
-                verify = verify & res_ok
+        # each probe row's match range: one search says where it starts;
+        # how many build rows it holds takes a second search only where a
+        # matchable build key can repeat
+        with _scope(xp, "join.probe"):
+            lo = searchsorted(xp, ba_s, pa, side="left")
 
-            # exact existence per probe row — drives semi/anti and outer
-            # null-extension (never hash-range counts alone)
-            exact_m = _scatter_or(xp, probe.capacity, i, verify)
+        def first_is_equal():
+            with _scope(xp, "join.unique"):
+                at_lo = ba_s[xp.clip(lo, 0, build.capacity - 1)]
+                return (at_lo == pa).astype(lo.dtype)
+
+        def range_length():
+            with _scope(xp, "join.probe"):
+                return searchsorted(xp, ba_s, pa, side="right") - lo
+
+        n_eq = choose(first_is_equal, range_length)
+
+        # (the running sum of the counts stays BETWEEN the two conditionals:
+        # it lowers to an int64 reduce-window, which the TPU compiler refuses
+        # inside a conditional's branch at most lengths under 2^18 — "ran
+        # out of memory in memory space vmem while allocating on stack" —
+        # and an associative scan in its place costs 70 MB of code a join)
+        with _scope(xp, "join.probe"):
+            if run_rid is not None:
+                # per-run search results to row granularity
+                lo, n_eq = lo[run_rid], n_eq[run_rid]
+            counts = xp.where(p_ok, n_eq.astype(np.int64), 0)
+            matched_hash = counts > 0
+            if how in ("left", "full"):
+                counts_eff = xp.where(probe_live, xp.maximum(counts, 1), 0)
+            else:
+                counts_eff = counts
+            ends = xp.cumsum(counts_eff)
+            total = ends[-1]
+
+        def rows(unique: bool):
+            """Output slots, exact verification and the joined rows (for a
+            semi / anti join the keep mask).  ``unique``: every match count
+            is 0 or 1 and the output has the probe's capacity, so output
+            slot ``j`` IS probe row ``j`` — no slot search, and no gather
+            through the probe side."""
+            def phase(name):
+                return _scope(xp, "join.unique" if unique else name)
+
+            with phase("join.expand"):
+                # output slot j → probe row i and duplicate index d
+                if unique:
+                    def at(a):               # i is the identity
+                        return a
+                    in_range = counts_eff > 0
+                    first = in_range         # d is 0 in every slot
+                    b_row = xp.clip(lo, 0, build.capacity - 1)
+                else:
+                    offsets = ends - counts_eff     # exclusive prefix
+                    slot = xp.arange(out_cap, dtype=np.int64)
+                    i = searchsorted(xp, ends, slot, side="right")
+                    i = xp.clip(i, 0, probe.capacity - 1)
+
+                    def at(a):
+                        return a[i]
+                    d = slot - offsets[i]
+                    in_range = slot < total
+                    first = d == 0
+                    b_row = xp.clip(lo[i] + d, 0, build.capacity - 1)
+                has_match = at(matched_hash)
+
+                # EXACT per-pair verification (null-aware): a pair survives
+                # only if every key column compares equal with both sides
+                # valid
+                verify = in_range & has_match & build_live_s[b_row]
+                for e in encs:
+                    if e is not None:
+                        pe, pv, be, bv = e
+                        be_s = be[perm]
+                        ok = at(pe) == be_s[b_row]
+                        if pv is not None:
+                            ok = ok & at(pv)
+                        if bv is not None:
+                            ok = ok & bv[perm][b_row]
+                        verify = verify & ok
+                if hashb is not None:
+                    verify = verify & (at(hashb[0]) == hashb[1][b_row])
+
+            with phase("join.gather"):
+                # assemble the combined (probe row, build row) batch for
+                # each slot; needed before existence when a residual ON
+                # conjunct participates in the match decision
+                left_vectors = [
+                    ColumnVector(v.data, v.dtype, v.valid, v.dictionary)
+                    for v in probe.vectors] if unique \
+                    else take_batch(xp, probe, i).vectors
+                right_out = take_batch(xp, build_s, b_row)
+                names: List[str] = list(probe.names) + list(right_out.names)
+                raw_vectors: List[ColumnVector] = \
+                    list(left_vectors) + list(right_out.vectors)
+
+                if self.residual is not None:
+                    # non-equi ON conjuncts are part of the MATCH CONDITION
+                    # (ExtractEquiJoinKeys keeps them as the join's
+                    # `condition`): a pair that fails them is not a match —
+                    # it does not satisfy semi-existence and DOES
+                    # null-extend in outer joins
+                    rctx = EvalContext(
+                        ColumnBatch(names, raw_vectors, verify, out_cap), xp)
+                    rv_res = rctx.broadcast(self.residual.eval(rctx))
+                    res_ok = rv_res.data.astype(bool)
+                    if rv_res.valid is not None:
+                        res_ok = res_ok & rv_res.valid   # NULL → no match
+                    verify = verify & res_ok
+
+                # exact existence per probe row — drives semi/anti and
+                # outer null-extension (never hash-range counts alone)
+                exact_m = verify if unique \
+                    else _scatter_or(xp, probe.capacity, i, verify)
+
+            if how in ("left_semi", "left_anti"):
+                return exact_m if how == "left_semi" \
+                    else (probe_live & ~exact_m)
+
+            if how in ("left", "full"):
+                # probe rows with zero VERIFIED matches emit one
+                # null-extended row on their first slot (covers
+                # zero-hash-match rows, all-pairs-refuted collisions, and
+                # residual-refuted matches)
+                null_slot = in_range & first & ~at(exact_m) & at(probe_live)
+                pair_ok = verify | null_slot
+                null_right = verify
+            else:
+                pair_ok = verify
+                null_right = None
+
+            vectors: List[ColumnVector] = []
+            for idx, v in enumerate(raw_vectors):
+                if null_right is not None and idx >= len(left_vectors):
+                    base = v.valid if v.valid is not None \
+                        else xp.ones(out_cap, bool)
+                    v = ColumnVector(v.data, v.dtype, base & null_right,
+                                     v.dictionary)
+                vectors.append(v)
+
+            out = ColumnBatch(names, vectors, pair_ok, out_cap)
+
+            if how == "full":
+                hit_b = _scatter_or(xp, build.capacity, b_row, verify)
+                unmatched_b = build_live_s & ~hit_b
+                out = self._append_unmatched_build(ctx, out, build_s,
+                                                   unmatched_b)
+            return out
+
+        out = choose(lambda: rows(True), lambda: rows(False))
 
         if hasattr(ctx, "add_flag"):
             ctx.add_flag(xp.maximum(total - out_cap, 0), "join", out_cap)
+            ctx.add_join_path(build_unique)
 
         if how in ("left_semi", "left_anti"):
-            keep = exact_m if how == "left_semi" \
-                else (probe_live & ~exact_m)
             return ColumnBatch(probe.names, probe.vectors,
-                               probe.row_valid_or_true() & keep,
+                               probe.row_valid_or_true() & out,
                                probe.capacity)
-
-        if how in ("left", "full"):
-            # probe rows with zero VERIFIED matches emit one null-extended
-            # row on their first slot (covers zero-hash-match rows,
-            # all-pairs-refuted collisions, and residual-refuted matches)
-            null_slot = in_range & (d == 0) & ~exact_m[i] & probe_live[i]
-            pair_ok = verify | null_slot
-            null_right = verify
-        else:
-            pair_ok = verify
-            null_right = None
-
-        vectors: List[ColumnVector] = []
-        for idx, v in enumerate(raw_vectors):
-            if null_right is not None and idx >= len(left_out.vectors):
-                base = v.valid if v.valid is not None \
-                    else xp.ones(out_cap, bool)
-                v = ColumnVector(v.data, v.dtype, base & null_right,
-                                 v.dictionary)
-            vectors.append(v)
-
-        out = ColumnBatch(names, vectors, pair_ok, out_cap)
-
-        if how == "full":
-            hit_b = _scatter_or(xp, build.capacity, b_row, verify)
-            unmatched_b = build_live_s & ~hit_b
-            out = self._append_unmatched_build(ctx, out, build_s, unmatched_b)
         return out
 
     # ------------------------------------------------------------------

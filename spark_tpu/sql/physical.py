@@ -33,6 +33,21 @@ from ..kernels import (
 Array = Any
 
 
+#: the flag kind of ``ExecContext.add_join_path``, and the host span that
+#: ``record_join_paths`` writes for it
+JOIN_PATH = "join.path"
+
+
+def record_join_paths(int_flags, kinds) -> None:
+    """One ``join.path`` span, attr ``unique``, for each join of the step
+    whose flags were just fetched (``python -m spark_tpu.tracing`` and the
+    benchmark's ``join.unique_pct`` read them)."""
+    for f, k in zip(int_flags, kinds):
+        if k == JOIN_PATH:
+            with tracing.span(JOIN_PATH, unique=bool(f < 0)):
+                pass
+
+
 class ExecContext:
     def __init__(self, xp, leaves: List[ColumnBatch]):
         self.xp = xp
@@ -52,6 +67,16 @@ class ExecContext:
         self.flags.append(value)
         self.flag_kinds.append(kind)
         self.flag_caps.append(cap)
+
+    def add_join_path(self, unique) -> None:
+        """Which path a join ran (``joins.PJoin``: the unique-build path or
+        the general one), beside the overflow flags so that it comes back
+        in their fetch: kind ``JOIN_PATH``, -1 unique / 0 general.  Never
+        positive, so no overflow test (each reads ``f > 0``) sees it, and
+        the maximum over shards reads unique only where every shard's
+        build was."""
+        self.add_flag(-self.xp.asarray(unique).astype(np.int32),
+                      JOIN_PATH, 0)
 
     def add_metric(self, op_id: int, label: str, value: Array) -> None:
         self.metrics.append((op_id, label, value))

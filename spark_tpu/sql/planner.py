@@ -779,6 +779,7 @@ class QueryExecution:
             out = pq.physical.run(ctx)
             ratio = _overflow_ratio(
                 [int(f) for f in ctx.flags], ctx.flag_caps)
+            P.record_join_paths(ctx.flags, ctx.flag_kinds)
             self._last_join_ratios = [
                 int(f) / max(c, 1)
                 for f, c, k in zip(ctx.flags, ctx.flag_caps, ctx.flag_kinds)
@@ -808,6 +809,7 @@ class QueryExecution:
             c, n_rows, _nd, int_flags, caps, kinds = SC.run_per_op(
                 pq.physical, pq.leaves)
             ratio = _overflow_ratio(int_flags, caps)
+            P.record_join_paths(int_flags, kinds)
             self._last_join_ratios = [
                 f / max(cp, 1)
                 for f, cp, k in zip(int_flags, caps, kinds) if k == "join"]
@@ -878,6 +880,7 @@ class QueryExecution:
             host = _slice_to_host(result, int(np.asarray(n_rows)))
             sp.attrs["bytes"] = _leaves_nbytes([host])
         ratio = _overflow_ratio(int_flags, flag_caps)
+        P.record_join_paths(int_flags, flag_kinds)
         self._last_join_ratios = [
             f / max(c, 1)
             for f, c, k in zip(int_flags, flag_caps, flag_kinds)

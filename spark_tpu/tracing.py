@@ -67,7 +67,7 @@ SPAN_PREFIX = "sql:"
 KERNEL_SCOPES = frozenset({
     "stage.step", "stage.merge",
     "join.keys", "join.build_sort", "join.probe", "join.expand",
-    "join.gather",
+    "join.gather", "join.unique",
     "agg.onehot", "agg.mxu", "agg.mxu.limbs", "agg.sort", "agg.sort.argsort",
     "agg.sort.permute", "agg.sort.segment", "pallas_agg",
     "sort_batch", "argsort", "take_batch", "compact", "partition_bucket",

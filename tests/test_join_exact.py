@@ -211,3 +211,175 @@ def test_literal_equality_is_filter_not_join_key(spark):
         "SELECT x FROM lita JOIN litb ON x = y AND g = -7 ORDER BY x"
     ).collect()
     assert [r["x"] for r in rows] == [0, 1]
+
+
+# -- the unique-build path (PJoin: chosen in the program from the sorted
+# -- build keys) against the general path and a pandas reference -----------
+
+def _join_paths():
+    from spark_tpu import tracing
+    return [s.attrs["unique"] for s in tracing.spans()
+            if s.name == "join.path"]
+
+
+def _path_case(build, two_key, seed):
+    """Probe and build frames with NULL probe keys, NULL and dead build rows
+    (``live`` 0: filtered off inside the program, so they reach the join as
+    dead rows), and values a residual refutes some matches by.  ``build``:
+    ``unique`` (every matchable key once), ``one_dup`` (one key twice),
+    ``all_dup`` (every key twice)."""
+    rng = np.random.default_rng(seed)
+    n = 90
+    lk = rng.integers(0, 24, n).astype(float)
+    lk[rng.random(n) < 0.1] = np.nan                   # NULL probe keys
+    keys = np.arange(4, 28)
+    if build == "one_dup":
+        keys = np.append(keys, 9)
+    elif build == "all_dup":
+        keys = np.repeat(keys, 2)
+    rk = np.concatenate([keys.astype(float),
+                         [np.nan, np.nan],              # NULL build keys
+                         [5.0, 5.0, 11.0]])             # dead, equal keys
+    live = np.ones(len(rk), np.int64)
+    live[-3:] = 0
+    left = pd.DataFrame({"lid": np.arange(n), "lk": lk,
+                         "lv": rng.integers(0, 10, n)})
+    right = pd.DataFrame({"rid": np.arange(len(rk)), "rk": rk,
+                          "rv": rng.integers(0, 10, len(rk)), "live": live})
+    if two_key:
+        left["lk2"] = left["lid"] % 2
+        right["rk2"] = right["rid"] % 2 if build == "unique" else 0
+        if build == "all_dup":
+            right["rk2"] = (right["rid"] // 2) % 2
+    return left, right
+
+
+def _path_reference(left, right, two_key, how):
+    """(lid, rid) pairs by a pandas merge of the non-NULL, live keys, the
+    residual ``lv != rv`` part of the match condition."""
+    on_l, on_r = (["lk", "lk2"], ["rk", "rk2"]) if two_key \
+        else (["lk"], ["rk"])
+    rl = right[right["live"] == 1]
+    pairs = left.dropna(subset=on_l).merge(
+        rl.dropna(subset=on_r), left_on=on_l, right_on=on_r)
+    pairs = pairs[pairs["lv"] != pairs["rv"]]
+    hit_l, hit_r = set(pairs["lid"]), set(pairs["rid"])
+    inner = [(int(a), int(b)) for a, b in zip(pairs["lid"], pairs["rid"])]
+    lone_l = [(int(a), None) for a in left["lid"] if a not in hit_l]
+    if how == "inner":
+        return sorted(inner)
+    if how == "left_semi":
+        return sorted((int(a),) for a in left["lid"] if a in hit_l)
+    if how == "left_anti":
+        return sorted((a,) for a, _ in lone_l)
+    out = inner + lone_l
+    if how == "full":
+        out += [(None, int(b)) for b in rl["rid"] if b not in hit_r]
+    return sorted(out, key=lambda t: tuple((v is None, v or 0) for v in t))
+
+
+def _to_df(spark, pdf):
+    cols = list(pdf.columns)
+    data = [tuple(None if pd.isna(v) else int(v) for v in r)
+            for r in pdf.itertuples(index=False)]
+    return spark.createDataFrame(data, cols)
+
+
+@pytest.mark.parametrize("lane", ["traced", "numpy"])
+@pytest.mark.parametrize("two_key", [False, True],
+                         ids=["exact_key", "two_key_hash"])
+@pytest.mark.parametrize("how", ["inner", "left", "left_semi", "left_anti",
+                                 "full"])
+@pytest.mark.parametrize("build", ["unique", "one_dup", "all_dup"])
+def test_unique_build_path_parity(spark, build, how, two_key, lane):
+    """The same rows whichever path ran: against the pandas reference, and
+    — the unique build again with a duplicated key that no probe row has,
+    which forces the general path — against the general path itself."""
+    from spark_tpu import tracing
+    left_p, right_p = _path_case(build, two_key, seed=17)
+
+    def run(right_pdf):
+        left, right = _to_df(spark, left_p), _to_df(spark, right_pdf)
+        cond = (left["lk"] == right["rk"]) & (left["lv"] != right["rv"])
+        if two_key:
+            cond = cond & (left["lk2"] == right["rk2"])
+        out = left.join(right.filter(right["live"] == 1), cond, how)
+        tracing.reset()
+        got = rows(out.select("lid") if how in ("left_semi", "left_anti")
+                   else out.select("lid", "rid"))
+        return got, _join_paths()
+
+    spark.conf.set("spark.sql.codegen.wholeStage",
+                   "true" if lane == "traced" else "false")
+    try:
+        got, paths = run(right_p)
+        assert got == _path_reference(left_p, right_p, two_key, how)
+        # full joins keep the general path; else the build decides (an
+        # overflowing join runs, and reports, once more at a grown capacity)
+        assert set(paths) == {build == "unique" and how != "full"}
+        if build == "unique" and how != "full":
+            extra = right_p.iloc[[0, 0]].assign(rk=1000, rid=[900, 901])
+            forced, paths = run(pd.concat([right_p, extra]))
+            assert set(paths) == {False}
+            assert forced == got
+    finally:
+        spark.conf.set("spark.sql.codegen.wholeStage", "true")
+
+
+@pytest.mark.parametrize("lane", ["traced", "numpy"])
+def test_build_unique_reads_matchable_neighbours_only(lane):
+    """Equal neighbours that are both live and valid: not unique.  Equal
+    neighbours that are the NULL / dead sentinels (hash path) or flagged
+    rows (exact path): still unique."""
+    import jax.numpy as jnp
+    from spark_tpu.sql import joins as J
+    xp = np if lane == "numpy" else jnp
+    dead, null = J._DEAD_BUILD, J._NULL_BUILD
+
+    def unique(keys, flags=None):
+        return bool(J._build_unique(
+            xp, xp.asarray(np.array(keys, np.int64)),
+            None if flags is None else xp.asarray(np.array(flags, np.int8))))
+
+    # hash path: sentinels outside the 62-bit hash range
+    assert unique([null, null, 3, 8, 9, dead, dead])
+    assert not unique([null, 3, 8, 8, dead])
+    assert unique([7])
+    # exact path: (flag, key) order, flagged rows carry the dead sentinel
+    assert unique([1, 2, 5, dead, dead], [0, 0, 0, 1, 1])
+    assert not unique([1, 5, 5, dead], [0, 0, 0, 1])
+    # a live key that IS int64 max beside a flagged row is one key, once
+    assert unique([1, dead, dead], [0, 0, 1])
+
+
+def test_join_path_spans_and_overflow_flag(spark):
+    """Each executed join reports its path beside the overflow flags: the
+    flag reads 0 on the unique path (no output slot past the probe's
+    capacity by construction), and no overflow test sees the path flag."""
+    import jax.numpy as jnp
+    from spark_tpu import tracing
+    from spark_tpu.sql import physical as P
+    from spark_tpu.sql.planner import QueryExecution, _overflow_ratio
+    fact = spark.createDataFrame(
+        {"k": np.arange(64, dtype=np.int64) % 16,
+         "v": np.arange(64, dtype=np.int64)})
+    for dim_keys, want in ((np.arange(16), True),
+                           (np.arange(16) // 2, False)):
+        dim = spark.createDataFrame(
+            {"dk": dim_keys.astype(np.int64),
+             "w": np.arange(16, dtype=np.int64)})
+        q = fact.join(dim, fact["k"] == dim["dk"])
+        pq = QueryExecution(spark, q._plan).planned
+        for xp, leaves in ((np, [b.to_host() for b in pq.leaves]),
+                           (jnp, [b.to_device() for b in pq.leaves])):
+            ctx = P.ExecContext(xp, leaves)
+            pq.physical.run(ctx)
+            assert ctx.flag_kinds == ["join", P.JOIN_PATH]
+            flags = [int(f) for f in ctx.flags]
+            assert flags[1] == (-1 if want else 0)
+            assert _overflow_ratio(flags[1:], ctx.flag_caps[1:]) == 0.0
+            if want:
+                assert flags[0] == 0 and not any(f > 0 for f in flags)
+        tracing.reset()
+        q.collect()
+        assert _join_paths()[-1] is want

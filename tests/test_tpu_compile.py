@@ -161,6 +161,35 @@ def test_q3_class_fused_plan_compiles(one_chip, on_tpu, spark):
     assert compiled.memory_analysis() is not None
 
 
+@pytest.mark.parametrize("rows", [500, 30_000, 130_000])
+def test_join_with_both_paths_compiles(one_chip, on_tpu, spark, rows):
+    """A join chooses between its two paths in conditionals.  Probe
+    capacities 512, 32768 and 131072: three of the lengths at which the TPU
+    compiler refuses an int64 ``cumsum`` (a ``reduce-window``) inside a branch —
+    which is why the running sum of the match counts sits BETWEEN the
+    join's two conditionals and in neither."""
+    from spark_tpu.sql import physical as P
+    from spark_tpu.sql.planner import QueryExecution
+    fact = spark.createDataFrame(
+        {"k": np.arange(rows, dtype=np.int64) % 100,
+         "v": np.arange(rows, dtype=np.int64)})
+    dim = spark.createDataFrame(
+        {"dk": np.arange(100, dtype=np.int64),
+         "w": np.arange(100, dtype=np.float64)})
+    q = fact.join(dim, fact["k"] == dim["dk"], "left")
+    pq = QueryExecution(spark, q._plan).planned
+
+    def step(leaves):
+        ctx = P.ExecContext(jnp, list(leaves))
+        return K.compact(jnp, pq.physical.run(ctx)), ctx.flags
+
+    text = jax.jit(step).lower(
+        _spec(tuple(b.to_device() for b in pq.leaves), one_chip)) \
+        .compile().as_text()
+    assert "conditional" in text
+    assert "join.unique" in text and "join.expand" in text
+
+
 @pytest.mark.parametrize("method", ["scan", "scan_unrolled"])
 def test_searchsorted_lowerings_compile(one_chip, method):
     """Both ``jnp.searchsorted`` lowerings the join probe can take, int64

@@ -281,6 +281,15 @@ def test_kernel_scopes_in_lowered_text(in_memory, monkeypatch):
     assert tracing.named_scope_of(
         "jit(run)/stage.step/PSort#1/PJoin#4/join.probe/while/body/gather:"
     ) == "PJoin#4/join.probe"
+    # a join's two paths are the branches of one conditional in the program:
+    # the general path keeps join.probe / join.expand, the unique-build
+    # path runs under join.unique
+    assert "branch_1_fun/join.unique/" in text \
+        and "branch_0_fun/join.expand/jit(searchsorted)" in text
+    assert tracing.named_scope_of(
+        "jit(step)/stage.step/PJoin#3/cond/branch_1_fun/join.unique/"
+        "take_batch/gather"
+    ) == "PJoin#3/join.unique/take_batch"
     assert tracing.named_scope_of("jit(run)/jit(main)/mul") is None
 
 
@@ -422,3 +431,40 @@ def test_plan_cache_key_memo_survives_freed_nodes():
     a = leaf(2)
     memo = {id(a): (leaf(3), "LocalRelation#stale")}
     assert L.plan_cache_key(a, memo) == L.plan_cache_key(a)
+
+
+# -- the benchmark's reading of the join paths --------------------------------
+
+@pytest.mark.parametrize("ring, want", [
+    # three of statement 7's four joins and statement 8's one; the joins of
+    # the warm-up before the slice and of the statement after it do not count
+    ("ring_join_path.json", 80.0),
+    # a program that records no join.path (any tree before PR 26) reads 0
+    ("ring_small.json", 0.0),
+])
+def test_join_unique_pct_on_the_recording(ring, want):
+    """``join.unique_pct`` as the benchmark computes it: its manifest entry,
+    its data file and the accepted ``program_spans`` reader, over a small
+    recorded ring laid on the harness's recorded trace."""
+    import importlib
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    from benchmark.lib import trace as TR
+    from benchmark.run import Context
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        entry = next(m for m in json.load(fh)["per_layer"]
+                     if m["name"] == "join.unique_pct")
+    assert (entry["unit"], entry["better"], entry["layer"], entry["moves"]) \
+        == ("%", "higher", "operators", "fact_rows_per_s")
+    assert entry["workloads"] == ["sf1-star-parquet", "mesh4-q3-q17"]
+    with open(os.path.join(bench, "layer_metrics",
+                           "join.unique_pct.json")) as fh:
+        spec = json.load(fh)
+    with open(os.path.join(bench, "tests", "trace_small.json")) as fh:
+        trace = TR.Reduced(json.load(fh))
+    with open(os.path.join(bench, "tests", ring)) as fh:
+        ctx = Context(trace=trace, ring=json.load(fh)["ring"])
+    reader = importlib.import_module("benchmark.readers." + spec["reader"])
+    assert reader.read(ctx, **spec["args"]) == pytest.approx(want)
