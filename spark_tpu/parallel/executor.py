@@ -243,9 +243,13 @@ class DistributedExecution:
         skew, jf = adapted.get("skew"), adapted.get("join")
         shrink = adapted.get("shrink")
         grew = False
+        ex_ratio = join_ratio = 0.0
         for attempt in range(self.MAX_ADAPT + 1):
-            result, ex_ratio, join_ratio, shrink_need = self._run_once(
-                optimized, skew, jf, shrink, check_caps=grew)
+            with tracing.replan(attempt, max(ex_ratio, join_ratio),
+                                {"skew": skew, "join": jf,
+                                 "shrink": shrink}):
+                result, ex_ratio, join_ratio, shrink_need = self._run_once(
+                    optimized, skew, jf, shrink, check_caps=grew)
             if ex_ratio <= 0.0 and join_ratio <= 0.0 and shrink_need <= 0:
                 if skew is not None or jf is not None or shrink is not None:
                     self.session._adapted_factors[base_key] = {
@@ -321,7 +325,8 @@ class DistributedExecution:
                 return result, ex_ratio, join_ratio, shrink_need
             host = result.to_host()
             sp.attrs["bytes"] = batch_nbytes(host)
-        P.record_join_paths(paths, [P.JOIN_PATH] * len(paths))
+        P.record_join_paths(paths, [P.JOIN_PATH] * len(paths),
+                            (notes.get("join.caps") or [()])[-1])
         return compact(np, host), 0.0, 0.0, 0
 
 
@@ -353,6 +358,11 @@ def shard_program(physical, mesh: Mesh):
             # factor
             shr_need = jnp.zeros((), jnp.int64)
             paths = []
+            # each join's static (output slots, probe capacity) a shard:
+            # a trace-time fact, kept with the program's notes
+            tracing.note("join.caps", [
+                cap for kind, cap in zip(ctx.flag_kinds, ctx.flag_caps)
+                if kind == P.JOIN_PATH])
             for f, kind, cap in zip(ctx.flags, ctx.flag_kinds,
                                     ctx.flag_caps):
                 if kind == P.JOIN_PATH:

@@ -191,13 +191,14 @@ def _ser_val(v: Any, slots: List[E.Literal]) -> str:
     raise _Unfingerprintable(f"{type(v).__name__} in plan fields")
 
 
-def _ser_plan(node, slots: List[E.Literal]) -> str:
+def _ser_plan(node, slots: List[E.Literal], leaf_identity=True) -> str:
     from ..sql import logical as L
     if isinstance(node, L.LocalRelation):
         # batch identity, not content hash: uid is monotonic per batch
         # object, so two sessions' same-shaped temp views never collide
-        return (f"Local#{L._batch_uid(node.batch)}"
-                f":{node.batch.schema.simpleString()}")
+        # (a statement SHAPE leaves it out: ``fingerprint``)
+        uid = f"#{L._batch_uid(node.batch)}" if leaf_identity else ""
+        return f"Local{uid}:{node.batch.schema.simpleString()}"
     fields = []
     for name in sorted(vars(node)):
         if name in ("children", "child"):
@@ -214,15 +215,20 @@ def _ser_plan(node, slots: List[E.Literal]) -> str:
                 and isinstance(v[0], L.LogicalPlan)):
             continue
         fields.append(f"{name}={_ser_val(v, slots)}")
-    inner = ",".join(_ser_plan(c, slots) for c in node.children)
+    inner = ",".join(_ser_plan(c, slots, leaf_identity)
+                     for c in node.children)
     return f"{type(node).__name__}[{';'.join(fields)}]({inner})"
 
 
-def fingerprint(session, plan) -> Optional[PlanFingerprint]:
-    """Fingerprint an OPTIMIZED plan, or None if it cannot be keyed."""
+def fingerprint(session, plan, leaf_identity=True
+                ) -> Optional[PlanFingerprint]:
+    """Fingerprint an OPTIMIZED plan, or None if it cannot be keyed.
+    ``leaf_identity`` False keys the statement's SHAPE: in-memory leaves by
+    their schema alone, so the same plan over another batch (a grace
+    bucket, a cross-process lane's partition) has the same key."""
     slots: List[E.Literal] = []
     try:
-        body = _ser_plan(plan, slots)
+        body = _ser_plan(plan, slots, leaf_identity)
     except (_Unfingerprintable, RecursionError):
         return None
     conf = ";".join(f"{e.key}={session.conf.get(e)!r}"
@@ -461,7 +467,7 @@ class PlanCache:
                 and not backend_supports_callbacks():
             return None                  # interpreted lane: nothing to cache
         with tracing.span("plancache.lookup", hit=False) as sp:
-            fp = fingerprint(session, qe.optimized)
+            fp = qe.fingerprint()
             entry = None if fp is None else self._get(fp.key)
             sp.attrs["hit"] = entry is not None
         if fp is None:
@@ -523,7 +529,7 @@ class PlanCache:
         if not session.conf.get(C.CODEGEN_ENABLED):
             return thunk()
         with tracing.span("plancache.lookup", hit=False) as sp:
-            fp = fingerprint(session, qe.optimized)
+            fp = qe.fingerprint()
             entry = None
             if fp is not None:
                 key = f"stage|{kind}|{fp.key}"
@@ -647,10 +653,8 @@ class PlanCache:
 
     def _run_entry(self, qe, entry: _Entry, fp: PlanFingerprint,
                    first_leaves=None) -> Optional[Any]:
-        from ..sql import physical as P
         from ..sql.planner import (PlannedQuery, _leaves_nbytes,
-                                   _overflow_ratio, _plan_reserve_bytes,
-                                   _slice_to_host)
+                                   _plan_reserve_bytes, _slice_to_host)
         session = qe.session
         if first_leaves is not None:
             leaves = first_leaves
@@ -682,12 +686,13 @@ class PlanCache:
             caps, kinds, mkeys = entry.meta.get(shape_key, ([], [], []))
             with tracing.span("d2h"):    # the flag fetch waits for the step
                 int_flags = [int(np.asarray(f)) for f in flags]
-                if _overflow_ratio(int_flags, caps) > 0.0:
-                    return None          # needs adaptive replan: fall back
+                if qe.read_flags(int_flags, caps, kinds) > 0.0:
+                    # needs the adaptive loop, which takes this run as its
+                    # first attempt (``qe._last_ratio``)
+                    return None
                 qe.metrics = {k: int(np.asarray(v))
                               for k, v in zip(mkeys, metric_vals)}
                 host = _slice_to_host(result, int(np.asarray(n_rows)))
-            P.record_join_paths(int_flags, kinds)
             return host
         finally:
             if mem is not None:

@@ -486,6 +486,12 @@ class Analyzer:
                 # view bodies may themselves reference views: recurse
                 resolved = self._resolve_relations(
                     self.catalog.lookup(node.name), _depth + 1)
+                # a view's body is a query of its own: its ``*`` is expanded
+                # here, so that an alias over the view has a schema to
+                # qualify (a self-join of one view under two aliases, a
+                # correlated reference that goes through such an alias)
+                resolved = resolved.transform_up(self._disambiguate_joins) \
+                    .transform_up(self._expand_stars)
                 return SubqueryAlias(node.name, resolved)
             return node
         return plan.transform_up(fn)

@@ -661,7 +661,7 @@ class PJoin(P.PhysicalPlan):
 
         if hasattr(ctx, "add_flag"):
             ctx.add_flag(xp.maximum(total - out_cap, 0), "join", out_cap)
-            ctx.add_join_path(build_unique)
+            ctx.add_join_path(build_unique, out_cap, probe.capacity)
 
         if how in ("left_semi", "left_anti"):
             return ColumnBatch(probe.names, probe.vectors,

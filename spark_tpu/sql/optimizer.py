@@ -766,7 +766,15 @@ def _expr_refs(exprs) -> set:
 def prune_file_columns(plan: LogicalPlan) -> LogicalPlan:
     """Top-down required-column propagation; file relations read only the
     columns the plan consumes (the difference between reading 24 columns
-    and 4 at TPC-DS scale — ``FileSourceStrategy.scala`` pruned schema)."""
+    and 4 at TPC-DS scale — ``FileSourceStrategy.scala`` pruned schema).
+
+    A Project below a consumer that reads by NAME keeps only the outputs
+    that consumer asks for, so the requirement reaches the scan through a
+    view's ``SELECT *`` and through the analyzer's join-side renames (else
+    a statement over ``CREATE VIEW t AS SELECT * FROM parquet...`` reads
+    every column of ``t`` at each of its uses).  Below a Distinct or a
+    Union a Project keeps all of its outputs: there the columns are the
+    rows' identity, or are matched by position."""
     from .logical import (
         EventTimeWatermark, FileRelation as FR, Sample,
     )
@@ -782,7 +790,7 @@ def prune_file_columns(plan: LogicalPlan) -> LogicalPlan:
                 return 1 << 8
         return min(fields, key=width).name
 
-    def walk(node: LogicalPlan, required):
+    def walk(node: LogicalPlan, required, narrow=True):
         if isinstance(node, FR):
             if required is None:
                 return node
@@ -797,13 +805,19 @@ def prune_file_columns(plan: LogicalPlan) -> LogicalPlan:
             return FR(node.fmt, node.paths, node._schema, node.options,
                       columns=keep, pushed_filters=node.pushed_filters)
         if isinstance(node, Project):
-            child = walk(node.child, _expr_refs(node.exprs))
-            return Project(node.exprs, child) \
-                if child is not node.child else node
+            exprs = node.exprs
+            if narrow and required is not None:
+                exprs = [e for e in exprs if e.name in required] \
+                    or exprs[:1]
+            child = walk(node.child, _expr_refs(exprs))
+            if child is node.child and len(exprs) == len(node.exprs):
+                return node
+            # type(node): the analyzer's join-side rename is a Project too
+            return type(node)(exprs, child)
         if isinstance(node, Filter):
             req = None if required is None \
                 else (required | node.condition.references())
-            child = walk(node.child, req)
+            child = walk(node.child, req, narrow)
             return Filter(node.condition, child) \
                 if child is not node.child else node
         if isinstance(node, Aggregate):
@@ -815,26 +829,26 @@ def prune_file_columns(plan: LogicalPlan) -> LogicalPlan:
         if isinstance(node, Sort):
             req = None if required is None \
                 else (required | _expr_refs(o.child for o in node.orders))
-            child = walk(node.child, req)
+            child = walk(node.child, req, narrow)
             return Sort(node.orders, child, node.is_global) \
                 if child is not node.child else node
         if isinstance(node, Limit):
-            child = walk(node.child, required)
+            child = walk(node.child, required, narrow)
             return Limit(node.n, child) \
                 if child is not node.child else node
         if isinstance(node, Distinct):
-            child = walk(node.child, required)
+            child = walk(node.child, required, False)
             return Distinct(child) if child is not node.child else node
         if isinstance(node, Sample):
-            child = walk(node.children[0], required)
+            child = walk(node.children[0], required, narrow)
             return Sample(node.fraction, node.seed, child) \
                 if child is not node.children[0] else node
         if isinstance(node, SubqueryAlias):
-            child = walk(node.children[0], required)
+            child = walk(node.children[0], required, narrow)
             return SubqueryAlias(node.alias, child) \
                 if child is not node.children[0] else node
         if isinstance(node, EventTimeWatermark):
-            child = walk(node.children[0], required)
+            child = walk(node.children[0], required, narrow)
             if child is not node.children[0]:
                 return EventTimeWatermark(node.col_name, node.delay_us,
                                           child)
@@ -847,7 +861,7 @@ def prune_file_columns(plan: LogicalPlan) -> LogicalPlan:
                 for sub in we.sub_expressions():
                     wrefs |= sub.references()
             req = None if required is None else (required | wrefs)
-            child = walk(node.children[0], req)
+            child = walk(node.children[0], req, narrow)
             return WindowNode(node.wexprs, child) \
                 if child is not node.children[0] else node
         if isinstance(node, Join):
@@ -860,8 +874,8 @@ def prune_file_columns(plan: LogicalPlan) -> LogicalPlan:
             else:
                 lreq = (required & lnames) | (on_refs & lnames) | using
                 rreq = (required & rnames) | (on_refs & rnames) | using
-            left = walk(node.left, lreq)
-            right = walk(node.right, rreq)
+            left = walk(node.left, lreq, narrow)
+            right = walk(node.right, rreq, narrow)
             if left is not node.left or right is not node.right:
                 return Join(left, right, node.how, node.on, node.using)
             return node
@@ -874,7 +888,8 @@ def prune_file_columns(plan: LogicalPlan) -> LogicalPlan:
                 kids = []
                 for c in node.children:
                     cn = c.schema().names
-                    kids.append(walk(c, frozenset(cn[i] for i in idx)))
+                    kids.append(walk(c, frozenset(cn[i] for i in idx),
+                                     False))
             if any(k is not c for k, c in zip(kids, node.children)):
                 return Union(kids)
             return node

@@ -38,13 +38,18 @@ Array = Any
 JOIN_PATH = "join.path"
 
 
-def record_join_paths(int_flags, kinds) -> None:
-    """One ``join.path`` span, attr ``unique``, for each join of the step
-    whose flags were just fetched (``python -m spark_tpu.tracing`` and the
-    benchmark's ``join.unique_pct`` read them)."""
-    for f, k in zip(int_flags, kinds):
+def record_join_paths(int_flags, kinds, caps=()) -> None:
+    """One ``join.path`` span for each join of the step whose flags were
+    just fetched: ``unique`` (the path it took) and, from the trace's static
+    capacities where the lane kept them, ``out_cap`` and ``probe_cap`` (the
+    join's fan-out).  ``python -m spark_tpu.tracing`` and the benchmark's
+    ``join.unique_pct`` read them."""
+    for n, (f, k) in enumerate(zip(int_flags, kinds)):
         if k == JOIN_PATH:
-            with tracing.span(JOIN_PATH, unique=bool(f < 0)):
+            attrs = {"unique": bool(f < 0)}
+            if n < len(caps):
+                attrs["out_cap"], attrs["probe_cap"] = caps[n]
+            with tracing.span(JOIN_PATH, **attrs):
                 pass
 
 
@@ -68,15 +73,16 @@ class ExecContext:
         self.flag_kinds.append(kind)
         self.flag_caps.append(cap)
 
-    def add_join_path(self, unique) -> None:
+    def add_join_path(self, unique, out_cap: int, probe_cap: int) -> None:
         """Which path a join ran (``joins.PJoin``: the unique-build path or
         the general one), beside the overflow flags so that it comes back
         in their fetch: kind ``JOIN_PATH``, -1 unique / 0 general.  Never
         positive, so no overflow test (each reads ``f > 0``) sees it, and
         the maximum over shards reads unique only where every shard's
-        build was."""
+        build was.  Its static "capacity" is the pair (output slots, probe
+        capacity), which ``record_join_paths`` puts on the span."""
         self.add_flag(-self.xp.asarray(unique).astype(np.int32),
-                      JOIN_PATH, 0)
+                      JOIN_PATH, (out_cap, probe_cap))
 
     def add_metric(self, op_id: int, label: str, value: Array) -> None:
         self.metrics.append((op_id, label, value))

@@ -455,6 +455,9 @@ class QueryExecution:
         #: per-operator metrics of the last execution:
         #: {(op_id, operator label): output row count}
         self.metrics: Dict[Tuple[int, str], int] = {}
+        #: the worst overflow ratio of the last attempt (``read_flags``)
+        self._last_ratio = 0.0
+        self._fingerprint = False        # not worked out yet (None: no key)
 
     @property
     def analyzed(self) -> LogicalPlan:
@@ -669,26 +672,24 @@ class QueryExecution:
             cached_out = plan_cache.try_execute(self)
             if cached_out is not None:
                 return cached_out
+        # (where the plan cache ran the planned capacities and they
+        # overflowed, that WAS the loop's first attempt: ``_last_ratio``)
 
         # ONE adapted-parameter shape for every executor:
-        # {"skew": float|None, "join": factors|None, "shrink": rows|None}
-        base_key = "local:" + self.planned.physical.key()
+        # {"skew": float|None, "join": factors|None, "shrink": rows|None},
+        # kept under the statement's SHAPE, so that a repeat (a new literal
+        # in a slot position included) starts from what the shape learned
+        # and executes once
+        base_key = self._capacity_key()
         adapted = self.session._adapted_factors.get(base_key) or {}
         factors, shrink = adapted.get("join"), adapted.get("shrink")
         grew = False
+        ratio = self._last_ratio if not adapted else 0.0
         for attempt in range(self.MAX_ADAPT + 1):
-            pq = self.planned if factors is None and shrink is None \
-                else Planner(self.session, join_factor_override=factors,
-                             agg_shrink_override=shrink) \
-                .plan(self.optimized)
-            if grew:
-                # exact per-join allocation guard (replaces the old
-                # factor x max-leaf estimate, which mis-blamed small
-                # joins in plans with one large leaf).  Only GROWTH in
-                # THIS execution is guarded — factors cached from a
-                # previous successful run already proved they fit.
-                check_planned_join_capacities(pq, self.session)
-            result, ratio = self._run_planned(pq)
+            if attempt or ratio <= 0.0:
+                with tracing.replan(attempt, ratio,
+                                    {"join": factors, "shrink": shrink}):
+                    result, ratio = self._attempt(factors, shrink, grew)
             if ratio <= 0.0:
                 if factors is not None or shrink is not None:
                     self.session._adapted_factors[base_key] = {
@@ -734,6 +735,47 @@ class QueryExecution:
                 "capacity %s", ratio * 100,
                 ["%.2f" % f if f else "-" for f in factors], shrink)
 
+    def _capacity_key(self) -> str:
+        """Where ``session._adapted_factors`` keeps the capacities this
+        statement's shape learned: the optimized plan with the literals in
+        slot positions and the identity of in-memory leaves left out
+        (``plancache.fingerprint``; the plan's own text where that cannot
+        key it), so a new literal and the same plan over another batch (a
+        grace bucket, a cross-process lane's partition) start from them.
+        Not the physical plan: a statement with kept capacities is planned
+        once, with them."""
+        from ..serving.plancache import fingerprint
+        fp = fingerprint(self.session, self.optimized, leaf_identity=False)
+        return "local:" + (self.optimized.tree_string() if fp is None
+                           else fp.key)
+
+    def fingerprint(self):
+        """``plancache.fingerprint`` of the optimized plan (None where it
+        cannot be keyed), worked out once a statement."""
+        if self._fingerprint is False:
+            from ..serving.plancache import fingerprint
+            self._fingerprint = fingerprint(self.session, self.optimized)
+        return self._fingerprint
+
+    def _attempt(self, factors, shrink, grew: bool
+                 ) -> Tuple[ColumnBatch, float]:
+        """One attempt of the adaptive loop: plan (with the capacities
+        chosen, where any are) and run."""
+        if factors is None and shrink is None:
+            pq = self.planned
+        else:
+            with tracing.span("plan"):
+                pq = Planner(self.session, join_factor_override=factors,
+                             agg_shrink_override=shrink).plan(self.optimized)
+        if grew:
+            # exact per-join allocation guard (replaces the old factor x
+            # max-leaf estimate, which mis-blamed small joins in plans
+            # with one large leaf).  Only GROWTH in THIS execution is
+            # guarded — factors kept from a previous successful run
+            # already proved they fit.
+            check_planned_join_capacities(pq, self.session)
+        return self._run_planned(pq)
+
     def _run_planned(self, pq: PlannedQuery) -> Tuple[ColumnBatch, float]:
         """One execution attempt → (host result, worst overflow ratio).
 
@@ -777,17 +819,8 @@ class QueryExecution:
         if not use_jit:
             ctx = P.ExecContext(np, [b.to_host() for b in pq.leaves])
             out = pq.physical.run(ctx)
-            ratio = _overflow_ratio(
-                [int(f) for f in ctx.flags], ctx.flag_caps)
-            P.record_join_paths(ctx.flags, ctx.flag_kinds)
-            self._last_join_ratios = [
-                int(f) / max(c, 1)
-                for f, c, k in zip(ctx.flags, ctx.flag_caps, ctx.flag_kinds)
-                if k == "join"]
-            self._last_shrink = [
-                (int(f), c)
-                for f, c, k in zip(ctx.flags, ctx.flag_caps, ctx.flag_kinds)
-                if k == "shrink"]
+            ratio = self.read_flags([int(f) for f in ctx.flags],
+                                    ctx.flag_caps, ctx.flag_kinds)
             self.metrics = {(oid, lbl): int(v)
                             for oid, lbl, v in ctx.metrics}
             return compact(np, out.to_host()), ratio
@@ -808,15 +841,7 @@ class QueryExecution:
             # retry still works, metrics are dropped (debug lane)
             c, n_rows, _nd, int_flags, caps, kinds = SC.run_per_op(
                 pq.physical, pq.leaves)
-            ratio = _overflow_ratio(int_flags, caps)
-            P.record_join_paths(int_flags, kinds)
-            self._last_join_ratios = [
-                f / max(cp, 1)
-                for f, cp, k in zip(int_flags, caps, kinds) if k == "join"]
-            self._last_shrink = [
-                (f, cp)
-                for f, cp, k in zip(int_flags, caps, kinds)
-                if k == "shrink"]
+            ratio = self.read_flags(int_flags, caps, kinds)
             self.metrics = {}
             return _slice_to_host(c, n_rows), ratio
         cache = SC.stage_cache(self.session)
@@ -879,16 +904,22 @@ class QueryExecution:
                             for k, v in zip(metric_keys, metric_vals)}
             host = _slice_to_host(result, int(np.asarray(n_rows)))
             sp.attrs["bytes"] = _leaves_nbytes([host])
-        ratio = _overflow_ratio(int_flags, flag_caps)
-        P.record_join_paths(int_flags, flag_kinds)
+        return host, self.read_flags(int_flags, flag_caps, flag_kinds)
+
+    def read_flags(self, int_flags, caps, kinds) -> float:
+        """What an attempt's fetched flags say: the ``join.path`` spans, the
+        worst overflow ratio (0.0: everything fitted) and, kept for the
+        adaptive loop's next choice, each join's ratio and each shrunk
+        aggregate's (lost rows, capacity)."""
+        P.record_join_paths(int_flags, kinds, caps)
         self._last_join_ratios = [
             f / max(c, 1)
-            for f, c, k in zip(int_flags, flag_caps, flag_kinds)
-            if k == "join"]
+            for f, c, k in zip(int_flags, caps, kinds) if k == "join"]
         self._last_shrink = [
-            (f, c) for f, c, k in zip(int_flags, flag_caps, flag_kinds)
+            (f, c) for f, c, k in zip(int_flags, caps, kinds)
             if k == "shrink"]
-        return host, ratio
+        self._last_ratio = _overflow_ratio(int_flags, caps)
+        return self._last_ratio
 
     def planned_preview(self) -> PlannedQuery:
         """Side-effect-free plan for explain(): lazy checkpoints are NOT

@@ -424,7 +424,7 @@ class _MappedStream(BatchStream):
                 int_flags = [int(np.asarray(f)) for f in flags]
                 runs = None if any(f > 0 for f in int_flags) \
                     else self._to_runs(out, n)
-            P.record_join_paths(int_flags, kinds)
+            P.record_join_paths(int_flags, kinds, caps)
             if runs is not None:
                 return runs, (jstep, extra, meta)
             cur = list(self._factors) if self._factors else []

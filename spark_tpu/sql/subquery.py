@@ -24,6 +24,7 @@ own scope is pulled up to the join level.
 
 from __future__ import annotations
 
+import contextvars
 import itertools
 from typing import List, Tuple
 
@@ -35,11 +36,12 @@ from .logical import (
     Aggregate, Distinct, Filter, Join, LogicalPlan, Project, SubqueryAlias,
 )
 
-_fresh = itertools.count()
+#: the counter of the statement being rewritten (``rewrite_subqueries``)
+_fresh: contextvars.ContextVar = contextvars.ContextVar("subquery_fresh")
 
 
 def _fresh_name(base: str) -> str:
-    return f"__sq{next(_fresh)}_{base}"
+    return f"__sq{next(_fresh.get())}_{base}"
 
 
 # ---------------------------------------------------------------------------
@@ -418,7 +420,20 @@ def rewrite_subqueries(plan: LogicalPlan, resolve) -> LogicalPlan:
     `resolve` is called on each nested subquery plan first (catalog/view
     resolution — nested plans are invisible to the analyzer's transform_up
     because they live inside expressions), and the rewrite RECURSES into
-    each subquery plan so subqueries nested inside subqueries work."""
+    each subquery plan so subqueries nested inside subqueries work.
+
+    The fresh column names count from 0 in every statement (unique within
+    the plan, which is all a name has to be): one text then analyzes to ONE
+    plan however often it is sent, and everything keyed on the plan serves
+    the repeat: the stage cache, the serving plan cache, the join capacities
+    a statement learned (``session._adapted_factors``).  A process-wide
+    counter made each of them miss on every call."""
+    if _fresh.get(None) is None:
+        token = _fresh.set(itertools.count())
+        try:
+            return rewrite_subqueries(plan, resolve)
+        finally:
+            _fresh.reset(token)
     from .optimizer import join_conjuncts, split_conjuncts
 
     def prep(p: LogicalPlan) -> LogicalPlan:

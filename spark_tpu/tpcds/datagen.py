@@ -8,6 +8,12 @@ are unnecessary; sizes scale linearly with ``sf_rows``.
 
 Returns sampled from sales keep the (item, ticket/order, customer) join
 identity the 3-channel queries (q17/q25/q29...) rely on.
+
+An order or a ticket is ONE line here (``ws_order_number`` / ``cs_order_number``
+/ ``ss_ticket_number`` is the row's number), so the self-joins of q16 / q94 /
+q95 (two lines of one order from different warehouses) are empty on this data;
+``tests/test_web_orders.py`` is where they do work, on the benchmark's
+order-structured generator (``benchmark/generators/web_sales.py``).
 """
 
 from __future__ import annotations

@@ -51,7 +51,8 @@ import jax
 from jax.profiler import TraceAnnotation
 
 __all__ = [
-    "Span", "span", "scope", "count", "fresh_jit", "note", "statement",
+    "Span", "span", "scope", "count", "fresh_jit", "replan", "note",
+    "statement",
     "adopt", "current_statement", "collecting", "spans", "summary",
     "last_statement", "statement_phases", "reset", "device_time_by_scope",
     "KERNEL_SCOPES",
@@ -224,6 +225,18 @@ def fresh_jit(site: str) -> span:
     span and the counter ``jit.fresh``, with the call site."""
     count("jit.fresh")
     return span("jit.fresh", site=site)
+
+
+def replan(attempt: int, ratio: float, factors):
+    """Around every attempt AFTER the first of an adaptive capacity loop
+    (``QueryExecution._execute_inner``, ``DistributedExecution.execute``):
+    the span ``join.replan`` with the attempt's number, the overflow ratio
+    (lost rows over capacity) that asked for it and the capacities chosen.
+    A statement whose first attempt fits records none."""
+    if attempt == 0:
+        return contextlib.nullcontext()
+    return span("join.replan", attempt=attempt, ratio=round(ratio, 4),
+                factors=factors)
 
 
 # -- notes --------------------------------------------------------------------

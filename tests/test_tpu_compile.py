@@ -190,6 +190,51 @@ def test_join_with_both_paths_compiles(one_chip, on_tpu, spark, rows):
     assert "join.unique" in text and "join.expand" in text
 
 
+@pytest.mark.parametrize("how,factor,out_cap", [
+    ("inner", 16.0, 1 << 21), ("left_semi", 1.0, 1 << 17),
+    ("left_anti", 1.0, 1 << 17)])
+def test_fanout_join_compiles(one_chip, on_tpu, spark, how, factor, out_cap):
+    """The general path at a real fan-out, as TPC-DS q95 / q94 run it: a
+    self-join on the order number with a ``<>`` residual, the build repeating
+    every key (12 lines an order).  Inner: a 2^17-row probe into 2^21
+    output slots (the capacity a re-plan chooses: ``join_factor_override``);
+    semi / anti: the residual decides existence, so the pairs are expanded
+    there too."""
+    from spark_tpu.sql import physical as P
+    from spark_tpu.sql.planner import Planner, QueryExecution
+    n = 100_000                               # pads to 2^17
+    spark.createDataFrame(
+        {"o": np.arange(n, dtype=np.int64) // 12,
+         "w": np.arange(n, dtype=np.int64) % 5}) \
+        .createOrReplaceTempView("fanout_lines")
+    on = "a.o = b.o AND a.w <> b.w"
+    q = spark.sql({
+        "inner": "SELECT a.o, b.w FROM fanout_lines a, fanout_lines b "
+                 f"WHERE {on}",
+        "left_semi": "SELECT a.o FROM fanout_lines a WHERE EXISTS "
+                     f"(SELECT * FROM fanout_lines b WHERE {on})",
+        "left_anti": "SELECT a.o FROM fanout_lines a WHERE NOT EXISTS "
+                     f"(SELECT * FROM fanout_lines b WHERE {on})"}[how])
+    pq = Planner(spark, join_factor_override=[factor]).plan(
+        QueryExecution(spark, q._plan).optimized)
+    spark.catalog.dropTempView("fanout_lines")
+    assert f"HashJoin {how}" in pq.physical.tree_string()
+    caps = []
+
+    def step(leaves):
+        ctx = P.ExecContext(jnp, list(leaves))
+        out = K.compact(jnp, pq.physical.run(ctx))
+        caps.extend(c for k, c in zip(ctx.flag_kinds, ctx.flag_caps)
+                    if k == P.JOIN_PATH)
+        return out, ctx.flags
+
+    text = jax.jit(step).lower(
+        _spec(tuple(b.to_device() for b in pq.leaves), one_chip)) \
+        .compile().as_text()
+    assert caps == [(out_cap, 1 << 17)]
+    assert "join.expand" in text and "join.gather" in text
+
+
 @pytest.mark.parametrize("method", ["scan", "scan_unrolled"])
 def test_searchsorted_lowerings_compile(one_chip, method):
     """Both ``jnp.searchsorted`` lowerings the join probe can take, int64
