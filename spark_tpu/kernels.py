@@ -110,6 +110,29 @@ def searchsorted(xp, a: Array, v: Array, side: str = "left") -> Array:
     return xp.searchsorted(a, v, side=side)
 
 
+def slot_owner(xp, ends: Array, n_slots: int) -> Array:
+    """For slots ``0..n_slots-1`` the number of entries of the non-decreasing
+    ``ends`` at or below the slot: ``searchsorted(ends, arange(n_slots),
+    side="right")``, the inverse of a running sum (output slot → the row
+    whose run of slots holds it).  jax lane: ONE scatter-add of a mark at
+    every ``ends[k]`` (sorted indices; an entry at or past ``n_slots``
+    drops; rows with no slot repeat their end and add up there) and ONE
+    inclusive int32 running sum over the slots, as ``jnp.repeat`` does inside:
+    no loop, where the search from every slot is log2(len(ends)) rounds of an
+    int64 gather over ``n_slots`` (PERF.md, PR 28).  The sum never passes
+    ``len(ends)``, so int32 holds it, and XLA:TPU takes an int32 running sum
+    inside a conditional's branch where it refuses an int64 one.  numpy
+    lane: the search itself, the tests' independent form of the same map."""
+    if _is_np(xp):
+        return np.searchsorted(np.asarray(ends),
+                               np.arange(n_slots, dtype=np.int64),
+                               side="right")
+    at = xp.minimum(ends, n_slots).astype(np.int32)
+    marks = xp.zeros(n_slots, dtype=np.int32).at[at].add(
+        1, mode="drop", indices_are_sorted=True)
+    return xp.cumsum(marks, dtype=np.int32)
+
+
 def radix_argsort(xp, keys: Array, bits: int = 4) -> Array:
     """Stable LSD radix argsort of int64 keys — the TPU-native candidate
     replacement for the bitonic ``lax.sort`` (`SortBenchmark.scala:120`

@@ -16,13 +16,16 @@ sorted-build + binary-search probe:
    ``searchsorted`` is the TPU-friendly stand-in for hash-table lookup;
 4. duplicate expansion uses the counts-cumsum-gather pattern into a STATIC
    output capacity (``spark.sql.join.outputCapacityFactor`` × probe
-   capacity); the true total is returned as an overflow flag that triggers
+   capacity): the running sum of the match counts gives each probe row's
+   run of output slots, and ``kernels.slot_owner`` inverts it (a mark at
+   every run's end and one running sum over the slots — no search from
+   every slot); the true total is returned as an overflow flag that triggers
    the executor's adaptive capacity retry — the honest dynamic-shape
    escape hatch.  A build side whose matchable keys are all distinct —
    a dimension's — needs none of that: the program reads it from the
    sorted build keys (``_build_unique``) and, where the output has the
    probe's capacity, takes output slot ``j`` for probe row ``j`` — no
-   second search, no slot search, no gather through the probe side;
+   second search, no slot map, no gather through the probe side;
 5. every candidate pair is verified by EXACT per-key value comparison
    (null-aware), so result rows are exact even on the hash search path;
    existence for semi/anti and outer null-extension derives from a
@@ -42,7 +45,7 @@ from ..columnar import (ColumnBatch, ColumnVector, bump_run_aware,
                         pad_capacity, unmaterialized_runs)
 from ..expressions import AnalysisException, Col, EQ, EvalContext, Expression, Hash64
 from ..kernels import (_POSITIONAL_EXPRS, _scope, multi_key_argsort,
-                       searchsorted, take_batch)
+                       searchsorted, slot_owner, take_batch)
 from .logical import Join
 from . import physical as P
 
@@ -460,7 +463,7 @@ class PJoin(P.PhysicalPlan):
             build_s = take_batch(xp, build, perm)
 
         out_cap = pad_capacity(int(probe.capacity * max(self.factor, 0.1)))
-        # the slot search below exists for builds that repeat a key; where
+        # the slot map below exists for builds that repeat a key; where
         # the output has the probe's capacity the program reads from the
         # sorted build keys whether it needs it (full's unmatched-build
         # append and a grown or shrunk output keep the general path)
@@ -547,7 +550,7 @@ class PJoin(P.PhysicalPlan):
             """Output slots, exact verification and the joined rows (for a
             semi / anti join the keep mask).  ``unique``: every match count
             is 0 or 1 and the output has the probe's capacity, so output
-            slot ``j`` IS probe row ``j`` — no slot search, and no gather
+            slot ``j`` IS probe row ``j`` — no slot map, and no gather
             through the probe side."""
             def phase(name):
                 return _scope(xp, "join.unique" if unique else name)
@@ -563,7 +566,7 @@ class PJoin(P.PhysicalPlan):
                 else:
                     offsets = ends - counts_eff     # exclusive prefix
                     slot = xp.arange(out_cap, dtype=np.int64)
-                    i = searchsorted(xp, ends, slot, side="right")
+                    i = slot_owner(xp, ends, out_cap)
                     i = xp.clip(i, 0, probe.capacity - 1)
 
                     def at(a):

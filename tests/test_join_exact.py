@@ -383,3 +383,99 @@ def test_join_path_spans_and_overflow_flag(spark):
         tracing.reset()
         q.collect()
         assert _join_paths()[-1] is want
+
+
+# -- the general path's slot-to-probe-row map (kernels.slot_owner: one
+# -- scatter of marks and one running sum) against the search it replaced ---
+
+def _slot_cases():
+    rng = np.random.default_rng(28)
+    mixed = rng.integers(0, 5, 64)
+    left = np.maximum(rng.integers(0, 3, 64), 1)     # a left join's counts_eff
+    left[rng.random(64) < 0.2] = 0                   # ... of dead probe rows
+    return {
+        "zeros_at_head": (np.r_[np.zeros(5, int), mixed], 256),
+        "zeros_in_middle": (np.r_[mixed[:20], np.zeros(9, int), mixed[20:]],
+                            256),
+        "zeros_at_tail": (np.r_[mixed, np.zeros(7, int)], 256),
+        "every_count_zero": (np.zeros(32, int), 64),
+        "one_row_owns_every_slot": (np.r_[0, 0, 128, 0], 128),
+        "overflow_total_past_slots": (mixed * 4, 128),
+        "overflow_one_end_at_n_slots": (np.r_[3, 61, 5], 64),
+        "slots_16x_rows": (rng.integers(0, 30, 64), 1024),
+        "slots_equal_rows": (rng.integers(0, 3, 128), 128),
+        "slots_a_tenth_of_rows": (rng.random(160) < 0.05, 16),
+        "left_join_counts_eff": (left, 256),
+    }
+
+
+@pytest.mark.parametrize("case", list(_slot_cases()))
+def test_slot_owner_is_the_right_search(case):
+    """``slot_owner`` on the jax lane (eager and inside ``jit``) is
+    ``np.searchsorted(ends, arange(n), "right")`` slot for slot — also
+    where the join overflows: marks at or past ``n_slots`` drop, the slots
+    below agree."""
+    import jax
+    import jax.numpy as jnp
+    from spark_tpu import kernels as K
+    counts, n_slots = _slot_cases()[case]
+    ends = np.cumsum(np.asarray(counts).astype(np.int64))
+    want = np.searchsorted(ends, np.arange(n_slots), "right")
+    if case.startswith("overflow"):
+        assert ends[-1] > n_slots
+    np.testing.assert_array_equal(K.slot_owner(np, ends, n_slots), want)
+    for fn in (K.slot_owner,
+               jax.jit(K.slot_owner, static_argnums=(0, 2))):
+        got = fn(jnp, jnp.asarray(ends), n_slots)
+        assert got.dtype == jnp.int32 and got.shape == (n_slots,)
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("how", ["inner", "left_semi", "left_anti"])
+def test_fanout_self_join_lanes_agree(spark, how):
+    """TPC-DS q95 / q94's shape: orders of 12 lines joined to themselves on
+    the order number with a ``<>`` residual, 16 output slots a probe row
+    (``join_factor_override``).  The jax lane's rows are the numpy lane's,
+    and the pairs a plain count says there must be."""
+    import jax.numpy as jnp
+    from spark_tpu import kernels as K
+    from spark_tpu.sql import physical as P
+    from spark_tpu.sql.planner import Planner, QueryExecution
+    n = 1000                                           # pads to 1024
+    order, wh = np.arange(n) // 12, (np.arange(n) * 7 // 3) % 5
+    spark.createDataFrame(
+        {"o": order.astype(np.int64), "w": wh.astype(np.int64),
+         "line": np.arange(n, dtype=np.int64)}) \
+        .createOrReplaceTempView("fanout_lines")
+    on = "a.o = b.o AND a.w <> b.w"
+    q = spark.sql({
+        "inner": "SELECT a.line, b.line AS other FROM fanout_lines a, "
+                 f"fanout_lines b WHERE {on}",
+        "left_semi": "SELECT a.line FROM fanout_lines a WHERE EXISTS "
+                     f"(SELECT * FROM fanout_lines b WHERE {on})",
+        "left_anti": "SELECT a.line FROM fanout_lines a WHERE NOT EXISTS "
+                     f"(SELECT * FROM fanout_lines b WHERE {on})"}[how])
+    pq = Planner(spark, join_factor_override=[16.0]).plan(
+        QueryExecution(spark, q._plan).optimized)
+    spark.catalog.dropTempView("fanout_lines")
+    assert f"HashJoin {how}" in pq.physical.tree_string()
+
+    def run(xp, leaves):
+        ctx = P.ExecContext(xp, leaves)
+        out = K.compact(xp, pq.physical.run(ctx))
+        caps = [c for k, c in zip(ctx.flag_kinds, ctx.flag_caps)
+                if k == P.JOIN_PATH]
+        flags = [int(f) for f in ctx.flags]
+        assert caps == [(1 << 14, 1 << 10)] and not any(f > 0 for f in flags)
+        return sorted(out.to_host().to_pylist())
+
+    got = run(jnp, [b.to_device() for b in pq.leaves])
+    assert got == run(np, [b.to_host() for b in pq.leaves])
+    same = order[:, None] == order[None, :]
+    pairs = same & (wh[:, None] != wh[None, :])
+    if how == "inner":
+        a, b = np.nonzero(pairs)
+        assert got == sorted(zip(a.tolist(), b.tolist()))
+    else:
+        keep = pairs.any(axis=1) == (how == "left_semi")
+        assert got == [(int(i),) for i in np.nonzero(keep)[0]]

@@ -161,13 +161,16 @@ def test_q3_class_fused_plan_compiles(one_chip, on_tpu, spark):
     assert compiled.memory_analysis() is not None
 
 
-@pytest.mark.parametrize("rows", [500, 30_000, 130_000])
+@pytest.mark.parametrize("rows", [500, 30_000, 130_000, 250_000])
 def test_join_with_both_paths_compiles(one_chip, on_tpu, spark, rows):
     """A join chooses between its two paths in conditionals.  Probe
     capacities 512, 32768 and 131072: three of the lengths at which the TPU
     compiler refuses an int64 ``cumsum`` (a ``reduce-window``) inside a branch —
-    which is why the running sum of the match counts sits BETWEEN the
-    join's two conditionals and in neither."""
+    which is why the int64 running sum of the match counts (``ends``) sits
+    BETWEEN the join's two conditionals and in neither.  The general
+    branch of the second holds ``slot_owner``'s running sum of the marks,
+    an int32 one, which the compiler takes there at every power of two from
+    2^7 to 2^22 (PERF.md, PR 28); 262144 is the web cell's probe."""
     from spark_tpu.sql import physical as P
     from spark_tpu.sql.planner import QueryExecution
     fact = spark.createDataFrame(

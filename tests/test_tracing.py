@@ -5,6 +5,7 @@ an operator reads them through (``SQLExecutionEnd.phases``, ``GET /status``
 
 import json
 import os
+import re
 import threading
 import urllib.request
 
@@ -283,9 +284,26 @@ def test_kernel_scopes_in_lowered_text(in_memory, monkeypatch):
     ) == "PJoin#4/join.probe"
     # a join's two paths are the branches of one conditional in the program:
     # the general path keeps join.probe / join.expand, the unique-build
-    # path runs under join.unique
+    # path runs under join.unique; the slot-to-probe-row map is a scatter
+    # and a running sum, so no loop stands under join.expand (the probe's
+    # searches, under join.probe, keep theirs)
     assert "branch_1_fun/join.unique/" in text \
-        and "branch_0_fun/join.expand/jit(searchsorted)" in text
+        and "branch_0_fun/join.expand/" in text
+    # (the lowered text names a loop inside an inner jit relative to it;
+    # the compiled program's op names are whole paths)
+    assert "join.probe/jit(searchsorted)" in text
+    assert not re.search(r'join\.expand/[^"\n]*(while|searchsorted)', text)
+    from spark_tpu.sql import physical as P
+    fact = in_memory.createDataFrame({"k": np.arange(64, dtype=np.int64) % 16})
+    dim = in_memory.createDataFrame({"dk": np.arange(16, dtype=np.int64) // 2})
+    pq = QueryExecution(
+        in_memory, fact.join(dim, fact["k"] == dim["dk"])._plan).planned
+    names = re.findall(r'op_name="([^"]*)"', jax.jit(
+        lambda leaves: pq.physical.run(P.ExecContext(jnp, list(leaves))))
+        .lower(tuple(b.to_device() for b in pq.leaves)).compile().as_text())
+    assert any("join.probe" in n and "/while/" in n for n in names)
+    assert any("join.expand" in n for n in names)
+    assert not any("join.expand" in n and "while" in n for n in names)
     assert tracing.named_scope_of(
         "jit(step)/stage.step/PJoin#3/cond/branch_1_fun/join.unique/"
         "take_batch/gather"
