@@ -717,14 +717,6 @@ SERVER_POOL_OFFLOAD = conf("spark.tpu.server.pool.offload").doc(
     "local path, so results are never worse than pool-off."
 ).boolean(True)
 
-STAGE_FUSION = conf("spark.tpu.stage.fusion").doc(
-    "Whole-stage tensor compilation: every exchange-bounded stage "
-    "executes as ONE compiled program obtained from the process-local "
-    "stage-executable cache (sql/stagecompile.py).  Off drops to "
-    "per-operator dispatch — one jitted kernel per physical node — the "
-    "debug/baseline mode the stagecache bench lane compares against."
-).boolean(True)
-
 STAGE_CACHE_MAX_ENTRIES = conf("spark.tpu.stage.cacheMaxEntries").doc(
     "Entry bound of the process-local stage-executable cache (LRU "
     "beyond it).  The cache is per PROCESS, not per session: subprocess "

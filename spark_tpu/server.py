@@ -21,7 +21,8 @@ Multi-tenancy guards (serving/ package):
   host-memory limits the client gets a structured 429 with Retry-After,
   never an unbounded queue entry;
 * all server sessions share one ``PlanCache`` mapping optimized-plan
-  fingerprints to compiled executables, so session B skips trace+compile
+  fingerprints to planned statements (their compiled programs are the
+  process stage cache's), so session B skips planning, trace and compile
   for a statement session A already ran (responses carry ``cacheHit`` /
   ``planningSkippedMs``);
 * per-statement deadlines (``spark.tpu.server.statementTimeout``) ride
@@ -343,7 +344,7 @@ class SQLServer:
                 raise RuntimeError(
                     f"session limit {self.max_sessions} reached")
             sess = self.session.newSession()
-            sess._plan_cache = self._plan_cache   # shared plan→executable
+            sess._plan_cache = self._plan_cache   # shared across sessions
             sess._stats_feedback = self._stats_feedback  # shared stats
             # one standing-query registry across the whole tier: the root
             # session's ``streaming`` metrics Source must see every

@@ -1,9 +1,9 @@
-"""Multi-tenant serving core: admission control + plan→executable cache.
+"""Multi-tenant serving core: admission control + cross-session plan cache.
 
 The serving-side counterpart of the exchange work in the parallel/
-package: ``plancache`` amortizes jit trace+compile across sessions (the
-Janino codegen-cache analog), ``admission`` bounds what a shared server
-accepts (the thriftserver pool-backpressure analog).  ``server.py``
+package: ``plancache`` amortizes planning across sessions (the compiled
+programs are the process stage cache's), ``admission`` bounds what a
+shared server accepts (the thriftserver pool-backpressure analog).  ``server.py``
 wires both into the HTTP statement path."""
 
 from .admission import (AdmissionController, AdmissionRejected,

@@ -1,14 +1,14 @@
-"""Cross-session plan → compiled-executable cache for the SQL server.
+"""Cross-session plan cache for the SQL server.
 
 The reference amortizes query compilation twice: Janino bytecode is
 cached process-wide in ``CodeGenerator.compile``'s Guava cache
 (``codegen/CodeGenerator.scala:1415``), and the thriftserver keeps one
-compiled plan serving many sessions.  On TPU the analogous cost is the
-jax trace + XLA compile of the whole-stage program — today paid per
-``SparkSession`` (each has a private ``_jit_cache``), so every new
-server session re-compiles every query.  Flare and TQP (PAPERS.md) both
-locate a compiled engine's serving throughput in exactly this
-amortization.
+compiled plan serving many sessions.  Here the two are two stores with
+one job each: the process ``StageCache`` keeps every compiled one-device
+program (the trace + XLA compile), and this module keeps the PLANNED
+statement, so a repeat from any server session skips analysis-to-plan
+work too.  Flare and TQP (PAPERS.md) both locate a compiled engine's
+serving throughput in exactly this amortization.
 
 This module provides it:
 
@@ -24,11 +24,15 @@ This module provides it:
   objects, host callbacks' side outputs) makes the plan uncacheable
   rather than wrongly shared.
 * ``PlanCache`` — a thread-safe, entry- and byte-bounded LRU from
-  fingerprint → (physical plan, leaf recipes, jit executable,
-  shape-keyed trace metadata).  ``try_execute(qe)`` is the whole
-  integration surface for ``QueryExecution``: it returns a finished
-  host batch on a usable entry (building one on a miss) or ``None`` to
-  fall through to the normal adaptive path.
+  fingerprint → (physical plan, leaf recipes, slot literals): what lets
+  a repeat skip PLANNING.  The compiled program is not kept here: every
+  one-device executable lives in the process ``StageCache``
+  (``sql/stagecompile.py``), reached through
+  ``QueryExecution._run_planned``, which a hit calls with the entry's
+  plan and this statement's literal values.  ``try_execute(qe)`` is the
+  whole integration surface for ``QueryExecution``: it returns a
+  finished host batch on a usable entry (building one on a miss) or
+  ``None`` to fall through to the normal adaptive path.
 
 Safety properties (the invalidation rules, see docs/DECISIONS.md):
 
@@ -43,10 +47,14 @@ Safety properties (the invalidation rules, see docs/DECISIONS.md):
   SET of a planning conf → ``invalidate_conf``) evict entries whose
   PLAN may be stale, and the fingerprint's conf/schema components are
   the correctness backstop for sessions the hooks cannot see.
-* a cached executable's static output capacities may not fit another
-  literal variant's data: overflow flags are checked exactly like the
-  normal path, and an overflowing fingerprint is POISONED (excluded
+* a cached plan's static output capacities may not fit another
+  literal variant's data: overflow flags are checked by the normal
+  path's own code, and an overflowing fingerprint is POISONED (excluded
   from caching) and re-run through the adaptive replan loop.
+* a literal the fingerprint slotted out must be a runtime PARAMETER of
+  the plan's stage program, never a constant of its trace: an entry is
+  admitted only if every slot of the logical fingerprint is (by object
+  identity) a slot of its physical plan's stage fingerprint.
 """
 
 from __future__ import annotations
@@ -54,7 +62,7 @@ from __future__ import annotations
 import collections
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -108,8 +116,6 @@ PLANNING_CONF_ENTRIES = (
     C.SHUFFLE_RANGE_SAMPLE_SIZE, C.CROSSPROC_DEDUP_REPLICATED,
     # adaptive replanning changes which exchange lane a join takes
     C.CROSSPROC_ADAPTIVE_REPLAN,
-    # whole-stage fusion toggles the fused-vs-per-op execution shape
-    C.STAGE_FUSION,
     # exchange tiering: which peers (if any) take the ICI device tier,
     # and the agreed byte floor below which a side stays on the host
     # path, both feed the tier-split decision the lanes replicate
@@ -135,10 +141,12 @@ class PlanFingerprint:
         self.key = key
         self.slots = slots
 
-    def param_values(self, entry_slots: List[E.Literal]) -> Tuple:
-        return tuple(
-            np.asarray(s.value, dtype=ref.dtype.np_dtype)
-            for s, ref in zip(self.slots, entry_slots))
+    def bindings(self, entry_slots: List[E.Literal]) -> Dict[int, Any]:
+        """THIS plan's slot values under the identity of a fingerprint-equal
+        entry's ``Literal`` objects: what ``QueryExecution._run_planned``
+        takes to run the entry's plan for this statement."""
+        return {id(ref): np.asarray(s.value, dtype=ref.dtype.np_dtype)
+                for s, ref in zip(self.slots, entry_slots)}
 
 
 def _ser_expr(e: E.Expression, slots: List[E.Literal],
@@ -237,30 +245,26 @@ def fingerprint(session, plan, leaf_identity=True
 
 
 class _Entry:
-    """One cached compilation: the physical plan, how to re-materialize
-    its leaves, the jit executable and its shape-keyed trace metadata."""
+    """One cached plan: the physical plan and how to re-materialize its
+    leaves (its executable is the stage cache's)."""
 
     __slots__ = ("key", "physical", "recipes", "leaf_schemas", "slots",
-                 "fn", "meta", "paths", "conf_snapshot", "nbytes",
-                 "planning_ms", "hits", "built_at", "notes", "first")
+                 "paths", "conf_snapshot", "nbytes", "planning_ms", "hits",
+                 "built_at")
 
     def __init__(self, key: str, physical, recipes, leaf_schemas, slots,
-                 fn, meta, paths, conf_snapshot, nbytes):
+                 paths, conf_snapshot, nbytes):
         self.key = key
         self.physical = physical
         self.recipes = recipes          # [("local", node) | ("file", node)]
         self.leaf_schemas = leaf_schemas  # [StructType] in planner order
         self.slots = slots              # entry-owned Literal objects
-        self.fn = fn                    # jit(run(leaves, params))
-        self.meta = meta                # shape_key -> (caps, kinds, mkeys)
         self.paths = paths              # abs file paths of file leaves
         self.conf_snapshot = conf_snapshot
         self.nbytes = nbytes
         self.planning_ms = 0.0
         self.hits = 0
         self.built_at = time.time()
-        self.notes: Dict[str, List] = {}   # what its trace noted (tracing)
-        self.first = True               # not called yet: the call compiles
 
 
 class _StageEntry:
@@ -291,13 +295,13 @@ class _StageEntry:
         self.built_at = time.time()
 
 
-#: fixed per-entry cost estimate for the executable + plan objects; the
-#: dominant VARIABLE cost (pinned LocalRelation inputs) is measured
+#: fixed per-entry cost estimate for the plan objects; the dominant
+#: VARIABLE cost (pinned LocalRelation inputs) is measured
 _ENTRY_OVERHEAD_BYTES = 64 << 10
 
 
 class PlanCache:
-    """Thread-safe LRU: fingerprint → compiled executable, shared across
+    """Thread-safe LRU: fingerprint → planned statement, shared across
     every ``_ServerSession`` (attach via ``session._plan_cache``)."""
 
     def __init__(self, conf):
@@ -455,17 +459,13 @@ class PlanCache:
         """The QueryExecution hook: run ``qe`` through the cache.
 
         Returns the finished host ColumnBatch, or None to fall through
-        to the normal adaptive execution path (uncacheable plan, jit
-        disabled, poisoned fingerprint, or capacity overflow)."""
+        to the normal adaptive execution path (uncacheable plan, the
+        interpreted lane, poisoned fingerprint, or capacity overflow)."""
         session = qe.session
         info = {"hit": False, "skippedMs": 0.0}
         session._last_plan_cache_info = info
-        if not session.conf.get(C.CODEGEN_ENABLED):
+        if not qe.compiles():
             return None
-        from ..sql.udf import backend_supports_callbacks, plan_has_slow_udf
-        if plan_has_slow_udf(qe.optimized) \
-                and not backend_supports_callbacks():
-            return None                  # interpreted lane: nothing to cache
         with tracing.span("plancache.lookup", hit=False) as sp:
             fp = qe.fingerprint()
             entry = None if fp is None else self._get(fp.key)
@@ -583,44 +583,22 @@ class PlanCache:
         return out
 
     def _build_and_run(self, qe, fp: PlanFingerprint) -> Optional[Any]:
-        import jax
-
-        from ..kernels import compact
         from ..memory import batch_nbytes
-        from ..sql import physical as P
+        from ..sql.stagecompile import stage_fingerprint
 
         t0 = time.perf_counter()
         pq = qe.planned                  # Planner records leaf recipes
         recipes = getattr(pq, "leaf_recipes", None)
+        # every literal the fingerprint slotted must reach the stage
+        # program as a parameter: one the planner copied or consumed
+        # would be a constant of the trace and serve the next value wrong
+        stage_slots = {id(l) for l in stage_fingerprint(pq.physical)[1]}
         if recipes is None or len(recipes) != len(pq.leaves) \
-                or any(kind == "opaque" for kind, _n in recipes):
+                or any(kind == "opaque" for kind, _n in recipes) \
+                or not all(id(l) in stage_slots for l in fp.slots):
             with self._lock:
                 self.uncacheable += 1
             return None
-        import jax.numpy as jnp
-        physical = pq.physical
-        stage_scope = qe._stage_scope
-        slots = fp.slots                 # entry owns THIS plan's literals
-        meta: Dict[Tuple, Tuple] = {}
-
-        def run(leaves, params):
-            E._slot_bindings.map = {
-                id(lit): p for lit, p in zip(slots, params)}
-            try:
-                with tracing.scope(stage_scope):
-                    ctx = P.ExecContext(jnp, list(leaves))
-                    out = physical.run(ctx)
-                    c = compact(jnp, out)
-                shape_key = tuple(b.capacity for b in leaves)
-                meta[shape_key] = (list(ctx.flag_caps),
-                                   list(ctx.flag_kinds),
-                                   [(oid, lbl)
-                                    for oid, lbl, _v in ctx.metrics])
-                return c, c.num_rows(), ctx.flags, \
-                    [v for _o, _l, v in ctx.metrics]
-            finally:
-                E._slot_bindings.map = None
-
         import os
         paths = []
         for kind, node in recipes:
@@ -630,10 +608,9 @@ class PlanCache:
                      for kind, node in recipes if kind == "local")
         conf_snapshot = {e.key: qe.session.conf.get(e)
                          for e in PLANNING_CONF_ENTRIES}
-        entry = _Entry(fp.key, physical, recipes,
-                       [b.schema for b in pq.leaves], slots,
-                       jax.jit(run), meta, paths, conf_snapshot,
-                       _ENTRY_OVERHEAD_BYTES + pinned)
+        entry = _Entry(fp.key, pq.physical, recipes,
+                       [b.schema for b in pq.leaves], fp.slots, paths,
+                       conf_snapshot, _ENTRY_OVERHEAD_BYTES + pinned)
         out = self._run_entry(qe, entry, fp, first_leaves=pq.leaves)
         if out is None:
             self._poison(fp.key)
@@ -653,50 +630,26 @@ class PlanCache:
 
     def _run_entry(self, qe, entry: _Entry, fp: PlanFingerprint,
                    first_leaves=None) -> Optional[Any]:
-        from ..sql.planner import (PlannedQuery, _leaves_nbytes,
-                                   _plan_reserve_bytes, _slice_to_host)
-        session = qe.session
+        """Run the entry's plan with THIS statement's literal values
+        through ``qe._run_planned`` (the one place that reserves memory,
+        dispatches the stage cache's executable and reads its flags).
+        None: the run overflowed and needs the adaptive loop, which takes
+        it as its first attempt (``qe._last_ratio``)."""
+        from ..sql.planner import PlannedQuery
         if first_leaves is not None:
             leaves = first_leaves
         else:
-            leaves = [self._materialize(r, session) for r in entry.recipes]
+            leaves = [self._materialize(r, qe.session)
+                      for r in entry.recipes]
             for batch, want in zip(leaves, entry.leaf_schemas):
                 if batch.schema.simpleString() != want.simpleString():
                     raise _StaleEntry(
                         f"leaf schema drifted: {batch.schema.simpleString()}"
                         f" != {want.simpleString()}")
-        params = fp.param_values(entry.slots)
-        pq = PlannedQuery(entry.physical, list(leaves))
-        mem = getattr(session, "_memory", None)
-        owner = f"query:{id(qe)}"
-        if mem is not None:
-            mem.acquire_execution(owner, _plan_reserve_bytes(pq))
-        try:
-            with tracing.span("h2d", bytes=_leaves_nbytes(leaves)):
-                dev_leaves = tuple(b.to_device() for b in leaves)
-            # a fresh ``jax.jit`` object per entry, outside the stage
-            # cache: its first call traces and compiles
-            timed = tracing.fresh_jit("plancache._build_and_run") \
-                if entry.first else tracing.span("stage.dispatch")
-            with timed, tracing.collecting(entry.notes):
-                result, n_rows, flags, metric_vals = entry.fn(dev_leaves,
-                                                              params)
-            entry.first = False
-            shape_key = tuple(b.capacity for b in leaves)
-            caps, kinds, mkeys = entry.meta.get(shape_key, ([], [], []))
-            with tracing.span("d2h"):    # the flag fetch waits for the step
-                int_flags = [int(np.asarray(f)) for f in flags]
-                if qe.read_flags(int_flags, caps, kinds) > 0.0:
-                    # needs the adaptive loop, which takes this run as its
-                    # first attempt (``qe._last_ratio``)
-                    return None
-                qe.metrics = {k: int(np.asarray(v))
-                              for k, v in zip(mkeys, metric_vals)}
-                host = _slice_to_host(result, int(np.asarray(n_rows)))
-            return host
-        finally:
-            if mem is not None:
-                mem.release_execution(owner)
+        host, ratio = qe._run_planned(
+            PlannedQuery(entry.physical, list(leaves)),
+            fp.bindings(entry.slots))
+        return None if ratio > 0.0 else host
 
     def metrics_source(self):
         """Gauges for the metrics system ('serving' Source half; the

@@ -41,8 +41,8 @@ from typing import Any, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import types as T
-from ..columnar import (ColumnBatch, ColumnVector, bump_run_aware,
-                        pad_capacity, unmaterialized_runs)
+from ..columnar import (ColumnBatch, ColumnVector, PlaneColumnVector,
+                        bump_run_aware, pad_capacity, unmaterialized_runs)
 from ..expressions import AnalysisException, Col, EQ, EvalContext, Expression, Hash64
 from ..kernels import (_POSITIONAL_EXPRS, _scope, multi_key_argsort,
                        searchsorted, slot_owner, take_batch)
@@ -509,6 +509,12 @@ class PJoin(P.PhysicalPlan):
             if xp is np:
                 return unique_fn() if build_unique else general_fn()
             from jax import lax
+            # a run plane memoizes its dense form at first use: expand the
+            # probe's here, in the enclosing trace, or the tracer of the
+            # branch traced first would be handed to the other
+            for v in probe.vectors:
+                if isinstance(v, PlaneColumnVector):
+                    v.data
             return lax.cond(build_unique, unique_fn, general_fn)
 
         # each probe row's match range: one search says where it starts;

@@ -11,7 +11,7 @@ capacities, dictionaries, schemas — change).
 from __future__ import annotations
 
 import logging
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -588,10 +588,21 @@ class QueryExecution:
         cache).  Without an attached plan cache this is the thunk."""
         if self._root_span is not None:
             self._root_span.attrs["path"] = kind
-        plan_cache = getattr(self.session, "_plan_cache", None)
+        plan_cache = self._statement_cache()
         if plan_cache is None:
             return thunk()
         return plan_cache.run_staged(self, kind, thunk)
+
+    def _statement_cache(self):
+        """The session's serving plan cache where this execution is a
+        statement's ROOT, else None: a sub-plan the stage runner
+        materializes (``stages._eager``) is a new batch every statement,
+        so its entry could never be hit again and would pin that batch;
+        it goes straight to the adaptive loop and the stage cache, which
+        keys leaves by shape."""
+        if self._root_span is None:
+            return None
+        return getattr(self.session, "_plan_cache", None)
 
     def _execute_inner(self) -> ColumnBatch:
         self.session._last_qe = self      # metrics/explain introspection
@@ -664,10 +675,11 @@ class QueryExecution:
                 _log.info("stage runner fallback to eager: %s", e)
 
         # serving plan cache (spark_tpu.serving.plancache): attached to
-        # server sessions, shared across all of them.  A usable entry
-        # skips plan+trace+compile entirely; None falls through to the
-        # normal adaptive path (uncacheable plan, overflow, jit off).
-        plan_cache = getattr(self.session, "_plan_cache", None)
+        # server sessions, shared across all of them, asked at a
+        # statement's root.  A usable entry skips planning and runs its
+        # plan through ``_run_planned``; None falls through to the normal
+        # adaptive path (uncacheable plan, overflow, jit off).
+        plan_cache = self._statement_cache()
         if plan_cache is not None:
             cached_out = plan_cache.try_execute(self)
             if cached_out is not None:
@@ -776,8 +788,12 @@ class QueryExecution:
             check_planned_join_capacities(pq, self.session)
         return self._run_planned(pq)
 
-    def _run_planned(self, pq: PlannedQuery) -> Tuple[ColumnBatch, float]:
+    def _run_planned(self, pq: PlannedQuery, bindings=None
+                     ) -> Tuple[ColumnBatch, float]:
         """One execution attempt → (host result, worst overflow ratio).
+        ``bindings`` ({id(Literal of ``pq.physical``): value}) replaces those
+        literals' own values as the stage's runtime parameters: how a
+        plan-cache hit runs the entry's plan with this statement's values.
 
         Before dispatch the query's device working set is reserved with
         the HBM memory manager (UnifiedMemoryManager's
@@ -796,27 +812,36 @@ class QueryExecution:
         if mem is not None:
             mem.acquire_execution(owner, _plan_reserve_bytes(pq))
         try:
-            return self._run_planned_inner(pq)
+            return self._run_planned_inner(pq, bindings or {})
         finally:
             if mem is not None:
                 mem.release_execution(owner)
 
-    def _run_planned_inner(self, pq: PlannedQuery
+    def compiles(self) -> bool:
+        """Whether this query runs as a compiled stage program (else the
+        interpreted numpy lane, which the plan cache has nothing to keep
+        for)."""
+        if not self.session.conf.get(C.CODEGEN_ENABLED):
+            return False
+        from .udf import backend_supports_callbacks, plan_has_slow_udf
+        if plan_has_slow_udf(self.optimized) \
+                and not backend_supports_callbacks():
+            # per-row Python UDFs need pure_callback; on backends
+            # without host callbacks (some TPU runtimes) the query
+            # drops to the interpreted host lane — the price the
+            # reference pays per-UDF-operator, paid per-query here.
+            # vectorized=True UDFs stay on the device path.
+            _log.info("slow-lane Python UDF on a backend without host "
+                      "callbacks: running interpreted")
+            return False
+        return True
+
+    def _run_planned_inner(self, pq: PlannedQuery, bindings: Dict[int, Any]
                            ) -> Tuple[ColumnBatch, float]:
-        use_jit = self.session.conf.get(C.CODEGEN_ENABLED)
-        if use_jit:
-            from .udf import backend_supports_callbacks, plan_has_slow_udf
-            if plan_has_slow_udf(self.optimized) \
-                    and not backend_supports_callbacks():
-                # per-row Python UDFs need pure_callback; on backends
-                # without host callbacks (some TPU runtimes) the query
-                # drops to the interpreted host lane — the price the
-                # reference pays per-UDF-operator, paid per-query here.
-                # vectorized=True UDFs stay on the device path.
-                _log.info("slow-lane Python UDF on a backend without host "
-                          "callbacks: running interpreted")
-                use_jit = False
-        if not use_jit:
+        if not self.compiles():
+            # the numpy lane reads a literal's own value: a plan-cache hit
+            # (the one source of bindings) never comes here
+            assert not bindings
             ctx = P.ExecContext(np, [b.to_host() for b in pq.leaves])
             out = pq.physical.run(ctx)
             ratio = self.read_flags([int(f) for f in ctx.flags],
@@ -834,16 +859,6 @@ class QueryExecution:
         # bucket pairs and repeated server statements all reuse ONE
         # compiled program per stage shape
         from . import stagecompile as SC
-        if not self.session.conf.get(C.STAGE_FUSION):
-            # baseline mode: one jitted kernel per physical operator,
-            # the dispatch structure the stagecache bench lane measures
-            # fusion against; flags are read back per op so adaptive
-            # retry still works, metrics are dropped (debug lane)
-            c, n_rows, _nd, int_flags, caps, kinds = SC.run_per_op(
-                pq.physical, pq.leaves)
-            ratio = self.read_flags(int_flags, caps, kinds)
-            self.metrics = {}
-            return _slice_to_host(c, n_rows), ratio
         cache = SC.stage_cache(self.session)
         # run-plane decision BEFORE the key: eligible lazy run columns
         # cross the boundary as fixed-capacity planes, and the plane
@@ -893,8 +908,10 @@ class QueryExecution:
         meta = entry.aux
         with tracing.span("h2d", bytes=_leaves_nbytes(stage_leaves)):
             dev_leaves = tuple(b.to_device() for b in stage_leaves)
+        params = tuple(bindings.get(id(l), v)
+                       for l, v in zip(slots, SC.param_values(slots)))
         result, n_rows, flags, metric_vals = cache.dispatch(
-            entry, dev_leaves, SC.param_values(slots))
+            entry, dev_leaves, params)
         shape_key = tuple(b.capacity for b in stage_leaves)
         flag_caps, flag_kinds, metric_keys = meta.get(shape_key,
                                                       ([], [], []))

@@ -23,11 +23,6 @@ The cache is deliberately per PROCESS, not per session: the serving
 tier's sessions and the crossproc subprocess reducers are exactly the
 places where per-session ``_jit_cache`` dicts made compile cost
 O(sessions x queries) instead of O(distinct stage shapes).
-
-``run_per_op`` is the measured BASELINE the fusion claim is judged
-against (bench.py ``stagecache`` lane): the same physical tree executed
-as one fresh jitted kernel per operator, the dispatch structure Spark
-has without WholeStageCodegen.
 """
 
 from __future__ import annotations
@@ -45,7 +40,6 @@ from .. import tracing
 __all__ = [
     "Stage", "StageCache", "stage_cache", "stage_fingerprint",
     "leaf_signature", "count_ops", "metrics_source", "plan_leaves",
-    "run_per_op",
 ]
 
 
@@ -407,100 +401,3 @@ def metrics_source() -> Dict[str, Callable]:
         "run_plane_overflows": _col.run_plane_overflows,
         "run_plane_expansions": _col.run_plane_expansions,
     }
-
-
-# ---------------------------------------------------------------------------
-# per-operator dispatch baseline (fusion off / bench comparison)
-# ---------------------------------------------------------------------------
-
-class _Fixed:
-    """Leaf stand-in holding an already-computed child output so one
-    operator can run in isolation (its children become constants of the
-    single-op trace)."""
-
-    children: Tuple = ()
-    op_id: int = 0
-
-    def __init__(self, batch, schema):
-        self._batch = batch
-        self._schema = schema
-
-    @property
-    def row_offset(self) -> int:
-        return 0
-
-    def offset_in(self, ctx):
-        return getattr(ctx, "shard_offset", 0)
-
-    def schema(self):
-        return self._schema
-
-    def key(self) -> str:
-        return "Fixed"
-
-    def run(self, ctx):
-        return self._batch
-
-
-def run_per_op(physical, leaves
-               ) -> Tuple[Any, int, int, List[int], List[int], List[str]]:
-    """Execute a physical tree as ONE JITTED KERNEL PER OPERATOR —
-    Spark's dispatch structure without WholeStageCodegen, kept as the
-    measured baseline for the fusion claim (bench ``stagecache`` lane;
-    ``spark.tpu.stage.fusion=false``).
-
-    Returns ``(compacted device batch, n_rows, dispatch count,
-    int overflow flags, flag caps, flag kinds)``.  Flags are read back
-    per operator so the adaptive replan loop still sees overflows;
-    per-op execution drops the device-side metric counters (each op runs
-    in its own context), which is why this is a bench/debug lane, not a
-    production mode."""
-    import copy
-
-    import jax
-    import jax.numpy as jnp
-
-    from ..kernels import compact
-    from . import physical as P
-
-    dev = [b.to_device() for b in leaves]
-    n_dispatch = 0
-    int_flags: List[int] = []
-    flag_caps: List[int] = []
-    flag_kinds: List[str] = []
-
-    def rec(node):
-        nonlocal n_dispatch
-        kids = [rec(c) for c in node.children]
-        one = copy.copy(node)
-        one.children = tuple(
-            _Fixed(k, c.schema()) for k, c in zip(kids, node.children))
-        cap_box = []
-
-        def step(ls):
-            ctx = P.ExecContext(jnp, list(ls))
-            out = one.run(ctx)
-            cap_box.append((list(ctx.flag_caps), list(ctx.flag_kinds)))
-            return out, ctx.flags
-
-        n_dispatch += 1
-        # deliberately uncached: this IS the per-op re-trace baseline
-        with tracing.fresh_jit("stagecompile.run_per_op"):
-            out, flags = jax.jit(step)(dev)
-        caps, kinds = cap_box[-1]
-        int_flags.extend(int(np.asarray(f)) for f in flags)
-        flag_caps.extend(caps)
-        flag_kinds.extend(kinds)
-        return out
-
-    out = rec(physical)
-
-    def fin(b):
-        c = compact(jnp, b)
-        return c, c.num_rows()
-
-    n_dispatch += 1
-    with tracing.fresh_jit("stagecompile.run_per_op"):
-        c, n = jax.jit(fin)(out)
-    return c, int(np.asarray(n)), n_dispatch, int_flags, flag_caps, \
-        flag_kinds

@@ -15,10 +15,10 @@ test_faults.py.
 """
 
 import os
-import subprocess
-import sys
 
 import pytest
+
+from worker_procs import run_exchange_workers
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 WORKER = os.path.join(HERE, "adaptive_worker.py")
@@ -28,15 +28,8 @@ MARKERS = ("DEMOTE-OK", "FEEDBACK-OK", "RANGE-DEMOTE-OK", "FROZEN-OK",
 
 
 def _run_adaptive(tmp_path, n, timeout_s=90.0):
-    root = str(tmp_path / "shuf")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("SPARK_TPU_FAULT_PLAN", None)
-    procs = [subprocess.Popen(
-        [sys.executable, WORKER, str(pid), str(n), root, "adaptive",
-         str(timeout_s)],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        env=env) for pid in range(n)]
-    outs = [p.communicate(timeout=420)[0] for p in procs]
+    procs, outs = run_exchange_workers(WORKER, tmp_path, n, "adaptive",
+                                       timeout_s)
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {pid}:\n{out}"
         for m in MARKERS:

@@ -10,10 +10,11 @@ boundaries), upgraded to actual processes.
 
 import os
 import socket
-import subprocess
 import sys
 
 import pytest
+
+from worker_procs import run_workers
 
 _WORKER = os.path.join(os.path.dirname(__file__), "twoproc_worker.py")
 
@@ -32,17 +33,9 @@ def test_two_process_cluster(tmp_path):
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
 
-    def launch(pid):
-        return subprocess.Popen(
-            [sys.executable, _WORKER, str(pid), str(port), beat_dir,
-             shuffle_dir],
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-            text=True, env=env)
-
-    p0 = launch(0)
-    p1 = launch(1)
-    out1, _ = p1.communicate(timeout=120)
-    out0, _ = p0.communicate(timeout=120)
+    (p0, p1), (out0, out1) = run_workers(
+        [[sys.executable, _WORKER, str(pid), str(port), beat_dir,
+          shuffle_dir] for pid in (0, 1)], env, tmp_path, 120)
     assert p1.returncode == 0, f"p1 failed:\n{out1[-3000:]}"
     assert p0.returncode == 0, f"p0 failed:\n{out0[-3000:]}"
     # old jaxlib CPU backends refuse multi-process XLA computations; the

@@ -22,7 +22,6 @@ Spawns ``adaptive_worker.py`` in two modes:
 
 import os
 import re
-import subprocess
 import sys
 
 import pytest
@@ -31,6 +30,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from spark_tpu.parallel.faults import (  # noqa: E402
     FAULT_PLAN_ENV, FaultPlan)
+from worker_procs import run_workers  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 WORKER = os.path.join(HERE, "adaptive_worker.py")
@@ -38,19 +38,18 @@ WORKER = os.path.join(HERE, "adaptive_worker.py")
 
 def _spawn(tmp_path, n, mode, timeout_s, plans=None):
     root = str(tmp_path / "shuf")
-    procs = []
+    envs = []
     for pid in range(n):
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         env.pop(FAULT_PLAN_ENV, None)
         build = (plans or {}).get(pid)
         if build is not None:
             env[FAULT_PLAN_ENV] = build().to_env()
-        procs.append(subprocess.Popen(
-            [sys.executable, WORKER, str(pid), str(n), root, mode,
-             str(timeout_s)],
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-            env=env))
-    return [p.communicate(timeout=420)[0] for p in procs], procs
+        envs.append(env)
+    procs, outs = run_workers(
+        [[sys.executable, WORKER, str(pid), str(n), root, mode,
+          str(timeout_s)] for pid in range(n)], envs, tmp_path, 420)
+    return outs, procs
 
 
 def _run_trace_parity(tmp_path, n):

@@ -34,6 +34,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from spark_tpu import types as T  # noqa: E402
 from spark_tpu.columnar import ColumnBatch, ColumnVector  # noqa: E402
 from spark_tpu.parallel import ici  # noqa: E402
+from worker_procs import run_exchange_workers  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 WORKER = os.path.join(HERE, "shuffled_join_worker.py")
@@ -304,15 +305,8 @@ def test_local_device_exchange_needs_enough_devices():
 # ---------------------------------------------------------------------------
 
 def _run_ici_parity(tmp_path, n, timeout_s=90.0):
-    root = str(tmp_path / "shuf")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("SPARK_TPU_FAULT_PLAN", None)
-    procs = [subprocess.Popen(
-        [sys.executable, WORKER, str(pid), str(n), root, "ici",
-         str(timeout_s)],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        env=env) for pid in range(n)]
-    outs = [p.communicate(timeout=420)[0] for p in procs]
+    procs, outs = run_exchange_workers(WORKER, tmp_path, n, "ici",
+                                       timeout_s)
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {pid}:\n{out}"
         # dict-coded battery pinned to host, still byte-identical

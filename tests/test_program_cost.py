@@ -1,8 +1,8 @@
 """Static program-quality bounds via XLA cost analysis (no TPU needed).
 
 VERDICT r3 item 2 — off-hardware perf insurance: the compiled programs
-behind the bench lanes (`bench.py`) are checked for HBM-traffic and flop
-regressions using ``jit(...).lower(...).compile().cost_analysis()``.
+behind the aggregate, join, sort and scan paths are checked for
+HBM-traffic and flop regressions using ``jit(...).lower(...).compile().cost_analysis()``.
 "The agg program reads its inputs a bounded number of times" is checkable
 today, and is exactly the property the Pallas/MXU formulations exist to
 preserve — a regression to a materialized one-hot round-trip

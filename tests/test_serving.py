@@ -229,6 +229,149 @@ def test_response_cache_fields_on_repeat(serve_root):
 
 
 # ---------------------------------------------------------------------------
+# the plan cache keeps plans; every executable is the stage cache's
+# ---------------------------------------------------------------------------
+
+def _rows(df):
+    return [tuple(r) for r in df.collect()]
+
+
+def _numpy_lane(serve_root):
+    s = serve_root.newSession()
+    s.conf.set(C.CODEGEN_ENABLED.key, "false")
+    return s
+
+
+def test_literal_variants_across_sessions_build_one_stage(serve_root):
+    """Two sessions, one ``PlanCache``, one statement shape, two literal
+    values: ONE program, built by the stage cache (the plan cache makes no
+    ``jax.jit`` of its own), and each value answers as the numpy lane."""
+    from spark_tpu import tracing
+    from spark_tpu.sql.stagecompile import stage_cache
+    cache = PlanCache(serve_root.conf_obj)
+    s1, s2 = serve_root.newSession(), serve_root.newSession()
+    s1._plan_cache = s2._plan_cache = cache
+    q = ("SELECT id % 7 AS g, sum(id * 3) AS s, count(*) AS c FROM range(91) "
+         "WHERE id < {} GROUP BY id % 7 ORDER BY g")
+    tracing.reset()
+    builds = stage_cache().stats()["builds"]
+    a1 = _rows(s1.sql(q.format(40)))
+    a2 = _rows(s2.sql(q.format(77)))
+    assert stage_cache().stats()["builds"] == builds + 1
+    assert "jit.fresh" not in tracing.summary()["counts"]
+    st = cache.stats()
+    assert (st["misses"], st["hits"], st["entries"]) == (1, 1, 1), st
+    oracle = _numpy_lane(serve_root)
+    assert a1 == _rows(oracle.sql(q.format(40)))
+    assert a2 == _rows(oracle.sql(q.format(77))) and a2 != a1
+
+
+def test_literal_outside_the_stage_slots_is_uncacheable(serve_root,
+                                                        monkeypatch):
+    """An entry is admitted only if every literal the logical fingerprint
+    slotted is a runtime parameter of its plan's stage program.  A planner
+    that COPIES a filter's condition leaves the fingerprint's literal out of
+    the physical plan: cached, the first value would be a constant of the
+    trace and answer for the second."""
+    import copy
+
+    from spark_tpu.sql.logical import Filter
+    from spark_tpu.sql.planner import Planner
+    real = Planner._to_physical
+
+    def copying(self, node, leaves):
+        if isinstance(node, Filter):
+            node = Filter(copy.deepcopy(node.condition), node.child)
+        return real(self, node, leaves)
+
+    monkeypatch.setattr(Planner, "_to_physical", copying)
+    cache = PlanCache(serve_root.conf_obj)
+    s = serve_root.newSession()
+    s._plan_cache = cache
+    q = "SELECT id FROM range(50) WHERE id * 2 < {} ORDER BY id"
+    assert _rows(s.sql(q.format(20))) == [(i,) for i in range(10)]
+    assert _rows(s.sql(q.format(64))) == [(i,) for i in range(32)]
+    st = cache.stats()
+    assert st["uncacheable"] == 2 and st["entries"] == 0, st
+
+
+def test_streamed_join_statement_is_cached_at_its_root_only(serve_root):
+    """A statement the stage runner streams enters the plan cache ONCE, at
+    its root: the sub-plans it materializes (a new batch every statement,
+    so an entry that could never be hit again) go straight to the stage
+    cache, where the repeat finds every program."""
+    from spark_tpu import tracing
+    from spark_tpu.sql.planner import QueryExecution
+    from spark_tpu.sql.stages import plan_stages
+    serve_root.conf.set(C.SCAN_MAX_BATCH_ROWS.key, "256")
+    cache = PlanCache(serve_root.conf_obj)
+    s = serve_root.newSession()
+    s._plan_cache = cache
+    s.sql("CREATE TABLE stfact AS SELECT id AS sk, id % 40 AS k, "
+          "id % 9 + 1 AS v FROM range(1100)")
+    s.sql("CREATE TABLE stdim AS SELECT id AS k, id % 5 AS g, id * 2 AS w "
+          "FROM range(40)")
+    q = ("SELECT g, sum(v) AS sv FROM stfact f JOIN stdim d "
+         "ON f.k = d.k WHERE w + 1 > 20 GROUP BY g ORDER BY g")
+    assert plan_stages(s, QueryExecution(s, s.sql(q)._plan).optimized) \
+        is not None
+    first = _rows(s.sql(q))
+    assert s._last_plan_cache_info["hit"] is False
+    entries = len(cache)
+    for _ in range(2):
+        tracing.reset()
+        assert _rows(s.sql(q)) == first
+        assert s._last_plan_cache_info["hit"] is True
+        assert len(cache) == entries
+        spans = tracing.spans()
+        lookups = [sp.attrs["hit"] for sp in spans
+                   if sp.name == "stage.lookup"]
+        assert lookups and all(lookups), lookups
+        assert not any(sp.name in ("jit.fresh", "stage.build")
+                       for sp in spans)
+        assert [sp.attrs["hit"] for sp in spans
+                if sp.name == "plancache.lookup"] == [True]
+    assert first == _rows(_numpy_lane(serve_root).sql(q))
+    s.sql("DROP TABLE stfact")
+    s.sql("DROP TABLE stdim")
+
+
+def test_overflowing_cached_run_is_poisoned_and_replans_once(serve_root):
+    """A plan-cache run that overflows its planned capacities poisons the
+    fingerprint and IS the adaptive loop's first attempt: one re-plan, two
+    executions, never a third that repeats the planned capacities."""
+    import numpy as np
+
+    from spark_tpu import tracing
+    cache = PlanCache(serve_root.conf_obj)
+    s = serve_root.newSession()
+    s._plan_cache = cache
+    o = np.arange(400, dtype=np.int64) % 20
+    s.createDataFrame({"o": o, "q": np.arange(400, dtype=np.int64)}) \
+        .createOrReplaceTempView("pclines")
+    q = ("SELECT count(*) AS n FROM pclines a, pclines b "
+         "WHERE a.o = b.o AND b.q < 1000")
+    tracing.reset()
+    assert _rows(s.sql(q)) == [(400 * 20,)]
+    spans = tracing.spans()
+    assert [sp.attrs["attempt"] for sp in spans
+            if sp.name == "join.replan"] == [1]
+    assert sum(sp.name == "d2h" for sp in spans) == 2
+    # both attempts are the stage cache's programs
+    assert sum(sp.name == "stage.build" for sp in spans) == 2
+    assert not any(sp.name == "jit.fresh" for sp in spans)
+    st = cache.stats()
+    assert (st["misses"], st["hits"], st["entries"]) == (1, 0, 0), st
+    assert len(cache._poisoned) == 1
+    tracing.reset()                      # the repeat: kept capacities, once
+    assert _rows(s.sql(q)) == [(400 * 20,)]
+    spans = tracing.spans()
+    assert not any(sp.name == "join.replan" for sp in spans)
+    assert sum(sp.name == "d2h" for sp in spans) == 1
+    assert cache.stats()["entries"] == 0
+
+
+# ---------------------------------------------------------------------------
 # admission control
 # ---------------------------------------------------------------------------
 

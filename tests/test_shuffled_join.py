@@ -23,14 +23,13 @@ skew-splitting span→reducer planner) gets direct unit tests here too.
 """
 
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 
 from spark_tpu import config as C
 from spark_tpu.parallel.hostshuffle import HostShuffleService
+from worker_procs import run_exchange_workers
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 WORKER = os.path.join(HERE, "shuffled_join_worker.py")
@@ -222,15 +221,8 @@ def test_shuffled_join_flag_is_safe_single_process(spark, tmp_path):
 # ---------------------------------------------------------------------------
 
 def _run_parity(tmp_path, n, timeout_s=90.0):
-    root = str(tmp_path / "shuf")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("SPARK_TPU_FAULT_PLAN", None)
-    procs = [subprocess.Popen(
-        [sys.executable, WORKER, str(pid), str(n), root, "parity",
-         str(timeout_s)],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        env=env) for pid in range(n)]
-    outs = [p.communicate(timeout=420)[0] for p in procs]
+    procs, outs = run_exchange_workers(WORKER, tmp_path, n, "parity",
+                                       timeout_s)
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {pid}:\n{out}"
         assert f"[p{pid}] ALL-OK" in out, out
@@ -255,15 +247,8 @@ def test_parity_three_processes(tmp_path):
 # ---------------------------------------------------------------------------
 
 def _run_spill_parity(tmp_path, n, timeout_s=90.0):
-    root = str(tmp_path / "shuf")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("SPARK_TPU_FAULT_PLAN", None)
-    procs = [subprocess.Popen(
-        [sys.executable, WORKER, str(pid), str(n), root, "spill",
-         str(timeout_s)],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        env=env) for pid in range(n)]
-    outs = [p.communicate(timeout=420)[0] for p in procs]
+    procs, outs = run_exchange_workers(WORKER, tmp_path, n, "spill",
+                                       timeout_s)
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {pid}:\n{out}"
         # the full battery passed against the oracle AND the spill path
@@ -289,15 +274,8 @@ def test_spill_parity_three_processes(tmp_path):
 # ---------------------------------------------------------------------------
 
 def _run_grace_parity(tmp_path, n, timeout_s=90.0):
-    root = str(tmp_path / "shuf")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("SPARK_TPU_FAULT_PLAN", None)
-    procs = [subprocess.Popen(
-        [sys.executable, WORKER, str(pid), str(n), root, "grace",
-         str(timeout_s)],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        env=env) for pid in range(n)]
-    outs = [p.communicate(timeout=420)[0] for p in procs]
+    procs, outs = run_exchange_workers(WORKER, tmp_path, n, "grace",
+                                       timeout_s)
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {pid}:\n{out}"
         assert f"[p{pid}] GRACE-OK" in out, out
@@ -329,15 +307,8 @@ def test_grace_parity_three_processes(tmp_path):
 # ---------------------------------------------------------------------------
 
 def _run_runcodes_parity(tmp_path, n, timeout_s=90.0):
-    root = str(tmp_path / "shuf")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("SPARK_TPU_FAULT_PLAN", None)
-    procs = [subprocess.Popen(
-        [sys.executable, WORKER, str(pid), str(n), root, "runcodes",
-         str(timeout_s)],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        env=env) for pid in range(n)]
-    outs = [p.communicate(timeout=420)[0] for p in procs]
+    procs, outs = run_exchange_workers(WORKER, tmp_path, n, "runcodes",
+                                       timeout_s)
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {pid}:\n{out}"
         # the worker asserted the gauge side (rle_columns_encoded,

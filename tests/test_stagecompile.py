@@ -1,14 +1,11 @@
 """Whole-stage tensor compilation (sql/stagecompile.py): the
 process-local stage-executable cache, literal-parameterized sharing,
-fusion-vs-per-op parity, and the fused-stage boundary contract.
+and the fused-stage boundary contract.
 
 The claims under test: repeated structurally-equal queries reuse ONE
 compiled stage program (no fresh jax.jit per execution); literal
 variants share that program with values riding as runtime arguments;
-fusion changes dispatch structure only — the per-operator baseline
-(`run_per_op`, `spark.tpu.stage.fusion=false`) produces byte-identical
-results at >=3x the dispatch count; and a stage whose recorded cut
-schemas disagree with the unfused physical tree fails
+and a stage whose recorded cut schemas disagree with the unfused physical tree fails
 ``verify_stage_contract`` loudly, never misexecutes."""
 
 import numpy as np
@@ -97,46 +94,6 @@ def test_stage_cache_entry_bound_is_lru(sess):
         c.get_or_build(f"k{i}", lambda: ((lambda x: x), None))
     assert len(c) == 2
     assert c.stats()["builds"] == 4
-
-
-# ---------------------------------------------------------------------------
-# fused vs per-operator dispatch: parity + the >=3x dispatch claim
-# ---------------------------------------------------------------------------
-
-def test_per_op_baseline_parity_and_dispatch_count(sess):
-    _mk(sess)
-    pq = _planned(
-        sess, "SELECT k, sum(v) AS sv, count(v) AS c FROM scq "
-              "WHERE v < 800 GROUP BY k")
-    fused = [tuple(r)
-             for r in sess.sql("SELECT k, sum(v) AS sv, count(v) AS c "
-                               "FROM scq WHERE v < 800 GROUP BY k "
-                               "ORDER BY k").collect()]
-    out, n_rows, n_dispatch, flags, caps, _k = SC.run_per_op(
-        pq.physical, pq.leaves)
-    assert not any(f > 0 for f in flags), "per-op run must not overflow"
-    from spark_tpu.sql.planner import _slice_to_host
-    host = _slice_to_host(out, n_rows)
-    per_op = sorted(zip(*(np.asarray(v.data)[:n_rows]
-                          for v in host.vectors)))
-    assert per_op == sorted(fused), \
-        "fusion may change dispatch structure, never results"
-    # the fused stage runs as ONE dispatch; per-op pays one per operator
-    assert n_dispatch >= 3, \
-        f"scan-filter-project-agg should be >=3 ops, got {n_dispatch}"
-    assert n_dispatch >= 3 * 1
-
-
-def test_stage_fusion_conf_off_matches_fused_results(sess):
-    _mk(sess)
-    q = ("SELECT k, sum(v) AS sv FROM scq WHERE v < 600 "
-         "GROUP BY k ORDER BY k")
-    fused = [tuple(r) for r in sess.sql(q).collect()]
-    sess.conf.set(C.STAGE_FUSION.key, "false")
-    try:
-        assert [tuple(r) for r in sess.sql(q).collect()] == fused
-    finally:
-        sess.conf.set(C.STAGE_FUSION.key, "true")
 
 
 # ---------------------------------------------------------------------------
@@ -363,3 +320,26 @@ def test_plane_stage_fallback_matches_plane_result(sess):
     finally:
         sess.conf.set(C.STAGE_RUN_PLANES.key, "true")
     assert on == off
+
+
+def test_plane_column_rides_a_join_probe_through_both_paths(sess):
+    """A run plane memoizes its dense form at first use.  ``PJoin`` traces
+    its unique-build and general paths as the two branches of one
+    ``lax.cond``: a plane first expanded inside a branch would hand that
+    branch's tracer to the other (``UnexpectedTracerError``), so the join
+    expands its probe's planes before the conditional."""
+    from spark_tpu.sql import logical as L
+    from spark_tpu.sql.dataframe import DataFrame
+    b = _run_leaf(32, 16)
+    DataFrame(sess, L.LocalRelation(b)).createOrReplaceTempView("rp_probe")
+    sess.createDataFrame({"k": np.arange(7, dtype=np.int64),
+                          "w": np.arange(7, dtype=np.int64) * 10}
+                         ).createOrReplaceTempView("rp_dim")
+    q = ("SELECT count(*) AS c, sum(ts) AS st, sum(w) AS sw "
+         "FROM rp_probe JOIN rp_dim ON v = k WHERE ts < 20")
+    dense = np.repeat(np.arange(32, dtype=np.int64), 16)
+    v = np.arange(512, dtype=np.int64) % 7
+    keep = dense < 20
+    got = sess.sql(q).collect()
+    assert tuple(got[0]) == (int(keep.sum()), int(dense[keep].sum()),
+                             int((v[keep] * 10).sum()))

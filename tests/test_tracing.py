@@ -401,25 +401,33 @@ def test_status_trace_and_http_spans(in_memory):
         srv.stop()
 
 
-def test_plancache_build_is_a_fresh_jit(in_memory):
-    """The serving plan cache builds a ``jax.jit`` of its own for a new
-    plan shape: the span and counter ``jit.fresh`` own it."""
+def test_plancache_miss_builds_in_the_stage_cache(in_memory):
+    """The serving plan cache keeps plans, not executables: a miss builds
+    its program in the stage cache (``stage.lookup`` miss + ``stage.build``,
+    no ``jit.fresh``), and the repeat hits both stores."""
     from spark_tpu.serving import PlanCache
     in_memory._plan_cache = PlanCache(in_memory.conf_obj)
     try:
+        sql = ("SELECT i_brand_id, COUNT(*) c, SUM(i_item_sk) + 29 s "
+               "FROM item WHERE i_manufact_id = 7 GROUP BY i_brand_id")
         tracing.reset()
-        sql = ("SELECT i_brand_id, COUNT(*) c FROM item "
-               "WHERE i_manufact_id = 7 GROUP BY i_brand_id")
-        in_memory.sql(sql).collect()
-        fresh = [s for s in tracing.spans() if s.name == "jit.fresh"]
-        assert [s.attrs["site"] for s in fresh] \
-            == ["plancache._build_and_run"]
-        assert tracing.summary()["counts"]["jit.fresh"] == 1
-        in_memory.sql(sql).collect()               # a hit: no new jit
-        assert tracing.summary()["counts"]["jit.fresh"] == 1
-        look = [s.attrs["hit"] for s in tracing.spans()
-                if s.name == "plancache.lookup"]
-        assert look == [False, True]
+        first, spans = in_memory.sql(sql).collect(), tracing.spans()
+        names = [s.name for s in spans]
+        assert "jit.fresh" not in names
+        assert "jit.fresh" not in tracing.summary()["counts"]
+        assert [s.attrs["hit"] for s in spans
+                if s.name == "stage.lookup"] == [False]
+        assert names.count("stage.build") == 1
+        tracing.reset()
+        again, spans = in_memory.sql(sql).collect(), tracing.spans()
+        assert again == first
+        names = [s.name for s in spans]
+        assert "jit.fresh" not in names and "stage.build" not in names
+        assert "plan" not in names                 # the hit skipped planning
+        assert [s.attrs["hit"] for s in spans
+                if s.name == "plancache.lookup"] == [True]
+        assert [s.attrs["hit"] for s in spans
+                if s.name == "stage.lookup"] == [True]
     finally:
         in_memory._plan_cache = None
 
