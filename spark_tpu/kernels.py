@@ -104,10 +104,63 @@ def searchsorted(xp, a: Array, v: Array, side: str = "left") -> Array:
     jnp's default while-loop ``scan`` on the jax lane.  (An unrolled
     lowering was once forced on TPU on a guess; on a v5e both compile, run
     and take the same 0.36 s at a 2048-row build and a 2^21-row probe —
-    PERF.md, PR 23 — and on CPU the scan is 2.3x faster.)"""
+    PERF.md, PR 23 — and on CPU the scan is 2.3x faster.)  Every round of
+    the loop is an int64 gather over all of ``v``: where the keys of ``a``
+    span few integers (``keys_span_under``) a join asks ``table_search``
+    instead, which reads each answer from a table indexed by the key."""
     if _is_np(xp):
         return np.searchsorted(np.asarray(a), np.asarray(v), side=side)
     return xp.searchsorted(a, v, side=side)
+
+
+def keys_span_under(xp, a: Array, m: Array, size: int) -> Array:
+    """True where the first ``m`` entries of the sorted int64 ``a`` hold at
+    least one key and span fewer than ``size`` integers (``a[m-1] - a[0] <
+    size``): a table of ``size`` entries indexed by ``key - a[0]`` then has
+    a place for every one of them.  The difference is taken in uint64
+    (Python integers on the numpy lane), where it cannot wrap: a build that
+    holds both a very negative and a very positive key reads "not dense"."""
+    if _is_np(xp):
+        m = int(m)
+        return m > 0 and int(a[m - 1]) - int(a[0]) < size
+    first, last = a[0], a[xp.maximum(m - 1, 0)]
+    span = last.astype(np.uint64) - first.astype(np.uint64)
+    return (m > 0) & (span < np.uint64(size))
+
+
+def table_search(xp, a: Array, m: Array, v: Array, size: int
+                 ) -> Tuple[Array, Array]:
+    """``(lo, n_eq)`` of every ``v`` in the first ``m`` entries of the sorted
+    int64 ``a``: ``lo = searchsorted(a[:m], v, "left")`` and ``lo + n_eq``
+    the ``"right"`` one, where ``keys_span_under(a, m, size)`` holds.  jax
+    lane: a direct-address table in place of the searches.  ONE scatter-add
+    of a 1 at ``key - a[0]`` for each of the ``m`` keys (a histogram of
+    ``size`` int32 entries; ``size`` a power of two) and ONE int32 running
+    sum over it; a key's count is its histogram entry and the keys at or
+    below it the running sum there, so each ``v`` pays ONE int32 gather of
+    the two stacked as a ``[2, size]`` plane where a search pays
+    ``log2(len(a))`` rounds of an int64 one, twice (on a v5e at 2^22 rows:
+    26 ms for the plane, 129 for the two tables apart, 1,617 for the
+    searches — PERF.md, PR 30).  Below ``a[0]`` the answer is 0, above
+    ``a[m-1]`` it is ``m``.  (XLA:TPU takes an int32 running sum inside a
+    conditional's branch at every power of two, as for ``slot_owner``.)
+    numpy lane: the searches themselves, the tests' independent form of the
+    same lookup."""
+    if _is_np(xp):
+        head = np.asarray(a)[:int(m)]
+        lo = np.searchsorted(head, np.asarray(v), side="left")
+        return lo, np.searchsorted(head, np.asarray(v), side="right") - lo
+    first, last = a[0], a[xp.maximum(m - 1, 0)]
+    at = xp.where(xp.arange(a.shape[0], dtype=np.int32) < m, a - first, size)
+    hist = xp.zeros(size, dtype=np.int32).at[at.astype(np.int32)].add(
+        1, mode="drop", indices_are_sorted=True)
+    plane = xp.stack([hist, xp.cumsum(hist, dtype=np.int32)])
+    inside = (v >= first) & (v <= last)
+    count, upto = plane[:, xp.where(inside, v - first, 0).astype(np.int32)]
+    n_eq = xp.where(inside, count, 0)
+    lo = xp.where(inside, upto - n_eq,
+                  xp.where(v < first, 0, m).astype(np.int32))
+    return lo, n_eq
 
 
 def slot_owner(xp, ends: Array, n_slots: int) -> Array:

@@ -366,7 +366,7 @@ def shard_program(physical, mesh: Mesh):
             for f, kind, cap in zip(ctx.flags, ctx.flag_kinds,
                                     ctx.flag_caps):
                 if kind == P.JOIN_PATH:
-                    paths.append(pmax(f))
+                    paths.append(P.all_shards_path(f, pmax))
                     continue
                 if kind == "shrink":
                     lost = f.astype(jnp.int64)

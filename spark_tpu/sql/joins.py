@@ -13,7 +13,15 @@ sorted-build + binary-search probe:
    combined hash with NULL/dead sentinels outside the hash range.
 2. the build side sorts by search key (dead rows sentineled to the end);
 3. each probe row binary-searches its match range [lo, hi) —
-   ``searchsorted`` is the TPU-friendly stand-in for hash-table lookup;
+   ``searchsorted`` is the TPU-friendly stand-in for hash-table lookup.
+   Where the search key is the value itself (1.) and the build's matchable
+   keys span fewer integers than the larger of the two sides has rows — a
+   surrogate key's do — the range is READ, not searched for: a
+   direct-address table indexed by ``key - first`` (``kernels.table_search``:
+   one scatter over the build, two int32 gathers a probe row, where a search
+   is log2(build) rounds of an int64 gather).  The program decides from the
+   sorted build keys, like 4.'s choice; hashes, floats' bit patterns and
+   sparse ids fail the test and are searched;
 4. duplicate expansion uses the counts-cumsum-gather pattern into a STATIC
    output capacity (``spark.sql.join.outputCapacityFactor`` × probe
    capacity): the running sum of the match counts gives each probe row's
@@ -44,8 +52,9 @@ from .. import types as T
 from ..columnar import (ColumnBatch, ColumnVector, PlaneColumnVector,
                         bump_run_aware, pad_capacity, unmaterialized_runs)
 from ..expressions import AnalysisException, Col, EQ, EvalContext, Expression, Hash64
-from ..kernels import (_POSITIONAL_EXPRS, _scope, multi_key_argsort,
-                       searchsorted, slot_owner, take_batch)
+from ..kernels import (_POSITIONAL_EXPRS, _scope, keys_span_under,
+                       multi_key_argsort, searchsorted, slot_owner,
+                       table_search, take_batch)
 from .logical import Join
 from . import physical as P
 
@@ -396,8 +405,9 @@ class PJoin(P.PhysicalPlan):
 
         # the phases below are the device scopes of a join (tracing.py):
         # join.keys, join.build_sort, join.probe (the searches and the match
-        # counts), then join.expand + join.gather on the general path or
-        # join.unique on the unique-build path
+        # counts) or join.dense (the table in their place), then join.expand
+        # + join.gather on the general path or join.unique on the
+        # unique-build path
         with _scope(xp, "join.keys"):
             # exact int64 encodings per key pair (None → hashB fallback for
             # that pair's verification).  A single probe key riding an
@@ -499,6 +509,17 @@ class PJoin(P.PhysicalPlan):
             build_live_s = build_live[perm]
             build_unique = _build_unique(
                 xp, ba_s, b_flag_s if exact else None) if skippable else False
+            # an exact build whose matchable keys span fewer integers than
+            # the join's larger side has rows (surrogate keys do) is asked
+            # through a table of that many entries, not searched: read in
+            # the program from the sorted keys, as ``build_unique`` is.  The
+            # numpy lane keeps the searches, the tests' independent form
+            tabled = exact and xp is not np
+            dense = False
+            if tabled:
+                table_size = pad_capacity(max(probe.capacity, build.capacity))
+                n_keys = xp.sum(b_flag_s == 0, dtype=np.int32)
+                dense = keys_span_under(xp, ba_s, n_keys, table_size)
 
         def choose(unique_fn, general_fn):
             """The unique-build path or the general one, by what the sorted
@@ -517,22 +538,36 @@ class PJoin(P.PhysicalPlan):
                     v.data
             return lax.cond(build_unique, unique_fn, general_fn)
 
-        # each probe row's match range: one search says where it starts;
-        # how many build rows it holds takes a second search only where a
-        # matchable build key can repeat
-        with _scope(xp, "join.probe"):
-            lo = searchsorted(xp, ba_s, pa, side="left")
-
-        def first_is_equal():
+        # each probe row's match range [lo, lo + n_eq).  By search: one
+        # search says where it starts; how many build rows it holds takes a
+        # second one only where a matchable build key can repeat.  By table:
+        # both are read at ``key - first`` (``kernels.table_search``)
+        def by_search(unique: bool):
+            with _scope(xp, "join.probe"):
+                lo = searchsorted(xp, ba_s, pa, side="left")
+                if not unique:
+                    return lo, searchsorted(xp, ba_s, pa, side="right") - lo
             with _scope(xp, "join.unique"):
                 at_lo = ba_s[xp.clip(lo, 0, build.capacity - 1)]
-                return (at_lo == pa).astype(lo.dtype)
+                return lo, (at_lo == pa).astype(lo.dtype)
 
-        def range_length():
-            with _scope(xp, "join.probe"):
-                return searchsorted(xp, ba_s, pa, side="right") - lo
+        def by_table():
+            with _scope(xp, "join.dense"):
+                return table_search(xp, ba_s, n_keys, pa, table_size)
 
-        n_eq = choose(first_is_equal, range_length)
+        lookups = [lambda: by_search(False)]
+        lookup = 0
+        if skippable:
+            lookups.append(lambda: by_search(True))
+            lookup = xp.asarray(build_unique).astype(np.int32)
+        if tabled:
+            lookups.append(by_table)
+            lookup = xp.where(dense, len(lookups) - 1, lookup)
+        if xp is np:
+            lo, n_eq = lookups[int(lookup)]()
+        else:
+            from jax import lax
+            lo, n_eq = lax.switch(lookup, lookups)
 
         # (the running sum of the counts stays BETWEEN the two conditionals:
         # it lowers to an int64 reduce-window, which the TPU compiler refuses
@@ -670,7 +705,7 @@ class PJoin(P.PhysicalPlan):
 
         if hasattr(ctx, "add_flag"):
             ctx.add_flag(xp.maximum(total - out_cap, 0), "join", out_cap)
-            ctx.add_join_path(build_unique, out_cap, probe.capacity)
+            ctx.add_join_path(build_unique, dense, out_cap, probe.capacity)
 
         if how in ("left_semi", "left_anti"):
             return ColumnBatch(probe.names, probe.vectors,

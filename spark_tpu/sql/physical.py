@@ -36,21 +36,31 @@ Array = Any
 #: the flag kind of ``ExecContext.add_join_path``, and the host span that
 #: ``record_join_paths`` writes for it
 JOIN_PATH = "join.path"
+#: the bits of that flag's negated value
+PATH_UNIQUE, PATH_DENSE = 1, 2
 
 
 def record_join_paths(int_flags, kinds, caps=()) -> None:
     """One ``join.path`` span for each join of the step whose flags were
-    just fetched: ``unique`` (the path it took) and, from the trace's static
-    capacities where the lane kept them, ``out_cap`` and ``probe_cap`` (the
-    join's fan-out).  ``python -m spark_tpu.tracing`` and the benchmark's
-    ``join.unique_pct`` read them."""
+    just fetched: ``unique`` and ``dense`` (the paths it took: the rows and
+    the probe lookup) and, from the trace's static capacities where the lane
+    kept them, ``out_cap`` and ``probe_cap`` (the join's fan-out).  ``python
+    -m spark_tpu.tracing`` and the benchmark's ``join.unique_pct`` /
+    ``join.dense_pct`` read them."""
     for n, (f, k) in enumerate(zip(int_flags, kinds)):
         if k == JOIN_PATH:
-            attrs = {"unique": bool(f < 0)}
+            attrs = {"unique": bool(-f & PATH_UNIQUE),
+                     "dense": bool(-f & PATH_DENSE)}
             if n < len(caps):
                 attrs["out_cap"], attrs["probe_cap"] = caps[n]
             with tracing.span(JOIN_PATH, **attrs):
                 pass
+
+
+def all_shards_path(flag, pmax):
+    """A ``JOIN_PATH`` flag over the mesh: a path reads taken only where
+    every shard took it, so each bit is the minimum over shards."""
+    return sum(pmax(-(-flag & bit)) for bit in (PATH_UNIQUE, PATH_DENSE))
 
 
 class ExecContext:
@@ -73,16 +83,21 @@ class ExecContext:
         self.flag_kinds.append(kind)
         self.flag_caps.append(cap)
 
-    def add_join_path(self, unique, out_cap: int, probe_cap: int) -> None:
-        """Which path a join ran (``joins.PJoin``: the unique-build path or
-        the general one), beside the overflow flags so that it comes back
-        in their fetch: kind ``JOIN_PATH``, -1 unique / 0 general.  Never
-        positive, so no overflow test (each reads ``f > 0``) sees it, and
-        the maximum over shards reads unique only where every shard's
-        build was.  Its static "capacity" is the pair (output slots, probe
-        capacity), which ``record_join_paths`` puts on the span."""
-        self.add_flag(-self.xp.asarray(unique).astype(np.int32),
-                      JOIN_PATH, (out_cap, probe_cap))
+    def add_join_path(self, unique, dense, out_cap: int,
+                      probe_cap: int) -> None:
+        """Which paths a join ran (``joins.PJoin``: the unique-build rows or
+        the general ones; the probe lookup by table or by search), beside
+        the overflow flags so that it comes back in their fetch: kind
+        ``JOIN_PATH``, minus the sum of ``PATH_UNIQUE`` and ``PATH_DENSE``
+        where taken.  Never positive, so no overflow test (each reads ``f >
+        0``) sees it; over shards each bit is reduced apart
+        (``all_shards_path``).  Its static "capacity" is the pair (output
+        slots, probe capacity), which ``record_join_paths`` puts on the
+        span."""
+        xp = self.xp
+        bits = xp.asarray(unique).astype(np.int32) * PATH_UNIQUE \
+            + xp.asarray(dense).astype(np.int32) * PATH_DENSE
+        self.add_flag(-bits, JOIN_PATH, (out_cap, probe_cap))
 
     def add_metric(self, op_id: int, label: str, value: Array) -> None:
         self.metrics.append((op_id, label, value))

@@ -67,8 +67,8 @@ SPAN_PREFIX = "sql:"
 #: ``<PhysicalPlan class>#<op_id>``; ``argsort.pass<i>`` is one chained pass)
 KERNEL_SCOPES = frozenset({
     "stage.step", "stage.merge",
-    "join.keys", "join.build_sort", "join.probe", "join.expand",
-    "join.gather", "join.unique",
+    "join.keys", "join.build_sort", "join.probe", "join.dense",
+    "join.expand", "join.gather", "join.unique",
     "agg.onehot", "agg.mxu", "agg.mxu.limbs", "agg.sort", "agg.sort.argsort",
     "agg.sort.permute", "agg.sort.segment", "pallas_agg",
     "sort_batch", "argsort", "take_batch", "compact", "partition_bucket",
@@ -449,14 +449,17 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
     ``{"device", "busy_s", "unnamed_s", "unnamed_pct", "by_scope": [[scope,
     seconds], ...], "top_ops": [[instruction, scope, seconds], ...] (``top``),
     "unnamed_top": [[instruction, seconds], ...], "host_spans": {name:
-    [count, seconds]}, "profile_start_ns", "annotations": [[name, start_ns
-    (epoch), dur_ns], ...]}``
+    [count, seconds]}, "join_paths": [[{unique, dense, out_cap, probe_cap},
+    count], ...] (the ``join.path`` spans by their attributes),
+    "profile_start_ns", "annotations": [[name, start_ns (epoch), dur_ns],
+    ...]}``
 
     Device seconds are self times on the busiest device's ``XLA Ops``
     line; ``unnamed`` is the part under no scope this module names."""
     data = jax.profiler.ProfileData.from_file(xplane_path)
     op_names = _op_names(xplane_path)
     devices, host, annotations, start_ns = {}, {}, [], None
+    join_paths: "collections.Counter[str]" = collections.Counter()
     for plane in data.planes:
         if plane.name == "Task Environment":
             start_ns = dict(plane.stats).get("profile_start_time", start_ns)
@@ -478,6 +481,9 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
                         got[0] += 1
                         got[1] += e.duration_ns / 1e9
                         annotations.append([name, e.start_ns, e.duration_ns])
+                        if name == "join.path":
+                            join_paths[json.dumps(dict(e.stats),
+                                                  sort_keys=True)] += 1
     if start_ns is not None:
         for a in annotations:
             a[1] = int(a[1] + start_ns)
@@ -485,6 +491,8 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
            "unnamed_pct": None, "by_scope": [], "top_ops": [],
            "unnamed_top": [],
            "host_spans": host, "profile_start_ns": start_ns,
+           "join_paths": [[json.loads(a), n]
+                          for a, n in join_paths.most_common()],
            "annotations": annotations}
     if not devices:
         return out
@@ -584,6 +592,8 @@ def _main(argv) -> int:
         for name, (n, s) in sorted(r["host_spans"].items(),
                                    key=lambda kv: -kv[1][1])[:24]:
             print(f"  {n:6d} {s:10.4f} s  sql:{name}")
+        for attrs, n in r["join_paths"]:
+            print(f"  {n:6d} sql:join.path  {json.dumps(attrs)}")
         print(json.dumps({"profile_start_ns": r["profile_start_ns"],
                           "annotations": len(r["annotations"])}))
     return 0

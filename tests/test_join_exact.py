@@ -216,10 +216,10 @@ def test_literal_equality_is_filter_not_join_key(spark):
 # -- the unique-build path (PJoin: chosen in the program from the sorted
 # -- build keys) against the general path and a pandas reference -----------
 
-def _join_paths():
+def _join_paths(attr="unique"):
     from spark_tpu import tracing
-    return [s.attrs["unique"] for s in tracing.spans()
-            if s.name == "join.path"]
+    return [s.attrs if attr is None else s.attrs[attr]
+            for s in tracing.spans() if s.name == "join.path"]
 
 
 def _path_case(build, two_key, seed):
@@ -376,13 +376,211 @@ def test_join_path_spans_and_overflow_flag(spark):
             pq.physical.run(ctx)
             assert ctx.flag_kinds == ["join", P.JOIN_PATH]
             flags = [int(f) for f in ctx.flags]
-            assert flags[1] == (-1 if want else 0)
+            # keys 0..15: the traced lane reads them from the table, the
+            # numpy lane keeps its searches
+            assert flags[1] == -(want * P.PATH_UNIQUE
+                                 + (xp is jnp) * P.PATH_DENSE)
             assert _overflow_ratio(flags[1:], ctx.flag_caps[1:]) == 0.0
             if want:
                 assert flags[0] == 0 and not any(f > 0 for f in flags)
         tracing.reset()
         q.collect()
         assert _join_paths()[-1] is want
+    # over the mesh a path reads taken only where every shard took it
+    for shards, all_took in (([3, 2], 2), ([1, 2], 0), ([3, 3, 3], 3),
+                             ([3, 1, 0], 0), ([1, 3], 1)):
+        assert P.all_shards_path(-np.array(shards, np.int32),
+                                 lambda x: x.max()) == -all_took
+
+
+# -- the probe lookup by table (kernels.table_search: taken in the program
+# -- where the build's keys span fewer integers than the join's larger side
+# -- has rows) against the numpy lane's searches and a plain reference ------
+
+_DENSE_KEYS = {
+    # kind: (the build's distinct keys, probe keys the build lacks: inside
+    #        its span, below its first, above its last; reads dense)
+    "negative_min": (lambda t: ([k for k in range(-12, 12)
+                                 if k not in (-3, 4)], [-3, 4, -13, 12, 900]),
+                     True),
+    "span_t_minus_1": (lambda t: ([100, 101, 103, 110, 100 + t - 1],
+                                  [102, 99, 100 + t, 100 + t + 1]), True),
+    "span_t": (lambda t: ([100, 101, 103, 110, 100 + t],
+                          [102, 99, 100 + t - 1, 100 + t + 1]), False),
+    "int64_extremes": (lambda t: ([I64_MIN, -5, 0, 7, I64_MAX - 1],
+                                  [I64_MIN + 1, 3, I64_MAX]), False),
+    "float": (lambda t: ([0.5, 1.5, 2.5, 3.0, 4.0], [0.75, -1.0, 99.0]),
+              False),
+    "two_key": (lambda t: (list(range(-12, 12)), [-13, 12, 900]), False),
+    "string": (lambda t: (list("bcefghkmnpq"), ["d", "a", "z"]), True),
+    "boolean": (lambda t: ([False, True], []), True),
+}
+
+
+def _dense_case(kind, build, seed=30):
+    """Probe rows (lid, lk, lv) and build rows (rid, rk, rv, live): the probe
+    holds every build key, keys the build lacks on each side of and inside
+    its span, and NULLs; the build NULL keys and dead rows, one of them far
+    outside the span (a flagged row is no key)."""
+    from spark_tpu.columnar import pad_capacity
+    rng = np.random.default_rng(seed)
+    n_probe = 56
+    table = pad_capacity(n_probe)                  # the build is smaller
+    distinct, lacking = _DENSE_KEYS[kind][0](table)
+    reps = {"unique": [1] * len(distinct),
+            "one_dup": [1, 2] + [1] * (len(distinct) - 2),
+            "all_dup": [2] * len(distinct)}[build]
+    rk = [k for k, r in zip(distinct, reps) for _ in range(r)]
+    far = {"float": 1e12, "string": "zz", "boolean": True}.get(
+        kind, 10 ** 15)
+    rk += [None, None, distinct[0], distinct[-1], far]
+    live = [1] * (len(rk) - 3) + [0, 0, 0]
+    order = rng.permutation(len(rk))
+    right = [(int(i), rk[j], int(rng.integers(0, 4)), live[j])
+             for i, j in enumerate(order)]
+    lk = list(distinct) + list(lacking) + [None, None, None]
+    lk += [lk[j] for j in rng.integers(0, len(lk), n_probe - len(lk))]
+    left = [(int(i), lk[j], int(rng.integers(0, 4)))
+            for i, j in enumerate(rng.permutation(n_probe))]
+    assert pad_capacity(len(right)) <= table
+    return left, right, table
+
+
+def _dense_reference(left, right, how):
+    """The join by two loops: equal non-NULL keys of a live build row, the
+    residual ``lv <> rv`` part of the match condition."""
+    pairs = [(a, b) for a, lk, lv in left for b, rk, rv, live in right
+             if live and lk is not None and rk is not None
+             and lk == rk and lv != rv]
+    hit_l, hit_r = {a for a, _ in pairs}, {b for _, b in pairs}
+    if how == "left_semi":
+        return sorted((a,) for a, _k, _v in left if a in hit_l)
+    if how == "left_anti":
+        return sorted((a,) for a, _k, _v in left if a not in hit_l)
+    out = list(pairs)
+    if how in ("left", "full"):
+        out += [(a, None) for a, _k, _v in left if a not in hit_l]
+    if how == "full":
+        out += [(None, b) for b, _k, _v, live in right
+                if live and b not in hit_r]
+    return sorted(out, key=lambda t: tuple((v is None, v or 0) for v in t))
+
+
+def _dense_run(spark, left, right, how, two_key=False):
+    """The join's rows and its ``join.path`` spans' attributes on each lane:
+    ``{lane: (rows, [attrs])}``."""
+    from spark_tpu import tracing, types as T
+    key = next(k for _i, k, _v in left if k is not None)
+    key_t = {bool: T.boolean, float: T.float64, str: T.string}.get(
+        type(key), T.int64)
+
+    def frame(data, names):
+        return spark.createDataFrame(data, T.StructType([
+            T.StructField(n, key_t if n in ("lk", "rk") else T.int64, True)
+            for n in names]))
+    ldf = frame(left, ["lid", "lk", "lv"])
+    rdf = frame(right, ["rid", "rk", "rv", "live"])
+    if two_key:
+        ldf = ldf.withColumn("lk2", F.lit(1))
+        rdf = rdf.withColumn("rk2", F.lit(1))
+    cond = (ldf["lk"] == rdf["rk"]) & (ldf["lv"] != rdf["rv"])
+    if two_key:
+        cond = cond & (ldf["lk2"] == rdf["rk2"])
+    out = ldf.join(rdf.filter(rdf["live"] == 1), cond, how)
+    out = out.select("lid") if how in ("left_semi", "left_anti") \
+        else out.select("lid", "rid")
+    got = {}
+    try:
+        for lane in ("traced", "numpy"):
+            spark.conf.set("spark.sql.codegen.wholeStage",
+                           "true" if lane == "traced" else "false")
+            tracing.reset()
+            got[lane] = (rows(out), _join_paths(None))
+    finally:
+        spark.conf.set("spark.sql.codegen.wholeStage", "true")
+    return got
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "left_semi", "left_anti",
+                                 "full"])
+@pytest.mark.parametrize("build", ["unique", "one_dup", "all_dup"])
+@pytest.mark.parametrize("kind", sorted(_DENSE_KEYS))
+def test_dense_table_path_parity(spark, kind, build, how):
+    """The same rows whether the probe's matches are read from the table or
+    searched for: the traced lane against the numpy lane (which keeps the
+    searches) and against two plain loops, with the path each join reports.
+    The table is taken exactly where one key pair has an int64 encoding and
+    the build's matchable keys span fewer integers than the table has
+    entries: up to ``T - 1`` and not ``T``, never by wrapping, never for a
+    float's bit pattern or a hash."""
+    from spark_tpu.columnar import pad_capacity
+    left, right, table = _dense_case(kind, build)
+    got = _dense_run(spark, left, right, how, two_key=kind == "two_key")
+    want = _dense_reference(left, right, how)
+    assert got["traced"][0] == want and got["numpy"][0] == want
+    for lane, dense in (("traced", _DENSE_KEYS[kind][1]), ("numpy", False)):
+        for path in got[lane][1]:
+            assert path["probe_cap"] == pad_capacity(len(left)) == table
+            assert path["dense"] is dense
+            # (the session keeps a statement shape's output capacity: after
+            # a build that repeated its keys the output outgrows the probe)
+            assert path["unique"] is (build == "unique" and how != "full"
+                                      and path["out_cap"] == table)
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "left_semi", "left_anti",
+                                 "full"])
+@pytest.mark.parametrize("build", ["no_live_row", "all_null_keys"])
+def test_dense_table_path_needs_a_key(spark, build, how):
+    """A build with no matchable key has no span: the search path, and no
+    probe row matches."""
+    left, right, _t = _dense_case("negative_min", "unique")
+    right = [(rid, rk if build == "no_live_row" else None, rv,
+              0 if build == "no_live_row" else live)
+             for rid, rk, rv, live in right]
+    got = _dense_run(spark, left, right, how)
+    want = _dense_reference(left, right, how)
+    assert got["traced"][0] == want and got["numpy"][0] == want
+    assert {p["dense"] for p in got["traced"][1]} == {False}
+    assert len(want) == {"inner": 0, "left_semi": 0}.get(how, len(want))
+
+
+@pytest.mark.parametrize("build", ["unique", "all_dup"])
+def test_dense_table_path_takes_a_run_plane_probe(spark, build):
+    """A probe key that arrives as a run plane (runs of equal keys, unexpanded
+    at the stage boundary) is looked up in the table like a dense column."""
+    from spark_tpu import tracing, types as T
+    from spark_tpu.columnar import ColumnBatch, ColumnVector, RunColumnVector
+    from spark_tpu.sql import logical as L
+    from spark_tpu.sql.dataframe import DataFrame
+    heads = np.array([3, 9, 4, 40, -2, 9, 5, 6], np.int64)   # 40, -2: absent
+    lens = np.full(8, 16, np.int64)
+    probe = ColumnBatch(
+        ["ts", "v"],
+        [RunColumnVector(heads, lens, T.int64),
+         ColumnVector(np.arange(128, dtype=np.int64) % 5, T.int64)],
+        None, 128)
+    DataFrame(spark, L.LocalRelation(probe)).createOrReplaceTempView("dp_probe")
+    k = np.repeat(np.arange(3, 11), 2 if build == "all_dup" else 1)
+    spark.createDataFrame({"k": k.astype(np.int64),
+                           "w": np.arange(len(k), dtype=np.int64)}
+                          ).createOrReplaceTempView("dp_dim")
+    try:
+        tracing.reset()
+        got = spark.sql("SELECT count(*) AS c, sum(ts) AS st, sum(w) AS sw, "
+                        "sum(v) AS sv FROM dp_probe JOIN dp_dim ON ts = k"
+                        ).collect()
+        dense, unique = _join_paths("dense"), _join_paths()
+    finally:
+        spark.catalog.dropTempView("dp_probe")
+        spark.catalog.dropTempView("dp_dim")
+    ts, v = np.repeat(heads, lens), np.arange(128) % 5
+    pairs = [(t, x, w) for t, x in zip(ts, v)
+             for kk, w in zip(k, range(len(k))) if kk == t]
+    assert tuple(got[0]) == (len(pairs), sum(p[0] for p in pairs),
+                             sum(p[2] for p in pairs),
+                             sum(p[1] for p in pairs))
+    assert set(dense) == {True} and set(unique) == {build == "unique"}
 
 
 # -- the general path's slot-to-probe-row map (kernels.slot_owner: one

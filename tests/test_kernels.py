@@ -447,6 +447,80 @@ def test_remap_codes_jit_matches_numpy():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+# -- the join's direct-address probe lookup against the searches it stands for
+
+_I64 = np.iinfo(np.int64)
+
+
+def _table_cases():
+    rng = np.random.default_rng(30)
+    runs = np.sort(rng.integers(-40, 60, 200))       # every key about twice
+    return {
+        # name: (matchable keys, table size)
+        "runs_from_negative_min": (runs, 256),
+        "distinct": (np.arange(7, 107), 128),
+        "one_key_many_times": (np.full(50, 12), 64),
+        "one_key_once": (np.array([-9]), 8),
+        "span_is_size_minus_1": (np.array([5, 5, 9, 5 + 63]), 64),
+        "int64_max_is_a_key": (np.array([_I64.max - 3, _I64.max, _I64.max]),
+                               8),
+        "int64_min_is_a_key": (np.array([_I64.min, _I64.min + 2]), 8),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_table_cases()))
+def test_table_search_is_both_searches(case):
+    """``table_search`` gives ``searchsorted(a[:m], v, "left")`` and, with
+    its count, the ``"right"`` one, for keys in the build, between its keys,
+    below its first and above its last, the int64 extremes among them; the
+    entries past ``m`` (the join's NULL / dead rows) are never counted."""
+    from spark_tpu import kernels as K
+    keys, size = _table_cases()[case]
+    keys = np.sort(keys).astype(np.int64)
+    m = len(keys)
+    a = np.concatenate([keys, np.full(5, _I64.max)])  # the dead suffix
+    assert bool(K.keys_span_under(np, a, np.int32(m), size))
+    v = np.unique(np.concatenate([
+        keys, keys[keys < _I64.max] + 1, keys[keys > _I64.min] - 1,
+        [_I64.min, _I64.max, 0]])).astype(np.int64)
+    want_lo = np.searchsorted(keys, v, side="left")
+    want_hi = np.searchsorted(keys, v, side="right")
+    for xp, fn in ((np, K.table_search), (jnp, K.table_search),
+                   (jnp, jax.jit(K.table_search, static_argnums=(0, 4)))):
+        lo, n_eq = fn(xp, xp.asarray(a), xp.asarray(np.int32(m)),
+                      xp.asarray(v), size)
+        np.testing.assert_array_equal(np.asarray(lo), want_lo)
+        np.testing.assert_array_equal(np.asarray(lo) + np.asarray(n_eq),
+                                      want_hi)
+        if xp is jnp:
+            assert lo.dtype == n_eq.dtype == jnp.int32
+
+
+@pytest.mark.parametrize("lane", ["traced", "numpy"])
+def test_keys_span_under_does_not_wrap(lane):
+    """The span is read from the first and the last of the ``m`` keys, in
+    uint64: a build holding both ends of int64 is not dense, a span of
+    exactly ``size`` is not, ``size - 1`` is, no key at all is not."""
+    from spark_tpu import kernels as K
+    xp = np if lane == "numpy" else jnp
+
+    def dense(keys, m, size):
+        return bool(K.keys_span_under(
+            xp, xp.asarray(np.array(keys, np.int64)),
+            xp.asarray(np.int32(m)), size))
+
+    assert dense([3, 4, 10, _I64.max], 3, 8)
+    assert not dense([3, 4, 11, _I64.max], 3, 8)
+    assert dense([3, 4, 11, _I64.max], 2, 8)         # the dead row's key
+    assert not dense([_I64.min, _I64.max], 2, 1 << 22)
+    assert not dense([_I64.min, 0], 2, 1 << 22)
+    assert not dense([-1, _I64.max], 2, 1 << 22)
+    assert dense([_I64.max - 1, _I64.max], 2, 8)
+    assert dense([_I64.min, _I64.min + 7], 2, 8)
+    assert dense([-4, 3], 2, 8)
+    assert not dense([_I64.max, _I64.max], 0, 8)     # nothing matchable
+
+
 def test_union_all_identical_dictionaries_fast_path():
     # all senders share one dictionary: codes concatenate untouched
     words = ("a", "b")

@@ -191,6 +191,7 @@ def test_join_with_both_paths_compiles(one_chip, on_tpu, spark, rows):
         .compile().as_text()
     assert "conditional" in text
     assert "join.unique" in text and "join.expand" in text
+    assert "join.dense" in text and "join.probe" in text
 
 
 @pytest.mark.parametrize("how,factor,out_cap", [
@@ -236,6 +237,7 @@ def test_fanout_join_compiles(one_chip, on_tpu, spark, how, factor, out_cap):
         .compile().as_text()
     assert caps == [(out_cap, 1 << 17)]
     assert "join.expand" in text and "join.gather" in text
+    assert "join.dense" in text and "join.probe" in text
 
 
 @pytest.mark.parametrize("method", ["scan", "scan_unrolled"])

@@ -289,6 +289,10 @@ def test_kernel_scopes_in_lowered_text(in_memory, monkeypatch):
     # searches, under join.probe, keep theirs)
     assert "branch_1_fun/join.unique/" in text \
         and "branch_0_fun/join.expand/" in text
+    # the probe lookup is a branch too: by table under join.dense, by search
+    # under join.probe
+    assert re.search(r"branch_\d_fun/join\.dense/", text) \
+        and re.search(r"branch_\d_fun/join\.probe/", text)
     # (the lowered text names a loop inside an inner jit relative to it;
     # the compiled program's op names are whole paths)
     assert "join.probe/jit(searchsorted)" in text
@@ -345,6 +349,26 @@ def test_annotations_share_the_ring_clock(in_memory, tmp_path):
     # with no profiler attached a span opens no annotation
     in_memory.sql(AGG_CUSTOMER_TOP100).collect()
     assert not tracing.spans()[-1].profiled
+
+
+def test_cli_prints_the_join_paths(in_memory, tmp_path, capsys):
+    """``python -m spark_tpu.tracing <trace dir>`` tallies the ``join.path``
+    spans by their attributes: which path each traced join took."""
+    fact = in_memory.createDataFrame({"k": np.arange(64, dtype=np.int64) % 16})
+    dim = in_memory.createDataFrame({"dk": np.arange(16, dtype=np.int64)})
+    q = fact.join(dim, fact["k"] == dim["dk"])
+    q.collect()                                        # warm
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        q.collect()
+    finally:
+        jax.profiler.stop_trace()
+    read = tracing.device_time_by_scope(tracing._xplanes(str(tmp_path))[-1])
+    assert read["join_paths"] == [[{"unique": 1, "dense": 1, "out_cap": 64,
+                                    "probe_cap": 64}, 1]]
+    assert tracing._main([str(tmp_path)]) == 0
+    assert '1 sql:join.path  {"dense": 1, "out_cap": 64, "probe_cap": 64, ' \
+        '"unique": 1}' in capsys.readouterr().out
 
 
 def test_check_clock_cli(tmp_path):
@@ -461,17 +485,30 @@ def test_plan_cache_key_memo_survives_freed_nodes():
 
 # -- the benchmark's reading of the join paths --------------------------------
 
-@pytest.mark.parametrize("ring, want", [
+_STAR_MESH = ["sf1-star-parquet", "mesh4-q3-q17"]
+_JOIN_CELLS = ["sf1-star-parquet", "sf1-star-cached", "sf1-web-orders-http",
+               "mesh4-q3-q17"]
+
+
+@pytest.mark.parametrize("metric, cells, ring, want", [
     # three of statement 7's four joins and statement 8's one; the joins of
     # the warm-up before the slice and of the statement after it do not count
-    ("ring_join_path.json", 80.0),
+    ("join.unique_pct", _STAR_MESH, "ring_join_path.json", 80.0),
     # a program that records no join.path (any tree before PR 26) reads 0
-    ("ring_small.json", 0.0),
+    ("join.unique_pct", _STAR_MESH, "ring_small.json", 0.0),
+    # the same spans as PR 30 records them: two of statement 7's joins and
+    # statement 8's one read their matches from the table
+    ("join.dense_pct", _JOIN_CELLS, "ring_join_dense.json", 60.0),
+    ("join.unique_pct", _STAR_MESH, "ring_join_dense.json", 80.0),
+    # a program whose join.path lacks the attribute (PR 26 to PR 29) reads 0
+    ("join.dense_pct", _JOIN_CELLS, "ring_join_path.json", 0.0),
+    ("join.dense_pct", _JOIN_CELLS, "ring_small.json", 0.0),
 ])
-def test_join_unique_pct_on_the_recording(ring, want):
-    """``join.unique_pct`` as the benchmark computes it: its manifest entry,
-    its data file and the accepted ``program_spans`` reader, over a small
-    recorded ring laid on the harness's recorded trace."""
+def test_join_path_pct_on_the_recording(metric, cells, ring, want):
+    """``join.unique_pct`` / ``join.dense_pct`` as the benchmark computes
+    them: the manifest entry, the data file and the accepted
+    ``program_spans`` reader, over a small recorded ring laid on the
+    harness's recorded trace."""
     import importlib
     import json
     import os
@@ -481,12 +518,11 @@ def test_join_unique_pct_on_the_recording(ring, want):
     from benchmark.run import Context
     with open(os.path.join(root, "BENCHMARK.json")) as fh:
         entry = next(m for m in json.load(fh)["per_layer"]
-                     if m["name"] == "join.unique_pct")
+                     if m["name"] == metric)
     assert (entry["unit"], entry["better"], entry["layer"], entry["moves"]) \
         == ("%", "higher", "operators", "fact_rows_per_s")
-    assert entry["workloads"] == ["sf1-star-parquet", "mesh4-q3-q17"]
-    with open(os.path.join(bench, "layer_metrics",
-                           "join.unique_pct.json")) as fh:
+    assert entry["workloads"] == cells
+    with open(os.path.join(bench, "layer_metrics", metric + ".json")) as fh:
         spec = json.load(fh)
     with open(os.path.join(bench, "tests", "trace_small.json")) as fh:
         trace = TR.Reduced(json.load(fh))
