@@ -27,6 +27,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import tracing
 from . import types as T
 
 Array = Any  # np.ndarray | jax.Array
@@ -211,10 +212,18 @@ def merge_dictionaries(
     ``remap_x[old_code] -> new_code``. Needed when two independently encoded
     string columns meet (union, join keys, comparisons).
     """
-    merged = tuple(sorted(set(a) | set(b)))
-    lookup = {v: i for i, v in enumerate(merged)}
-    remap_a = np.fromiter((lookup[v] for v in a), dtype=np.int32, count=len(a))
-    remap_b = np.fromiter((lookup[v] for v in b), dtype=np.int32, count=len(b))
+    if a is b or a == b:
+        # one dictionary on both sides (two reads of one relation, the arms
+        # of a self-join): nothing to merge, whatever its size
+        same = np.arange(len(a), dtype=np.int32)
+        return a, same, same
+    with tracing.span("dict.unify", words=len(a) + len(b)):
+        merged = tuple(sorted(set(a) | set(b)))
+        lookup = {v: i for i, v in enumerate(merged)}
+        remap_a = np.fromiter((lookup[v] for v in a), dtype=np.int32,
+                              count=len(a))
+        remap_b = np.fromiter((lookup[v] for v in b), dtype=np.int32,
+                              count=len(b))
     return merged, remap_a, remap_b
 
 
@@ -270,11 +279,16 @@ class ColumnVector:
         if row_valid is not None:
             sel = np.asarray(row_valid)
             data, valid = data[sel], valid[sel]
+        if self.dictionary is None:
+            return self._to_pylist(data, valid)
+        global _LATE_MATERIALIZED_ROWS
+        _LATE_MATERIALIZED_ROWS += len(data)
+        with tracing.span("dict.decode", rows=len(data)):
+            return self._to_pylist(data, valid)
+
+    def _to_pylist(self, data: np.ndarray, valid: np.ndarray) -> List[Any]:
         out: List[Any] = []
         dt = self.dtype
-        if self.dictionary is not None and len(data):
-            global _LATE_MATERIALIZED_ROWS
-            _LATE_MATERIALIZED_ROWS += len(data)
         for i in range(len(data)):
             if not valid[i]:
                 out.append(None)

@@ -1473,12 +1473,14 @@ class Hash64(Expression):
     @staticmethod
     def _string_hash_table(dictionary: Tuple[str, ...]) -> np.ndarray:
         import hashlib
+        from . import tracing
         out = np.empty(max(len(dictionary), 1), np.int64)
         out[:] = 0
-        for i, w in enumerate(dictionary):
-            data = w if isinstance(w, bytes) else str(w).encode("utf-8")
-            h = hashlib.blake2b(data, digest_size=8).digest()
-            out[i] = np.frombuffer(h, np.int64)[0]
+        with tracing.span("dict.unify", words=len(dictionary)):
+            for i, w in enumerate(dictionary):
+                data = w if isinstance(w, bytes) else str(w).encode("utf-8")
+                h = hashlib.blake2b(data, digest_size=8).digest()
+                out[i] = np.frombuffer(h, np.int64)[0]
         return out
 
     def eval(self, ctx):

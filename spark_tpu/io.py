@@ -875,6 +875,14 @@ def scan_string_dictionaries(rel: L.FileRelation,
     str_cols = [f.name for f in schema.fields if f.dataType.is_string]
     if not str_cols:
         return {}
+    with tracing.span("dict.scan", columns=len(str_cols)) as sp:
+        out = _scan_string_dictionaries(rel, batch_rows, str_cols)
+        sp.attrs["words"] = sum(len(d) for d in out.values())
+    return out
+
+
+def _scan_string_dictionaries(rel: L.FileRelation, batch_rows: int,
+                              str_cols: List[str]) -> Dict[str, tuple]:
     uniques: Dict[str, set] = {c: set() for c in str_cols}
     files = [] if rel.fmt == "jdbc" else _resolve_paths(rel.paths)
     if rel.fmt == "parquet":
@@ -922,21 +930,28 @@ def reencode_strings(batch: ColumnBatch,
     if not fixed_dicts:
         return batch
     vectors = []
-    for name, v in zip(batch.names, batch.vectors):
-        target = fixed_dicts.get(name)
-        if target is None or v.dictionary is None or \
-                tuple(v.dictionary) == tuple(target):
-            vectors.append(v)
-            continue
-        tarr = np.asarray(target, dtype=object)
-        local = np.asarray(v.dictionary, dtype=object)
-        remap = np.searchsorted(tarr, local).astype(np.int32) \
-            if len(local) else np.zeros(0, np.int32)
-        codes = np.asarray(v.data).astype(np.int64)
-        new_codes = remap[np.clip(codes, 0, max(len(local) - 1, 0))] \
-            if len(local) else np.zeros_like(codes, np.int32)
-        new_codes = np.where(codes < 0, -1, new_codes).astype(np.int32)
-        vectors.append(ColumnVector(new_codes, v.dtype, v.valid, tuple(target)))
+    with tracing.span("dict.reencode", words=0) as sp:
+        for name, v in zip(batch.names, batch.vectors):
+            target = fixed_dicts.get(name)
+            if target is None or v.dictionary is None or \
+                    v.dictionary is target:
+                vectors.append(v)
+                continue
+            if v.dictionary == target:
+                # the SAME tuple on every batch: a compiled step then knows
+                # its input's dictionary by identity, not word by word
+                vectors.append(ColumnVector(v.data, v.dtype, v.valid, target))
+                continue
+            sp.attrs["words"] += len(v.dictionary)
+            tarr = np.asarray(target, dtype=object)
+            local = np.asarray(v.dictionary, dtype=object)
+            remap = np.searchsorted(tarr, local).astype(np.int32) \
+                if len(local) else np.zeros(0, np.int32)
+            codes = np.asarray(v.data).astype(np.int64)
+            new_codes = remap[np.clip(codes, 0, max(len(local) - 1, 0))] \
+                if len(local) else np.zeros_like(codes, np.int32)
+            new_codes = np.where(codes < 0, -1, new_codes).astype(np.int32)
+            vectors.append(ColumnVector(new_codes, v.dtype, v.valid, target))
     return ColumnBatch(list(batch.names), vectors, batch.row_valid,
                        batch.capacity)
 

@@ -48,6 +48,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import tracing
 from .. import types as T
 from ..columnar import (ColumnBatch, ColumnVector, PlaneColumnVector,
                         bump_run_aware, pad_capacity, unmaterialized_runs)
@@ -124,10 +125,12 @@ class _Hash64B(Hash64):
         import hashlib
         out = np.empty(max(len(dictionary), 1), np.int64)
         out[:] = 0
-        for i, w in enumerate(dictionary):
-            data = w if isinstance(w, bytes) else str(w).encode("utf-8")
-            h = hashlib.blake2b(data, digest_size=8, key=b"spark-tpu-joinB").digest()
-            out[i] = np.frombuffer(h, np.int64)[0]
+        with tracing.span("dict.unify", words=len(dictionary)):
+            for i, w in enumerate(dictionary):
+                data = w if isinstance(w, bytes) else str(w).encode("utf-8")
+                h = hashlib.blake2b(data, digest_size=8,
+                                    key=b"spark-tpu-joinB").digest()
+                out[i] = np.frombuffer(h, np.int64)[0]
         return out
 
 
@@ -265,6 +268,23 @@ def range_key_spec(node: Join, left_schema: T.StructType,
             lk == "str")
 
 
+def _canonical_ids(left: tuple, right: tuple):
+    """(left table, right table): each dictionary's codes -> ids in ONE id
+    space, the sorted union of both sides' words, so that ids compare by
+    word across sides.  (None, None) where the two sides carry one
+    dictionary (two reads of one relation, the arms of a self-join): their
+    codes already are such ids, and nothing is built or baked into the
+    program.  Host work at trace time, once a compile."""
+    if left is right or left == right:
+        return None, None
+    with tracing.span("dict.unify", words=len(left) + len(right)):
+        lw = [w if isinstance(w, str) else str(w) for w in left]
+        rw = [w if isinstance(w, str) else str(w) for w in right]
+        pos = {w: i for i, w in enumerate(sorted(set(lw) | set(rw)))}
+        return (np.array([pos[w] for w in lw] or [0], np.int64),
+                np.array([pos[w] for w in rw] or [0], np.int64))
+
+
 def _exact_encode_pair(pctx: EvalContext, bctx: EvalContext,
                        l: Expression, r: Expression):
     """Exact int64 encodings of one equi-key pair, value-comparable across
@@ -280,15 +300,14 @@ def _exact_encode_pair(pctx: EvalContext, bctx: EvalContext,
     lv = pctx.broadcast(l.eval(pctx))
     rv = bctx.broadcast(r.eval(bctx))
 
-    def enc(side_ctx, v, other_dict):
+    def enc(side_ctx, v, table):
         if v.dictionary is not None:
-            words = [w if isinstance(w, str) else str(w) for w in v.dictionary]
-            other = [w if isinstance(w, str) else str(w) for w in other_dict]
-            pos = {w: i for i, w in enumerate(sorted(set(words) | set(other)))}
-            table = np.array([pos[w] for w in words] or [0], np.int64)
             codes = xp.clip(v.data.astype(np.int64), 0,
-                            max(len(words) - 1, 0))
-            return xp.asarray(table)[codes]
+                            max(len(v.dictionary) - 1, 0))
+            if table is None:            # one dictionary: codes ARE ids
+                return codes
+            with _scope(xp, "join.keys.remap"):
+                return xp.asarray(table)[codes]
         dt = np.dtype(str(v.data.dtype))
         if np.issubdtype(dt, np.floating):
             return _orderable_f64(xp, v.data.astype(np.float64))
@@ -307,8 +326,10 @@ def _exact_encode_pair(pctx: EvalContext, bctx: EvalContext,
         from ..expressions import ExprValue
         lv = ExprValue(lv.data.astype(np.float64), lv.valid, None)
         rv = ExprValue(rv.data.astype(np.float64), rv.valid, None)
-    p_enc = enc(pctx, lv, rv.dictionary if has_dict else [])
-    b_enc = enc(bctx, rv, lv.dictionary if has_dict else [])
+    l_ids, r_ids = _canonical_ids(lv.dictionary, rv.dictionary) \
+        if has_dict else (None, None)
+    p_enc = enc(pctx, lv, l_ids)
+    b_enc = enc(bctx, rv, r_ids)
     if p_enc is None or b_enc is None:
         return None
     p_val = None if lv.valid is None \
@@ -388,6 +409,18 @@ class PJoin(P.PhysicalPlan):
         left = self.children[0].run(ctx)
         right = self.children[1].run(ctx)
         return self._run_on(ctx, left, right)
+
+    def _string_keyed(self, probe: ColumnBatch, build: ColumnBatch) -> bool:
+        """Whether a key pair is dictionary-coded (``join.path``'s
+        ``string``)."""
+        for l, r in self.key_pairs:
+            try:
+                if l.data_type(probe.schema).is_string \
+                        or r.data_type(build.schema).is_string:
+                    return True
+            except Exception:
+                pass
+        return False
 
     # ------------------------------------------------------------------
     def _run_on(self, ctx: P.ExecContext, probe: ColumnBatch,
@@ -705,7 +738,8 @@ class PJoin(P.PhysicalPlan):
 
         if hasattr(ctx, "add_flag"):
             ctx.add_flag(xp.maximum(total - out_cap, 0), "join", out_cap)
-            ctx.add_join_path(build_unique, dense, out_cap, probe.capacity)
+            ctx.add_join_path(build_unique, dense, out_cap, probe.capacity,
+                              self._string_keyed(probe, build))
 
         if how in ("left_semi", "left_anti"):
             return ColumnBatch(probe.names, probe.vectors,

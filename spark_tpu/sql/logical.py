@@ -20,7 +20,8 @@ from ..expressions import AnalysisException, Expression
 __all__ = [
     "LogicalPlan", "LocalRelation", "RangeRelation", "Project", "Filter",
     "Aggregate", "Sort", "SortOrder", "Limit", "Join", "Union", "Distinct",
-    "SubqueryAlias", "UnresolvedRelation", "FileRelation", "Sample",
+    "SubqueryAlias", "cte_copies", "UnresolvedRelation", "FileRelation",
+    "Sample",
 ]
 
 
@@ -132,6 +133,9 @@ def plan_cache_key(node: "LogicalPlan", _memo: Optional[dict] = None) -> str:
 
 class LocalRelation(LogicalPlan):
     """In-memory data (``LocalRelation.scala``); leaf."""
+
+    #: the optimizer's relation of no row (``optimizer.empty_relation``)
+    empty = False
 
     def __init__(self, batch: ColumnBatch):
         self.batch = batch
@@ -551,6 +555,10 @@ class Sample(LogicalPlan):
 class SubqueryAlias(LogicalPlan):
     """Names a subtree so SQL can reference ``alias.column``."""
 
+    #: (name, copy number) where the parser put this alias in for a CTE's
+    #: name: one more copy of the CTE's body (``cte_copies``)
+    cte: Optional[Tuple[str, int]] = None
+
     def __init__(self, alias: str, child: LogicalPlan):
         self.alias = alias
         self.children = (child,)
@@ -564,6 +572,32 @@ class SubqueryAlias(LogicalPlan):
 
     def __repr__(self):
         return f"SubqueryAlias {self.alias}"
+
+
+def cte_copies(plan: LogicalPlan) -> List[Tuple[str, int]]:
+    """(name, copy number) of every copy of a CTE's body in ``plan``, as the
+    parser marked them where it substituted the name (an analyzed plan still
+    has the marks; the optimizer drops the aliases that carry them).  Each
+    copy is planned and executed by itself: the ``cte.body`` records."""
+    from .subquery import SubqueryExpr
+    out: List[Tuple[str, int]] = []
+
+    def of_expr(e: Expression) -> None:
+        if isinstance(e, SubqueryExpr):
+            walk(e.plan)
+        for c in e.children:
+            of_expr(c)
+
+    def walk(node: LogicalPlan) -> None:
+        if isinstance(node, SubqueryAlias) and node.cte is not None:
+            out.append(node.cte)
+        for e in node.expressions():
+            of_expr(e)
+        for c in node.children:
+            walk(c)
+
+    walk(plan)
+    return out
 
 
 class Explode(LogicalPlan):

@@ -691,11 +691,80 @@ def join_conjuncts(es: List[Expression]) -> Expression:
     return out
 
 
+#: one empty relation a schema, so that a statement optimized again holds
+#: the SAME leaf (a plan fingerprint keys an in-memory leaf by identity)
+_EMPTY_RELATIONS: Dict[str, LocalRelation] = {}
+
+
+def empty_relation(schema) -> LocalRelation:
+    """The relation of ``schema`` with no row, marked ``empty``."""
+    key = repr([(f.name, str(f.dataType)) for f in schema.fields])
+    rel = _EMPTY_RELATIONS.get(key)
+    if rel is None:
+        if len(_EMPTY_RELATIONS) >= 256:
+            _EMPTY_RELATIONS.clear()
+        rel = LocalRelation(ColumnBatch.empty(schema))
+        rel.empty = True
+        _EMPTY_RELATIONS[key] = rel
+    return rel
+
+
+def is_empty_relation(node: LogicalPlan) -> bool:
+    return isinstance(node, LocalRelation) and node.empty
+
+
 def prune_filters(node: LogicalPlan) -> LogicalPlan:
-    """Remove Filter(true); keep Filter(false) (planner emits empty)."""
-    if isinstance(node, Filter) and isinstance(node.condition, Literal):
-        if node.condition.value is True:
-            return node.child
+    """``PruneFilters``: a Filter's constant conjuncts are folded where the
+    pushdown rules left them (a CTE read with ``sale_type = 's'`` puts
+    ``'w' = 's'`` above the other arm of its ``UNION ALL``, with no column
+    for any rule to push it by).  TRUE conjuncts go; one FALSE or NULL
+    conjunct selects nothing, and the Filter with everything under it
+    becomes the empty relation of its schema, which is never computed."""
+    if not isinstance(node, Filter):
+        return node
+    conjuncts = split_conjuncts(node.condition)
+    folded = [c if isinstance(c, Literal) or c.references()
+              else constant_fold_expr(c) for c in conjuncts]
+    if any(isinstance(c, Literal) and (c.value is False or c.value is None)
+           for c in folded):
+        return empty_relation(node.schema())
+    keep = [c for c in folded
+            if not (isinstance(c, Literal) and c.value is True)]
+    if not keep:
+        return node.child
+    if len(keep) == len(conjuncts) \
+            and all(a is b for a, b in zip(keep, conjuncts)):
+        return node
+    return Filter(join_conjuncts(keep), node.child)
+
+
+def propagate_empty_relation(node: LogicalPlan) -> LogicalPlan:
+    """``PropagateEmptyRelation``, the cases a pruned Filter leaves behind:
+    an operator that yields no row from no row is itself the empty
+    relation; a UNION ALL drops its empty arms (the first arm names the
+    output, so the arms left keep its names and types)."""
+    if isinstance(node, Union):
+        live = [c for c in node.children if not is_empty_relation(c)]
+        if len(live) == len(node.children):
+            return node
+        schema = node.schema()
+        if not live:
+            return empty_relation(schema)
+        from ..expressions import Cast
+        first = live[0]
+        have = first.schema()
+        if [(f.name, str(f.dataType)) for f in have.fields] != \
+                [(f.name, str(f.dataType)) for f in schema.fields]:
+            first = Project([
+                Alias(Col(h.name) if str(h.dataType) == str(f.dataType)
+                      else Cast(Col(h.name), f.dataType), f.name)
+                for h, f in zip(have.fields, schema.fields)], first)
+        return first if len(live) == 1 else Union([first] + live[1:])
+    if isinstance(node, (Project, Filter, Sort, Limit, Distinct)) \
+            or (isinstance(node, Aggregate) and node.keys) \
+            or (isinstance(node, Join) and node.how in ("inner", "cross")):
+        if any(is_empty_relation(c) for c in node.children):
+            return empty_relation(node.schema())
     return node
 
 
@@ -981,6 +1050,7 @@ class Optimizer:
                 reorder_joins,
                 push_filter_into_join,
                 prune_filters,
+                propagate_empty_relation,
                 push_project_through_limit,
                 push_project_through_sort,
                 prune_project_under_aggregate,

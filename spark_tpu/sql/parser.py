@@ -26,6 +26,7 @@ time is never on the hot path.
 
 from __future__ import annotations
 
+import itertools
 import re
 from typing import Any, List, Optional, Sequence, Tuple
 
@@ -873,7 +874,7 @@ class Parser:
 
     # -- queries ----------------------------------------------------------
     def parse_query(self) -> LogicalPlan:
-        ctes = {}
+        ctes, copies = {}, {}
         from .subquery import SubqueryExpr
 
         def subst_plan(p: LogicalPlan) -> LogicalPlan:
@@ -881,7 +882,13 @@ class Parser:
 
         def subst(node: LogicalPlan) -> LogicalPlan:
             if isinstance(node, UnresolvedRelation) and node.name.lower() in ctes:
-                return ctes[node.name.lower()]
+                # a CTE is substituted where it is named: every reference
+                # is one more COPY of its body, marked so that execution
+                # can say how many it runs (``logical.cte_copies``)
+                body = ctes[node.name.lower()]
+                ref = SubqueryAlias(body.alias, body.child)
+                ref.cte = (body.alias, next(copies[body.alias]))
+                return ref
             return node
 
         def subst_exprs(node: LogicalPlan) -> LogicalPlan:
@@ -907,6 +914,7 @@ class Parser:
                 # scope for later bodies, so substitute them NOW — the
                 # registered plan is fully self-contained
                 ctes[name.lower()] = SubqueryAlias(name, subst_plan(sub))
+                copies[name] = itertools.count()
                 if not self.accept_op(","):
                     break
         plan = self._set_op_query()

@@ -43,8 +43,9 @@ PATH_UNIQUE, PATH_DENSE = 1, 2
 def record_join_paths(int_flags, kinds, caps=()) -> None:
     """One ``join.path`` span for each join of the step whose flags were
     just fetched: ``unique`` and ``dense`` (the paths it took: the rows and
-    the probe lookup) and, from the trace's static capacities where the lane
-    kept them, ``out_cap`` and ``probe_cap`` (the join's fan-out).  ``python
+    the probe lookup) and, from the trace's static facts where the lane
+    kept them, ``out_cap`` and ``probe_cap`` (the join's fan-out) and
+    ``string`` (a key pair is dictionary-coded).  ``python
     -m spark_tpu.tracing`` and the benchmark's ``join.unique_pct`` /
     ``join.dense_pct`` read them."""
     for n, (f, k) in enumerate(zip(int_flags, kinds)):
@@ -52,7 +53,8 @@ def record_join_paths(int_flags, kinds, caps=()) -> None:
             attrs = {"unique": bool(-f & PATH_UNIQUE),
                      "dense": bool(-f & PATH_DENSE)}
             if n < len(caps):
-                attrs["out_cap"], attrs["probe_cap"] = caps[n]
+                attrs["out_cap"], attrs["probe_cap"], attrs["string"] = \
+                    caps[n]
             with tracing.span(JOIN_PATH, **attrs):
                 pass
 
@@ -84,20 +86,20 @@ class ExecContext:
         self.flag_caps.append(cap)
 
     def add_join_path(self, unique, dense, out_cap: int,
-                      probe_cap: int) -> None:
+                      probe_cap: int, string: bool = False) -> None:
         """Which paths a join ran (``joins.PJoin``: the unique-build rows or
         the general ones; the probe lookup by table or by search), beside
         the overflow flags so that it comes back in their fetch: kind
         ``JOIN_PATH``, minus the sum of ``PATH_UNIQUE`` and ``PATH_DENSE``
         where taken.  Never positive, so no overflow test (each reads ``f >
         0``) sees it; over shards each bit is reduced apart
-        (``all_shards_path``).  Its static "capacity" is the pair (output
-        slots, probe capacity), which ``record_join_paths`` puts on the
-        span."""
+        (``all_shards_path``).  Its static "capacity" is the triple (output
+        slots, probe capacity, whether a key pair is dictionary-coded),
+        which ``record_join_paths`` puts on the span."""
         xp = self.xp
         bits = xp.asarray(unique).astype(np.int32) * PATH_UNIQUE \
             + xp.asarray(dense).astype(np.int32) * PATH_DENSE
-        self.add_flag(-bits, JOIN_PATH, (out_cap, probe_cap))
+        self.add_flag(-bits, JOIN_PATH, (out_cap, probe_cap, bool(string)))
 
     def add_metric(self, op_id: int, label: str, value: Array) -> None:
         self.metrics.append((op_id, label, value))

@@ -27,6 +27,7 @@ from ..kernels import compact
 from .logical import (
     Aggregate, Distinct, FileRelation, Filter, Join, Limit, LocalRelation,
     LogicalPlan, Project, RangeRelation, Sample, Sort, SubqueryAlias, Union,
+    cte_copies,
 )
 from . import physical as P
 
@@ -548,6 +549,11 @@ class QueryExecution:
         prev_active = getattr(cls._tls, "active", None)
         cls._set_thread_active(self.session)
         try:
+            # every copy of a CTE's body in the statement is planned and
+            # run by itself: one zero-length record each
+            for name, copy in cte_copies(self.analyzed):
+                with tracing.span("cte.body", name=name, copy=copy):
+                    pass
             result = self._execute_inner()
         except BaseException as e:
             self.session._post_event({

@@ -67,7 +67,8 @@ SPAN_PREFIX = "sql:"
 #: ``<PhysicalPlan class>#<op_id>``; ``argsort.pass<i>`` is one chained pass)
 KERNEL_SCOPES = frozenset({
     "stage.step", "stage.merge",
-    "join.keys", "join.build_sort", "join.probe", "join.dense",
+    "join.keys", "join.keys.remap", "join.build_sort", "join.probe",
+    "join.dense",
     "join.expand", "join.gather", "join.unique",
     "agg.onehot", "agg.mxu", "agg.mxu.limbs", "agg.sort", "agg.sort.argsort",
     "agg.sort.permute", "agg.sort.segment", "pallas_agg",
@@ -154,7 +155,7 @@ class span:
 
     __slots__ = ("name", "attrs", "_ann", "_t0", "_children_ns")
 
-    def __init__(self, name: str, **attrs):
+    def __init__(self, name: str, /, **attrs):    # ``name=`` is an attr
         self.name = name
         self.attrs = attrs
 
@@ -449,8 +450,8 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
     ``{"device", "busy_s", "unnamed_s", "unnamed_pct", "by_scope": [[scope,
     seconds], ...], "top_ops": [[instruction, scope, seconds], ...] (``top``),
     "unnamed_top": [[instruction, seconds], ...], "host_spans": {name:
-    [count, seconds]}, "join_paths": [[{unique, dense, out_cap, probe_cap},
-    count], ...] (the ``join.path`` spans by their attributes),
+    [count, seconds]}, "join_paths": [[{unique, dense, out_cap, probe_cap,
+    string}, count], ...] (the ``join.path`` spans by their attributes),
     "profile_start_ns", "annotations": [[name, start_ns (epoch), dur_ns],
     ...]}``
 

@@ -664,7 +664,8 @@ def test_fanout_self_join_lanes_agree(spark, how):
         caps = [c for k, c in zip(ctx.flag_kinds, ctx.flag_caps)
                 if k == P.JOIN_PATH]
         flags = [int(f) for f in ctx.flags]
-        assert caps == [(1 << 14, 1 << 10)] and not any(f > 0 for f in flags)
+        assert caps == [(1 << 14, 1 << 10, False)] \
+            and not any(f > 0 for f in flags)
         return sorted(out.to_host().to_pylist())
 
     got = run(jnp, [b.to_device() for b in pq.leaves])

@@ -235,7 +235,7 @@ def test_fanout_join_compiles(one_chip, on_tpu, spark, how, factor, out_cap):
     text = jax.jit(step).lower(
         _spec(tuple(b.to_device() for b in pq.leaves), one_chip)) \
         .compile().as_text()
-    assert caps == [(out_cap, 1 << 17)]
+    assert caps == [(out_cap, 1 << 17, False)]
     assert "join.expand" in text and "join.gather" in text
     assert "join.dense" in text and "join.probe" in text
 

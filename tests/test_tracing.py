@@ -272,6 +272,19 @@ def test_kernel_scopes_in_lowered_text(in_memory, monkeypatch):
     texts.append(SC.stage_cache().peek(key).fn.lower(
         tuple(b.to_device() for b in leaves), SC.param_values(slots))
         .as_text(debug_info=True))
+    # a join of two string columns with dictionaries of their own gathers
+    # each side's codes through the id table the trace built for it
+    from spark_tpu.sql.joins import _exact_encode_pair
+    from spark_tpu.expressions import EvalContext
+
+    def words(*w):
+        return ColumnBatch(["w"], [ColumnVector(
+            jnp.arange(n, dtype=jnp.int32) % len(w), T.string, None, w)],
+            None, n)
+    texts.append(jax.jit(lambda a, b: _exact_encode_pair(
+        EvalContext(a, jnp), EvalContext(b, jnp), Col("w"), Col("w"))[0])
+        .lower(words("a", "c"), words("b", "c", "d"))
+        .as_text(debug_info=True))
     text = "\n".join(texts)
     missing = sorted(s for s in tracing.KERNEL_SCOPES if s not in text)
     assert not missing, missing
@@ -365,10 +378,10 @@ def test_cli_prints_the_join_paths(in_memory, tmp_path, capsys):
         jax.profiler.stop_trace()
     read = tracing.device_time_by_scope(tracing._xplanes(str(tmp_path))[-1])
     assert read["join_paths"] == [[{"unique": 1, "dense": 1, "out_cap": 64,
-                                    "probe_cap": 64}, 1]]
+                                    "probe_cap": 64, "string": 0}, 1]]
     assert tracing._main([str(tmp_path)]) == 0
     assert '1 sql:join.path  {"dense": 1, "out_cap": 64, "probe_cap": 64, ' \
-        '"unique": 1}' in capsys.readouterr().out
+        '"string": 0, "unique": 1}' in capsys.readouterr().out
 
 
 def test_check_clock_cli(tmp_path):
