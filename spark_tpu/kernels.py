@@ -5,9 +5,11 @@ hash aggregation (``unsafe/map/BytesToBytesMap.java:66``), radix sort
 (``collection/unsafe/sort/RadixSort.java``), and the iterator-chain operators
 — with XLA-friendly primitives:
 
-* group-by is SORT-BASED: multi-key ``lax.sort`` → segment boundaries →
-  ``segment_sum/min/max``.  Scatter-heavy hash maps fit TPUs poorly; sorting
-  rides the hardware sort and keeps shapes static (Spark itself falls back to
+* group-by is SORT-BASED: multi-key ``lax.sort`` → runs of equal keys → a
+  segmented scan within the runs, read at each run's end (``sorted_runs``,
+  ``reduce_runs``).  Scatter-heavy hash maps fit TPUs poorly, and so does a
+  scatter-reduce over rows that are already sorted; sorting rides the
+  hardware sort and keeps shapes static (Spark itself falls back to
   sort-based aggregation when its hash map fills —
   ``TungstenAggregationIterator.scala``).
 * filter never compacts — it ANDs the row mask; ``compact`` is explicit.
@@ -21,7 +23,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -656,6 +658,18 @@ def _global_reduce(xp, data: Array, kind: str, capacity: int) -> Array:
 
 def segment_reduce(xp, data: Array, seg_ids: Array, num_segments: int,
                    kind: str) -> Array:
+    """``out[s] = reduce(data[seg_ids == s])``, the kind's identity where no
+    row has ``s``; ``seg_ids`` in any order.  numpy lane: ``np.add.at`` and
+    its kin, the form every keyed aggregate's numpy lane reduces by (the
+    tests' independent form of ``reduce_runs``).  jax lane: a scatter-reduce
+    that does not know whether its ids are sorted (68 ns an element with a
+    64-bit combiner on a v5e, 55 with int32 ids and ``indices_are_sorted``;
+    PERF.md, PR 32).  Since PR 32 the keyed sum / min / max path is off it
+    (``reduce_runs``); left on it, jax lane, are the callers whose ids are
+    NOT runs of sorted rows or that no cell reaches: ``_plane_global_
+    aggregate`` (live rows by run-plane slot), ``_percentile_groups`` and
+    ``_collect_into_arrays`` (their own value-sorted segments, int64 ids)
+    and ``sql/window.py``'s frame reduction (ids by partition, unsorted)."""
     np_dt = np.asarray(data).dtype if _is_np(xp) else np.dtype(str(data.dtype))
     ident = IDENTITY[kind](np_dt)
     if _is_np(xp):
@@ -667,6 +681,296 @@ def segment_reduce(xp, data: Array, seg_ids: Array, num_segments: int,
     if kind == "min":
         return jax.ops.segment_min(data, seg_ids, num_segments=num_segments)
     return jax.ops.segment_max(data, seg_ids, num_segments=num_segments)
+
+
+# ---------------------------------------------------------------------------
+# runs of sorted rows: what the sort aggregate does after its argsort
+# ---------------------------------------------------------------------------
+#
+# Rows sorted by group are reduced where they lie.  A scatter-reduce
+# (``segment_reduce``) does not know its ids are sorted and pays a serial
+# 64-bit combine an element on a TPU (68 ns; PERF.md, PR 28); on sorted rows
+# the same reduction is a segmented scan, and a group's result is the scan
+# at its run's last row.  Everything here is int32 (a capacity is static and
+# under 2^31) and moves columns through an index as ONE plane
+# (``gather_columns``): a gather's cost on the chip is its index, not its
+# width (a plane of 5, 8 or 13 words through 2^22 indices takes 105-115 ms
+# on a v5e, one int32 column 36-47; PERF.md, PR 32).
+
+_RSUM_ROW = 1024
+
+
+def running_sum_i32(xp, x: Array) -> Array:
+    """Inclusive int32 running sum of ``x`` (jax lane).  Past ``_RSUM_ROW``
+    elements in two levels (running sums along rows of ``_RSUM_ROW``, the
+    running sum of the row totals added back): XLA:TPU's own ``cumsum`` of
+    2^22 elements is one reduce-window of 4-19 s of compile and over a
+    megabyte of code, this form 0.2-0.5 s, at the same speed (PERF.md
+    section 7), and the compiler takes it inside a conditional's branch."""
+    n = int(x.shape[0])
+    x = x.astype(np.int32)
+    if n <= _RSUM_ROW or n % _RSUM_ROW:
+        return xp.cumsum(x, dtype=np.int32)
+    within = xp.cumsum(x.reshape(n // _RSUM_ROW, _RSUM_ROW), axis=1,
+                       dtype=np.int32)
+    totals = within[:, -1]
+    before = xp.cumsum(totals, dtype=np.int32) - totals
+    return (within + before[:, None]).reshape(n)
+
+
+def gather_columns(xp, cols: Sequence[Array], idx: Array) -> List[Array]:
+    """``[c[idx] for c in cols]`` for 1-D columns of one length, each back
+    in its own dtype, bit for bit.  jax lane: every column but a float64
+    one rides ONE ``[k, n]`` plane of uint32 words through ONE gather
+    (8-byte integers as two words by ``bitcast_convert_type``, 4-byte ones
+    as one, 2-byte ones sign-extended, bools / int8 packed four to a word);
+    float64 columns ride a second, float64 plane, because XLA:TPU has no
+    bitcast out of its emulated float64 (and a cast would not keep the
+    bits).  ``idx`` must be in bounds."""
+    if _is_np(xp):
+        idx = np.asarray(idx)
+        return [np.asarray(c)[idx] for c in cols]
+    import jax
+    bitcast = jax.lax.bitcast_convert_type
+    words: List[Array] = []       # rows of the uint32 plane
+    floats: List[Array] = []      # rows of the float64 plane
+    small: List[Array] = []       # 1-byte columns, packed after the loop
+    back = []                     # (plane, fplane, bytes' first row) -> column
+    for c in cols:
+        at, dt = len(words), c.dtype
+        if dt == np.float64:
+            back.append(lambda w, f, b, at=len(floats): f[at])
+            floats.append(c)
+        elif dt.itemsize == 8:
+            pair = bitcast(c, np.uint32)
+            words += [pair[..., 0], pair[..., 1]]
+            back.append(lambda w, f, b, at=at, dt=dt: bitcast(
+                xp.stack([w[at], w[at + 1]], axis=-1), dt))
+        elif dt.itemsize == 4:
+            words.append(bitcast(c, np.uint32))
+            back.append(lambda w, f, b, at=at, dt=dt: bitcast(w[at], dt))
+        elif dt.itemsize == 2:
+            words.append(bitcast(c.astype(np.int32), np.uint32))
+            back.append(lambda w, f, b, at=at, dt=dt: bitcast(
+                w[at], np.int32).astype(dt))
+        else:
+            back.append(lambda w, f, b, at=len(small), dt=dt: (
+                (w[b + at // 4] >> np.uint32(8 * (at % 4))) & np.uint32(0xFF)
+            ).astype(np.uint8).astype(dt))
+            small.append(c.astype(np.uint8).astype(np.uint32))
+    first_small = len(words)
+    for i in range(0, len(small), 4):
+        word = small[i]
+        for j, byte in enumerate(small[i + 1:i + 4], 1):
+            word = word | (byte << np.uint32(8 * j))
+        words.append(word)
+    plane = xp.stack(words)[:, idx] if words else None
+    fplane = xp.stack(floats)[:, idx] if floats else None
+    return [get(plane, fplane, first_small) for get in back]
+
+
+class SortedRuns(NamedTuple):
+    """The rows of a keyed aggregate once sorted by (liveness, keys): runs
+    of equal keys, live rows first.  Group ``g`` is the ``g``-th run."""
+    perm: Array          # sorted position -> row
+    seg_ids: Array       # sorted position -> group; a dead row: capacity-1
+    is_start: Array      # sorted position opens a live run
+    live_s: Array        # sorted position holds a live row
+    num_groups: Array
+    # jax lane only (the numpy lane scatters and has no use for them):
+    start_of: Optional[Array] = None   # group -> sorted position of its first
+    end_of: Optional[Array] = None     # and of its last row (in bounds)
+    longest: Optional[Array] = None    # rows of the longest live run
+
+
+def group_sort_columns(xp, key_vals: Sequence[ExprValue], live: Array
+                       ) -> List[Array]:
+    """The sort columns of a keyed aggregate: liveness first (dead rows
+    last), then each key as (null rank, value); NULL is a group of its own
+    and ranks before every value.  A key with no validity has no null
+    rank: a constant column changes no stable order, and costs a TPU a sort
+    pass and a gather."""
+    sort_cols: List[Array] = [(~live).astype(np.int8)]
+    for v in key_vals:
+        data = v.data
+        if (np.asarray(data).dtype if _is_np(xp) else data.dtype) == np.bool_:
+            data = data.astype(np.int8)
+        if v.valid is None:
+            sort_cols.append(data)
+        else:
+            sort_cols += [xp.where(v.valid, np.int8(0), np.int8(-1)),
+                          xp.where(v.valid, data, xp.zeros((), data.dtype))]
+    return sort_cols
+
+
+def _run_starts(xp, sorted_cols: Sequence[Array], live_s: Array,
+                capacity: int) -> Array:
+    """Sorted positions that open a live run: some sort column differs from
+    the row before (position 0 always does)."""
+    change = xp.zeros(capacity, bool)
+    for c in sorted_cols:
+        change = change | (c != xp.concatenate([c[:1], c[:-1]]))
+    if _is_np(xp):
+        change[:1] = True          # a capacity-0 host batch has no row 0
+    else:
+        change = change.at[0].set(True)
+    return change & live_s
+
+
+def sorted_runs(xp, sort_cols: Sequence[Array], live: Array, capacity: int,
+                carry: Sequence[Array] = ()
+                ) -> Tuple[SortedRuns, List[Array]]:
+    """Sort rows by ``sort_cols`` (``group_sort_columns``: liveness first)
+    and lay out their runs; ``carry`` (the aggregate's buffers) comes back in
+    sorted order.  THE grouping prologue of every keyed sort aggregate
+    (``_sorted_grouped_aggregate``, ``parallel/dist.py``'s partial, merge and
+    final stages), so that their grouping cannot drift apart.
+
+    jax lane, all int32: the sort columns and the carry go through ``perm``
+    ONCE, as one plane; the liveness column does not go at all (dead rows
+    sort last, so ``live_s`` is ``arange < n_live``); ``seg_ids`` is one
+    running sum; ``start_of`` ONE scatter of the start positions (distinct
+    slots, the rest dropped) and ``end_of`` its shift.  numpy lane:
+    ``lexsort`` and an int64 ``cumsum``, the tests' independent form."""
+    with _scope(xp, "agg.sort.argsort"):
+        perm = multi_key_argsort(xp, sort_cols, capacity)
+    if _is_np(xp):
+        live_s = np.asarray(live)[perm]
+        is_start = _run_starts(xp, [np.asarray(c)[perm] for c in sort_cols],
+                               live_s, capacity)
+        seg_ids = np.cumsum(is_start.astype(np.int64)) - 1
+        seg_ids = np.where(live_s, seg_ids, np.int64(capacity - 1))
+        runs = SortedRuns(perm, seg_ids, is_start, live_s,
+                          np.sum(is_start.astype(np.int64)))
+        return runs, gather_columns(xp, carry, perm)
+    keys = list(sort_cols[1:])
+    with _scope(xp, "agg.sort.permute"):
+        moved = gather_columns(xp, keys + list(carry), perm)
+    with _scope(xp, "agg.sort.segment"):
+        pos = xp.arange(capacity, dtype=np.int32)
+        n_live = xp.sum(live, dtype=np.int32)
+        live_s = pos < n_live
+        is_start = _run_starts(xp, moved[:len(keys)], live_s, capacity)
+        seg_ids = xp.where(live_s, running_sum_i32(xp, is_start) - 1,
+                           np.int32(capacity - 1))
+        num_groups = xp.sum(is_start, dtype=np.int32)
+        # one slot a start, every other row's write dropped: 25 ms at 2^22
+        # on a v5e.  No hint: ``unique_indices`` buys nothing (25.0 ms) and
+        # ``indices_are_sorted``, which the dropped rows make untrue, is 4
+        # ms faster and WRONG (PERF.md, PR 32)
+        start_of = xp.full(capacity, capacity, np.int32).at[
+            xp.where(is_start, seg_ids, np.int32(capacity))].set(
+                pos, mode="drop")
+        next_start = xp.concatenate(
+            [start_of[1:], xp.full(1, capacity, np.int32)])
+        end_of = xp.where(pos == num_groups - 1, n_live, next_start) - 1
+        start_of = xp.minimum(start_of, np.int32(capacity - 1))
+        longest = xp.max(xp.where(pos < num_groups, end_of - start_of + 1,
+                                  np.int32(0)))
+    runs = SortedRuns(perm, seg_ids, is_start, live_s, num_groups,
+                      start_of, end_of, longest)
+    return runs, moved[len(keys):]
+
+
+def segmented_scan(xp, seg_ids: Array, bufs: Sequence[Array],
+                   kinds: Sequence[str], longest: Array
+                   ) -> Tuple[List[Array], Array]:
+    """Inclusive scan of every buffer within runs of equal ``seg_ids``
+    (sorted: equal ids ARE one run, so no flag is carried), all buffers in
+    one loop: rounds of stride ``d = 1, 2, 4, ...``, ``v[i] = op(v[i-d],
+    v[i])`` where ``seg_ids[i-d] == seg_ids[i]``, while ``d < longest``.
+    The loop reads how long it must run from its input: ``ceil(log2(
+    longest))`` rounds, which it returns beside the scans.  After it the
+    last row of a run of at most ``longest`` rows holds the run's reduction:
+    integer sums exact mod 2^64, ``min`` / ``max`` exact, a float sum added
+    as a balanced tree (its error bound is below the serial sum's, and does
+    not grow with the rows before the run, as a differenced running sum's
+    would)."""
+    import jax
+    n = int(seg_ids.shape[0])
+    ops = {"sum": xp.add, "min": xp.minimum, "max": xp.maximum}
+    # position i - d of the ids, with "no run" before position 0
+    padded_ids = xp.concatenate([xp.full(n, -1, seg_ids.dtype), seg_ids])
+
+    def more(state):
+        return state[0] < longest
+
+    def round_(state):
+        d, rounds, vals = state
+        same = jax.lax.dynamic_slice(padded_ids, (n - d,), (n,)) == seg_ids
+        # position i - d of a buffer: the buffer rolled by d (what wraps
+        # around lies under ``same`` false).  NOT a padded loop carry
+        # updated in place by ``dynamic_update_slice``: XLA:TPU reads that
+        # one AFTER it has written it (wrong sums from position 2^17 on at
+        # 14 rounds and more with a float64 buffer beside an int64 one, on
+        # a v5e; PERF.md, PR 32; ``tools/prof_aggscan.py`` keeps the form)
+        return d * 2, rounds + 1, tuple(
+            xp.where(same, ops[kind](jax.lax.dynamic_slice(
+                xp.concatenate([v, v]), (n - d,), (n,)), v), v)
+            for v, kind in zip(vals, kinds))
+
+    _, rounds, vals = jax.lax.while_loop(
+        more, round_, (np.int32(1), np.int32(0), tuple(bufs)))
+    return list(vals), rounds
+
+
+def reduce_runs(xp, runs: SortedRuns, sorted_bufs: Sequence[Array],
+                kinds: Sequence[str], capacity: int
+                ) -> Tuple[List[Array], Optional[Array]]:
+    """Each buffer (in SORTED order, dead rows holding its kind's identity)
+    reduced by group: slot ``g`` holds group ``g``'s reduction, every slot
+    past the groups the identity.  jax lane: ``segmented_scan`` and ONE
+    gather of the scans, stacked, at each run's last row; also returns the
+    scan's rounds.  numpy lane: ``np.add.at`` and its kin, rounds None."""
+    if _is_np(xp):
+        return [segment_reduce(xp, b, runs.seg_ids, capacity, k)
+                for b, k in zip(sorted_bufs, kinds)], None
+    if not sorted_bufs:                       # DISTINCT: keys alone
+        return [], xp.zeros((), np.int32)
+    as_bool = [b.dtype == np.bool_ for b in sorted_bufs]
+    bufs = [b.astype(np.int8) if flag else b
+            for b, flag in zip(sorted_bufs, as_bool)]
+    scans, rounds = segmented_scan(xp, runs.seg_ids, bufs, kinds,
+                                   runs.longest)
+    at_end = gather_columns(xp, scans, runs.end_of)
+    is_group = xp.arange(capacity, dtype=np.int32) < runs.num_groups
+    out = []
+    for got, kind, flag in zip(at_end, kinds, as_bool):
+        ident = IDENTITY[kind](np.dtype(bool) if flag
+                               else np.dtype(str(got.dtype)))
+        red = xp.where(is_group, got, xp.asarray(ident, got.dtype))
+        out.append(red.astype(bool) if flag else red)
+    return out, rounds
+
+
+def run_keys(xp, runs: SortedRuns, key_vals: Sequence[ExprValue],
+             capacity: int) -> List[Tuple[Array, Optional[Array]]]:
+    """(data, validity) of every key at each group's slot: the value of the
+    group's first row, zero (False) past the groups.  jax lane: keys are
+    READ, not moved: ``perm[start_of]`` names each group's first row, and
+    every key's data and validity come from the UNSORTED columns there, as
+    one plane.  numpy lane: ``_scatter_starts`` over the sorted columns."""
+    if not key_vals:
+        return []
+    if _is_np(xp):
+        out = []
+        for v in key_vals:
+            out.append((
+                _scatter_starts(xp, np.asarray(v.data)[runs.perm],
+                                runs.seg_ids, runs.is_start, capacity),
+                None if v.valid is None else _scatter_starts(
+                    xp, np.asarray(v.valid)[runs.perm], runs.seg_ids,
+                    runs.is_start, capacity)))
+        return out
+    cols = [v.data for v in key_vals] \
+        + [v.valid for v in key_vals if v.valid is not None]
+    first_row = runs.perm[runs.start_of]
+    got = gather_columns(xp, cols, first_row)
+    is_group = xp.arange(capacity, dtype=np.int32) < runs.num_groups
+    got = [xp.where(is_group, c, xp.zeros((), c.dtype)) for c in got]
+    valids = iter(got[len(key_vals):])
+    return [(d, None if v.valid is None else next(valids))
+            for d, v in zip(got, key_vals)]
 
 
 # ---------------------------------------------------------------------------
@@ -699,6 +1003,7 @@ def grouped_aggregate(
     key_exprs: Sequence[Expression],
     agg_slots: Sequence[Tuple[AggregateFunction, str]],
     bucket_cap: int = 4096,
+    scan_rounds: Optional[List[Array]] = None,
 ) -> ColumnBatch:
     """GROUP BY keys with aggregate outputs; one batch in, one batch out.
 
@@ -711,11 +1016,15 @@ def grouped_aggregate(
     buckets, aggregation runs on the MXU (one-hot matmul over 8-bit limb
     planes — see ``_mxu_grouped_aggregate``); a runtime ``lax.cond`` falls
     back to the sort-based path otherwise.
+
+    ``scan_rounds``, where given, receives the rounds the sort path's
+    segmented scan took (a traced scalar; 0 where the MXU took the rows),
+    the operator's ``agg.scan_rounds`` metric; nothing on the numpy lane.
     """
     if _mxu_agg_on() and not _is_np(xp) and key_exprs \
             and _mxu_applicable(batch.schema, key_exprs, agg_slots):
         return _mxu_grouped_aggregate(xp, batch, key_exprs, agg_slots,
-                                      bucket_cap)
+                                      bucket_cap, scan_rounds)
     if _is_np(xp) and not key_exprs:
         out = _run_aware_global_aggregate(batch, agg_slots)
         if out is not None:
@@ -727,8 +1036,9 @@ def grouped_aggregate(
     if not _is_np(xp) and key_exprs:
         # which lowering a keyed aggregate took, noted at trace time with
         # the stage being built: tracing.last_statement()["notes"]
-        tracing.note("agg_lowering", "sort")
-    return _sorted_grouped_aggregate(xp, batch, key_exprs, agg_slots)
+        tracing.note("agg_lowering", "sort.scan")
+    return _sorted_grouped_aggregate(xp, batch, key_exprs, agg_slots,
+                                     scan_rounds)
 
 
 def _run_aware_global_aggregate(
@@ -886,9 +1196,14 @@ def _sorted_grouped_aggregate(
     batch: ColumnBatch,
     key_exprs: Sequence[Expression],
     agg_slots: Sequence[Tuple[AggregateFunction, str]],
+    scan_rounds: Optional[List[Array]] = None,
 ) -> ColumnBatch:
-    """Sort-based grouping: multi-key sort → segment boundaries → segment
-    reduce (the general path; also the numpy oracle)."""
+    """Sort-based grouping: multi-key sort -> runs of equal keys -> each
+    buffer reduced within its run (the general path; also the numpy
+    oracle).  jax lane: ``sorted_runs`` / ``reduce_runs`` / ``run_keys`` (one
+    plane through ``perm``, a segmented scan, reads at the runs' ends);
+    ``scan_rounds``, where given, receives the scan's rounds (a traced
+    scalar: the operator's ``agg.scan_rounds`` metric)."""
     if not key_exprs and batch.capacity == 0:
         # the global row exists even over an empty input (COUNT=0, SUM
         # NULL); pad to one all-dead row so the ordinary no-live-rows
@@ -900,75 +1215,54 @@ def _sorted_grouped_aggregate(
     live = batch.row_valid_or_true()
     schema = batch.schema
 
-    # ---- evaluate keys and build the composite sort key -----------------
     key_vals: List[ExprValue] = [ctx.broadcast(k.eval(ctx)) for k in key_exprs]
-    sort_cols: List[Array] = [(~live).astype(np.int8)]
-    for v in key_vals:
-        data = v.data
-        if (np.asarray(data).dtype if _is_np(xp) else data.dtype) == np.bool_:
-            data = data.astype(np.int8)
-        if v.valid is None:
-            sort_cols += [xp.zeros(capacity, np.int8), data]
-        else:
-            # NULL forms its own group; rank it before all values
-            sort_cols += [xp.where(v.valid, np.int8(0), np.int8(-1)),
-                          xp.where(v.valid, data, xp.zeros((), data.dtype))]
+    sort_cols = group_sort_columns(xp, key_vals, live)
+
+    # every plain slot's buffers, built before the sort so that all of them
+    # go through ``perm`` in one plane and reduce in one scan
+    specs = {i: f.make_buffers(ctx, live)
+             for i, (f, _n) in enumerate(agg_slots)
+             if not getattr(f, "is_percentile", False)
+             and not getattr(f, "is_collect", False)}
+    flat = [s for ss in specs.values() for s in ss]
+
     # keyless (global) aggregation needs NO sort: every buffer reduces
     # over one segment, and the reductions are order-independent (First
     # reduces original-row indices).  The sort was the dominant cost of
     # every global aggregate — a full O(n log^2 n) bitonic pass on TPU
     # for a single output row.
-    with _scope(xp, "agg.sort.argsort"):
-        perm = multi_key_argsort(xp, sort_cols, capacity) \
-            if key_exprs else None
-
-    with _scope(xp, "agg.sort.permute"):
-        sorted_cols = sort_cols if perm is None \
-            else [c[perm] for c in sort_cols]
-        live_s = live if perm is None else live[perm]
-
-    # ---- segment boundaries --------------------------------------------
     if key_exprs:
+        runs, sorted_bufs = sorted_runs(xp, sort_cols, live, capacity,
+                                        [s.data for s in flat])
         with _scope(xp, "agg.sort.segment"):
-            change = xp.zeros(capacity, bool)
-            for c in sorted_cols:
-                shifted = xp.concatenate([c[:1], c[:-1]])
-                change = change | (c != shifted)
-            is_start = change
-            if _is_np(xp):
-                is_start = is_start.copy()
-                is_start[0] = True
-            else:
-                is_start = is_start.at[0].set(True)
-            is_start = is_start & live_s
-            seg_ids = xp.cumsum(is_start.astype(np.int64)) - 1
-            seg_ids = xp.where(live_s, seg_ids, np.int64(capacity - 1))
-            num_groups = xp.sum(is_start.astype(np.int64))
+            reduced, rounds = reduce_runs(xp, runs, sorted_bufs,
+                                          [s.kind for s in flat], capacity)
+            keys_out = run_keys(xp, runs, key_vals, capacity)
+        if scan_rounds is not None and rounds is not None:
+            scan_rounds.append(rounds)
+        seg_ids, is_start, live_s = runs.seg_ids, runs.is_start, runs.live_s
+        if not _is_np(xp):
+            # the percentile and collect slots keep ``segment_reduce`` and
+            # its int64 ids (no cell reaches them)
+            seg_ids = seg_ids.astype(np.int64)
+        perm = runs.perm
     else:
+        reduced = [_global_reduce(xp, s.data, s.kind, capacity) for s in flat]
+        keys_out = []
         seg_ids = xp.zeros(capacity, np.int64)
-        is_start = None
-        num_groups = None  # exactly one global group
+        perm, is_start, live_s = None, None, live
+    reduced = iter(reduced)
+    reduced_of = {i: [next(reduced) for _s in ss] for i, ss in specs.items()}
 
-    # ---- reduce buffers --------------------------------------------------
     out_names: List[str] = []
     out_vectors: List[ColumnVector] = []
-
-    # key output columns: value at each segment start scattered to group slot
-    group_pos = xp.arange(capacity, dtype=np.int64)
-    for k, v in zip(key_exprs, key_vals):
+    for k, v, (kdata, kvalid) in zip(key_exprs, key_vals, keys_out):
         dt = k.data_type(schema)
-        with _scope(xp, "agg.sort.permute"):
-            data_s = ctx.broadcast(v).data[perm]
-            valid_s = None if v.valid is None else v.valid[perm]
-        with _scope(xp, "agg.sort.segment"):
-            kdata = _scatter_starts(xp, data_s, seg_ids, is_start, capacity)
-            kvalid = None if valid_s is None else _scatter_starts(
-                xp, valid_s, seg_ids, is_start, capacity)
         out_names.append(k.name)
         out_vectors.append(ColumnVector(kdata.astype(dt.np_dtype), dt, kvalid,
                                         v.dictionary))
 
-    contribute = live
+    group_pos = xp.arange(capacity, dtype=np.int64)
     # all percentile slots over one child share ONE value-sort
     pct_slots = [(f, n) for f, n in agg_slots
                  if getattr(f, "is_percentile", False)]
@@ -980,7 +1274,7 @@ def _sorted_grouped_aggregate(
         for group in by_child.values():
             pct_results.update(_percentile_groups(
                 xp, ctx, group, sort_cols, live, capacity))
-    for func, name in agg_slots:
+    for i, (func, name) in enumerate(agg_slots):
         if getattr(func, "is_percentile", False):
             out_names.append(name)
             out_vectors.append(pct_results[name])
@@ -993,29 +1287,20 @@ def _sorted_grouped_aggregate(
                 xp, ctx, func, cperm, sort_cols, seg_ids, is_start,
                 group_pos, live_s, capacity))
             continue
-        specs = func.make_buffers(ctx, contribute)
-        if perm is None:
-            reduced = [_global_reduce(xp, s.data, s.kind, capacity)
-                       for s in specs]
-        else:
-            with _scope(xp, "agg.sort.permute"):
-                sorted_bufs = [s.data[perm] for s in specs]
-            with _scope(xp, "agg.sort.segment"):
-                reduced = [segment_reduce(xp, b, seg_ids, capacity, s.kind)
-                           for b, s in zip(sorted_bufs, specs)]
+        red = reduced_of[i]
         dt = func.data_type(schema)
         if isinstance(func, First):
             # argmin/argmax of row index → gather the value column
             v = ctx.broadcast(func.children[0].eval(ctx))
-            idx = xp.clip(reduced[0], 0, capacity - 1).astype(np.int64)
+            idx = xp.clip(red[0], 0, capacity - 1).astype(np.int64)
             # reduced index is in PRE-sort coordinates (buffers built pre-sort
             # then permuted; values stored are original indices)
             data = v.data[idx]
-            got = (reduced[0] >= 0) & (reduced[0] < np.int64(1 << 62))
+            got = (red[0] >= 0) & (red[0] < np.int64(1 << 62))
             valid = got if v.valid is None else (got & v.valid[idx])
             out = ExprValue(data, valid, v.dictionary)
         else:
-            out = func.finish(xp, reduced)
+            out = func.finish(xp, red)
         dictionary = out.dictionary if out.dictionary is not None \
             else func.output_dictionary(ctx)
         data = out.data.astype(dt.np_dtype) if dt.np_dtype != np.bool_ \
@@ -1025,7 +1310,7 @@ def _sorted_grouped_aggregate(
 
     # ---- output row mask -------------------------------------------------
     if key_exprs:
-        out_rv = group_pos < num_groups
+        out_rv = group_pos < runs.num_groups
         return ColumnBatch(out_names, out_vectors, out_rv, capacity)
     # keyless (global) aggregation: exactly ONE row, so emit capacity 1 —
     # cross joins of scalar subquery blocks (TPC-DS q88/q90) stay tiny
@@ -1176,7 +1461,10 @@ def _collect_into_arrays(xp, ctx, func, perm, sort_cols, seg_ids, is_start,
 
 def _scatter_starts(xp, sorted_data: Array, seg_ids: Array, is_start: Array,
                     capacity: int) -> Array:
-    """out[g] = sorted_data[first row of segment g] (scatter at starts)."""
+    """out[g] = sorted_data[first row of segment g] (scatter at starts).
+    Since PR 32 only ``run_keys``'s numpy lane calls it (the tests'
+    independent form); the jax lane reads a group's key at ``perm[
+    start_of[g]]`` and scatters nothing."""
     if _is_np(xp):
         out = np.zeros(capacity, dtype=np.asarray(sorted_data).dtype)
         idx = np.asarray(seg_ids)[np.asarray(is_start)]
@@ -1290,7 +1578,8 @@ def _masked_minmax64(xp, lo, hi, mask):
 
 
 @_scoped("agg.mxu")
-def _mxu_grouped_aggregate(xp, batch, key_exprs, agg_slots, bucket_cap):
+def _mxu_grouped_aggregate(xp, batch, key_exprs, agg_slots, bucket_cap,
+                           scan_rounds=None):
     import jax
     import jax.numpy as jnp
     from . import pallas_agg
@@ -1479,19 +1768,23 @@ def _mxu_grouped_aggregate(xp, batch, key_exprs, agg_slots, bucket_cap):
 
         return (tuple(pad(d) for d in out_datas),
                 tuple(pad(v) for v in out_valids),
-                pad(grow))
+                pad(grow), xp.zeros((), np.int32))
 
     def slow_branch(_):
-        cb = _sorted_grouped_aggregate(xp, batch, key_exprs, agg_slots)
+        rounds: List[Array] = []
+        cb = _sorted_grouped_aggregate(xp, batch, key_exprs, agg_slots,
+                                       rounds)
         datas = tuple(v.data for v in cb.vectors)
         valids = tuple(
             xp.broadcast_to(v.valid, (capacity,)) if v.valid is not None
             else xp.ones(capacity, bool) for v in cb.vectors)
         return datas, valids, xp.broadcast_to(cb.row_valid_or_true(),
-                                              (capacity,))
+                                              (capacity,)), rounds[0]
 
-    datas, valids, row_valid = jax.lax.cond(fits, fast_branch, slow_branch,
-                                            None)
+    datas, valids, row_valid, rounds = jax.lax.cond(
+        fits, fast_branch, slow_branch, None)
+    if scan_rounds is not None:
+        scan_rounds.append(rounds)
 
     # ---- assemble (names/dtypes/dictionaries are host-static) -----------
     out_names: List[str] = []

@@ -35,8 +35,9 @@ from ..aggregates import AggregateFunction, First
 from ..columnar import ColumnBatch, ColumnVector, pad_capacity
 from ..expressions import Col, EvalContext, Expression, Hash64
 from .. import tracing
-from ..kernels import (_scatter_starts, _scope, compact, multi_key_argsort,
-                       segment_reduce, sort_batch, sort_key_transform)
+from ..kernels import (_global_reduce, _scope, compact, group_sort_columns,
+                       reduce_runs, run_keys, sort_batch, sort_key_transform,
+                       sorted_runs)
 from ..sql import physical as P
 from ..sql.joins import PJoin
 from .collective import (broadcast_all, hash_exchange, pmax, pmin,
@@ -404,77 +405,80 @@ def _concat_batches(a: ColumnBatch, b: ColumnBatch) -> ColumnBatch:
     return ColumnBatch(a.names, vectors, rv, a.capacity + b.capacity)
 
 
-def _group_by_keys(xp, key_vals, live, capacity):
-    """The grouping prologue shared VERBATIM by the partial, partial-merge
-    and final aggregation stages (so key grouping can never desynchronize
-    between them): sort rows by (liveness, per-key null flag, key value),
-    derive segment ids.  Returns (perm, seg_ids, is_start, num_groups);
-    is_start/num_groups are None for the global (no keys) case."""
+def _group_by_keys(xp, key_vals, live, capacity, carry=()):
+    """The grouping prologue of the partial, partial-merge and final
+    aggregation stages: ``kernels.sorted_runs`` over ``kernels.
+    group_sort_columns``, the SAME code ``_sorted_grouped_aggregate`` runs
+    (one copy), so that key grouping cannot drift between them.  ``carry`` (the stage's buffers, unsorted) comes back
+    in sorted order, moved through ``perm`` with the sort columns as one
+    plane.  Returns ``(runs, carry)``; ``runs`` is None for the global (no
+    keys) case, which neither sorts nor permutes.  Every keyed caller in
+    this module is on it; none is left on a scatter."""
     if not key_vals:
-        # keyless (global): no sort, no segments — every buffer reduces
-        # whole-array (order-independent; First reduces rank values).
-        # perm=None tells the stages to skip permutation and use
-        # _reduce_buf's global path instead of a segment scatter.
-        return None, xp.zeros(capacity, np.int64), None, None
-    sort_cols = [(~live).astype(np.int8)]
-    for v in key_vals:
-        data = v.data.astype(np.int8) if str(v.data.dtype) == "bool" \
-            else v.data
-        if v.valid is None:
-            sort_cols += [xp.zeros(capacity, np.int8), data]
-        else:
-            sort_cols += [xp.where(v.valid, np.int8(0), np.int8(-1)),
-                          xp.where(v.valid, data, xp.zeros((), data.dtype))]
+        return None, list(carry)
     if xp is jnp:
-        tracing.note("agg_lowering", "sort")   # kernels.grouped_aggregate's
-    with _scope(xp, "agg.sort"):               # scopes, on this copy of it
-        with _scope(xp, "agg.sort.argsort"):
-            perm = multi_key_argsort(xp, sort_cols, capacity)
-        with _scope(xp, "agg.sort.permute"):
-            sorted_cols = [c[perm] for c in sort_cols]
-            live_s = live[perm]
-        with _scope(xp, "agg.sort.segment"):
-            change = xp.zeros(capacity, bool)
-            for c in sorted_cols:
-                change = change | (c != xp.concatenate([c[:1], c[:-1]]))
-            is_start = change.at[0].set(True) if xp is jnp \
-                else _np_set0(change)
-            is_start = is_start & live_s
-            seg_ids = xp.cumsum(is_start.astype(np.int64)) - 1
-            seg_ids = xp.where(live_s, seg_ids, np.int64(capacity - 1))
-            num_groups = xp.sum(is_start.astype(np.int64))
-    return perm, seg_ids, is_start, num_groups
-
-
-def _reduce_buf(xp, data, perm, seg_ids, capacity, kind):
-    """One aggregation-buffer reduction: segment scatter in SORTED
-    coordinates with keys, whole-array reduce without (perm=None — the
-    global case must pay neither the sort nor a scatter)."""
-    if perm is None:
-        from ..kernels import _global_reduce
-        return _global_reduce(xp, data, kind, capacity)
+        tracing.note("agg_lowering", "sort.scan")
     with _scope(xp, "agg.sort"):
-        with _scope(xp, "agg.sort.permute"):
-            data_s = data[perm]
-        with _scope(xp, "agg.sort.segment"):
-            return segment_reduce(xp, data_s, seg_ids, capacity, kind)
+        return sorted_runs(xp, group_sort_columns(xp, key_vals, live), live,
+                           capacity, carry)
 
 
-def _emit_group_keys(xp, keys, key_dts, key_vals, perm, seg_ids, is_start,
-                     capacity):
-    """Scatter each group's key value to its segment-start slot; returns
-    (names, vectors) for the output key columns."""
+def _reduce_bufs(xp, runs, bufs, kinds, capacity):
+    """The stage's buffers reduced by group, all in one scan: ``kernels.
+    reduce_runs`` in SORTED coordinates with keys, a whole-array reduce
+    without (``runs`` None: the global case pays neither a sort nor a scan).
+    Returns ``(reduced, rounds)``; ``rounds`` is the scan's, None where
+    there was none."""
+    if runs is None:
+        return [_global_reduce(xp, b, k, capacity)
+                for b, k in zip(bufs, kinds)], None
+    with _scope(xp, "agg.sort"), _scope(xp, "agg.sort.segment"):
+        return reduce_runs(xp, runs, bufs, kinds, capacity)
+
+
+def _emit_group_keys(xp, keys, key_dts, key_vals, runs, capacity):
+    """Each group's key values at its slot (``kernels.run_keys``: read at
+    the group's first row); returns (names, vectors) for the output key
+    columns."""
+    with _scope(xp, "agg.sort"), _scope(xp, "agg.sort.segment"):
+        got = run_keys(xp, runs, key_vals, capacity)
     names, vectors = [], []
-    for k, dt, v in zip(keys, key_dts, key_vals):
-        with _scope(xp, "agg.sort"), _scope(xp, "agg.sort.segment"):
-            kd = _scatter_starts(xp, v.data[perm], seg_ids, is_start,
-                                 capacity)
-            kv = None if v.valid is None else _scatter_starts(
-                xp, v.valid[perm], seg_ids, is_start, capacity)
+    for k, dt, v, (kd, kv) in zip(keys, key_dts, key_vals, got):
         names.append(k.name)
         vectors.append(ColumnVector(kd.astype(dt.np_dtype), dt, kv,
                                     v.dictionary))
     return names, vectors
+
+
+def _reduce_stage(ctx, node, key_vals, live, capacity, plain, firsts):
+    """One aggregation stage's grouping and reductions.  ``plain`` is
+    ``[(buffer, kind)]`` and ``firsts`` ``[(rank, dead_rank, value,
+    validplane, is_last)]``, all unsorted with dead rows masked.  Every
+    buffer goes through the sort's permutation in ONE plane and every plain
+    one reduces in ONE scan, whose rounds become ``node``'s operator metric.
+    Returns ``(runs, reduced plain buffers, reduced (rank, value, valid)
+    triples)``."""
+    xp = ctx.xp
+    carry = [d for d, _k in plain]
+    for rank, _dead, value, validplane, _last in firsts:
+        carry += [rank, value, validplane]
+    runs, moved = _group_by_keys(xp, key_vals, live, capacity, carry)
+    reduced, rounds = _reduce_bufs(xp, runs, moved[:len(plain)],
+                                   [k for _d, k in plain], capacity)
+    triples = []
+    for j, (_rank, dead_rank, _v, _vp, is_last) in enumerate(firsts):
+        r_s, v_s, vp_s = moved[len(plain) + 3 * j:len(plain) + 3 * j + 3]
+        triples.append(_first_last_reduce(xp, runs, r_s, dead_rank, v_s,
+                                          vp_s, is_last, capacity))
+    if rounds is not None:
+        ctx.add_metric(node.op_id, P.SCAN_ROUNDS, rounds)
+    return runs, reduced, triples
+
+
+def _group_mask(xp, runs, capacity):
+    """The output row mask of a stage: its groups, or the one global row."""
+    return xp.arange(capacity, dtype=np.int64) < (
+        1 if runs is None else runs.num_groups)
 
 
 class DPartialAggregate(DNode):
@@ -506,12 +510,8 @@ class DPartialAggregate(DNode):
         capacity = batch.capacity
 
         key_vals = [ectx.broadcast(k.eval(ectx)) for k in self.keys]
-        perm, seg_ids, is_start, num_groups = _group_by_keys(
-            xp, key_vals, live, capacity)
-        names, vectors = _emit_group_keys(
-            xp, self.keys, [k.data_type(batch.schema) for k in self.keys],
-            key_vals, perm, seg_ids, is_start, capacity)
-
+        # every slot's buffers, built before the sort (``_reduce_stage``)
+        plain, firsts, specs_of = [], [], {}
         for i, (func, n) in enumerate(self.slots):
             if isinstance(func, First):
                 # value-carry buffers (rank, value, winner-validity): the
@@ -539,14 +539,22 @@ class DPartialAggregate(DNode):
                 rank = xp.where(contrib, rank, dead_rank)
                 validplane = v.valid if v.valid is not None \
                     else xp.ones(capacity, bool)
-                if perm is None:
-                    r_s, v_s, vp_s = rank, v.data, validplane
-                else:
-                    r_s, v_s, vp_s = rank[perm], v.data[perm], \
-                        validplane[perm]
-                r_red, v_red, valid_red = _first_last_reduce(
-                    xp, r_s, dead_rank, v_s, vp_s, seg_ids, is_last,
-                    capacity, global_mode=perm is None)
+                specs_of[i] = v
+                firsts.append((rank, dead_rank, v.data, validplane, is_last))
+                continue
+            specs_of[i] = func.make_buffers(ectx, live)
+            plain += [(spec.data, spec.kind) for spec in specs_of[i]]
+        runs, reduced, triples = _reduce_stage(
+            ctx, self, key_vals, live, capacity, plain, firsts)
+        names, vectors = _emit_group_keys(
+            xp, self.keys, [k.data_type(batch.schema) for k in self.keys],
+            key_vals, runs, capacity)
+
+        reduced, triples = iter(reduced), iter(triples)
+        for i, (func, n) in enumerate(self.slots):
+            if isinstance(func, First):
+                v = specs_of[i]
+                r_red, v_red, valid_red = next(triples)
                 bn_rank, bn_val, bn_valid = self.buffer_names(i, func)
                 names += [bn_rank, bn_val, bn_valid]
                 np_v = np.dtype(str(v_red.dtype)) if xp is jnp \
@@ -562,11 +570,10 @@ class DPartialAggregate(DNode):
                     v_red, v_dt, None, v.dictionary))
                 vectors.append(ColumnVector(valid_red, T.int8, None, None))
                 continue
-            specs = func.make_buffers(ectx, live)
             odict = func.output_dictionary(ectx)
-            for j, (bn, spec) in enumerate(zip(self.buffer_names(i, func), specs)):
-                reduced = _reduce_buf(xp, spec.data, perm, seg_ids, capacity,
-                                      spec.kind)
+            for j, (bn, spec) in enumerate(zip(self.buffer_names(i, func),
+                                               specs_of[i])):
+                red = next(reduced)
                 names.append(bn)
                 if j == 0 and odict is not None:
                     # min/max over a dictionary column: the value buffer
@@ -574,16 +581,13 @@ class DPartialAggregate(DNode):
                     # so union_all/the exchange carry (and unify) the
                     # dictionary instead of shipping bare ints
                     vectors.append(ColumnVector(
-                        reduced, func.data_type(batch.schema), None, odict))
+                        red, func.data_type(batch.schema), None, odict))
                     continue
-                vectors.append(ColumnVector(reduced, T.np_dtype_to_engine(spec.np_dtype)
+                vectors.append(ColumnVector(red, T.np_dtype_to_engine(spec.np_dtype)
                                             if spec.np_dtype != np.bool_ else T.boolean,
                                             None, None))
-        if self.keys:
-            rv = xp.arange(capacity, dtype=np.int64) < num_groups
-        else:
-            rv = xp.arange(capacity, dtype=np.int64) < 1
-        return ColumnBatch(names, vectors, rv, capacity)
+        return ColumnBatch(names, vectors, _group_mask(xp, runs, capacity),
+                           capacity)
 
     def __repr__(self):
         return (f"PartialAggregate keys=[{', '.join(map(repr, self.keys))}] "
@@ -591,25 +595,25 @@ class DPartialAggregate(DNode):
 
 
 
-def _first_last_reduce(xp, rank_s, dead_rank, value_s, validplane_s, seg_ids,
-                       is_last, capacity, global_mode=False):
-    """Shared (rank, value, validity) segment merge for first/last value-
+def _first_last_reduce(xp, runs, rank_s, dead_rank, value_s, validplane_s,
+                       is_last, capacity):
+    """Shared (rank, value, validity) merge by group for first/last value-
     carry buffers — used identically by the partial and final stages so
     the rank encoding can never desynchronize.  With keys the inputs are
-    in SORTED coordinates; ``global_mode`` (keyless) reduces whole-array
-    with unsorted inputs.  Returns (rank_red, value_red, valid_red int8)."""
+    in SORTED coordinates and reduce by ``_reduce_bufs`` (the winning rank
+    in one scan; its value and validity, masked by it, in a second);
+    without (``runs`` None) they reduce whole-array, unsorted.  Returns
+    (rank_red, value_red, valid_red int8)."""
     from ..aggregates import IDENTITY
-    from ..kernels import _global_reduce
     kind = "max" if is_last else "min"
 
-    def red(d, k):
-        return _global_reduce(xp, d, k, capacity) if global_mode \
-            else segment_reduce(xp, d, seg_ids, capacity, k)
+    def red(bufs, kinds):
+        return _reduce_bufs(xp, runs, bufs, kinds, capacity)[0]
 
-    r_red = red(rank_s, kind)
+    (r_red,) = red([rank_s], [kind])
     # [:1] not [0]: broadcasts identically for capacity>0 and stays
     # shape-(0,)-safe for capacity-0 host batches
-    r_mine = r_red[:1] if global_mode else r_red[seg_ids]
+    r_mine = r_red[:1] if runs is None else r_red[runs.seg_ids]
     win = (rank_s == r_mine) & (rank_s != dead_rank)
     np_dt = np.dtype(str(value_s.dtype)) if xp is jnp \
         else np.asarray(value_s).dtype
@@ -618,17 +622,36 @@ def _first_last_reduce(xp, rank_s, dead_rank, value_s, validplane_s, seg_ids,
         np_dt = np.dtype(np.int8)
     ident = IDENTITY["max"](np_dt)
     masked = xp.where(win, value_s, np.asarray(ident, value_s.dtype))
-    v_red = red(masked, "max")
     masked_valid = xp.where(win, validplane_s.astype(np.int8), np.int8(0))
-    valid_red = red(masked_valid, "max")
+    v_red, valid_red = red([masked, masked_valid], ["max", "max"])
     return r_red, v_red, valid_red
 
 
-def _np_set0(change):
-    change = change.copy()
-    if len(change):          # a capacity-0 host batch has no first row
-        change[0] = True
-    return change
+def _partial_buffers(xp, batch, live, partial, slots):
+    """The buffer columns a partial stage emitted, as ``_reduce_stage``
+    takes them (the merge and final stages read the same columns the same
+    way): each plain buffer with its OWN kind — sum of sums, min of mins —
+    and dead rows at that kind's identity, each first / last triple with
+    dead rows at the dead rank."""
+    from ..aggregates import IDENTITY
+    plain, firsts = [], []
+    for i, (func, _n) in enumerate(slots):
+        if isinstance(func, First):
+            is_last = getattr(func, "ARGREDUCE", "first") == "last"
+            dead_rank = np.int64(-1) if is_last else np.int64(1 << 62)
+            bn_rank, bn_val, bn_valid = partial.buffer_names(i, func)
+            firsts.append((
+                xp.where(live, batch.column(bn_rank).data, dead_rank),
+                dead_rank, batch.column(bn_val).data,
+                batch.column(bn_valid).data != 0, is_last))
+            continue
+        for j, kind in enumerate(DFinalAggregate._buffer_kinds(func)):
+            data = batch.column(partial.buffer_names(i, func)[j]).data
+            np_dt = np.dtype(str(data.dtype))
+            ident = IDENTITY[kind](np_dt)
+            plain.append((xp.where(live, data, np.asarray(ident, np_dt)),
+                          kind))
+    return plain, firsts
 
 
 class DFinalAggregate(DNode):
@@ -658,30 +681,22 @@ class DFinalAggregate(DNode):
 
         key_refs = [Col(k.name) for k in self.keys]
         key_vals = [ectx.broadcast(k.eval(ectx)) for k in key_refs]
-        perm, seg_ids, is_start, num_groups = _group_by_keys(
-            xp, key_vals, live, capacity)
+        plain, firsts = _partial_buffers(xp, batch, live, self.partial,
+                                         self.slots)
+        runs, reduced, triples = _reduce_stage(
+            ctx, self, key_vals, live, capacity, plain, firsts)
         cs_child = self.partial.children[0].schema()
         names, vectors = _emit_group_keys(
             xp, self.keys, [k.data_type(cs_child) for k in self.keys],
-            key_vals, perm, seg_ids, is_start, capacity)
+            key_vals, runs, capacity)
 
+        reduced, triples = iter(reduced), iter(triples)
         for i, (func, n) in enumerate(self.slots):
             if isinstance(func, First):
                 is_last = getattr(func, "ARGREDUCE", "first") == "last"
                 dead_rank = np.int64(-1) if is_last else np.int64(1 << 62)
-                bn_rank, bn_val, bn_valid = self.partial.buffer_names(i, func)
-                rank_col = batch.column(bn_rank).data
-                val_col = batch.column(bn_val)
-                validplane = batch.column(bn_valid).data != 0
-                rank_m = xp.where(live, rank_col, dead_rank)
-                if perm is None:
-                    r_s, v_s, vp_s = rank_m, val_col.data, validplane
-                else:
-                    r_s, v_s, vp_s = rank_m[perm], val_col.data[perm], \
-                        validplane[perm]
-                r_red, v_red, valid_red = _first_last_reduce(
-                    xp, r_s, dead_rank, v_s, vp_s, seg_ids, is_last,
-                    capacity, global_mode=perm is None)
+                val_col = batch.column(self.partial.buffer_names(i, func)[1])
+                r_red, v_red, valid_red = next(triples)
                 got = (r_red != dead_rank) & (valid_red != 0)
                 dt = func.data_type(cs_child)
                 data = v_red.astype(np.bool_) \
@@ -691,19 +706,7 @@ class DFinalAggregate(DNode):
                 vectors.append(ColumnVector(data, dt, got,
                                             val_col.dictionary))
                 continue
-            bufs = []
-            specs_kinds = self._buffer_kinds(func)
-            for j, kind in enumerate(specs_kinds):
-                bname = self.partial.buffer_names(i, func)[j]
-                col = batch.column(bname)
-                masked = col.data
-                from ..aggregates import IDENTITY
-                np_dt = np.dtype(str(masked.dtype))
-                ident = IDENTITY[kind](np_dt)
-                masked = xp.where(live, masked, np.asarray(ident, np_dt))
-                reduced = _reduce_buf(xp, masked, perm, seg_ids, capacity,
-                                      kind)
-                bufs.append(reduced)
+            bufs = [next(reduced) for _kind in self._buffer_kinds(func)]
             out = func.finish(xp, bufs)
             dt = func.data_type(cs_child)
             dictionary = out.dictionary
@@ -715,12 +718,8 @@ class DFinalAggregate(DNode):
             data = out.data.astype(dt.np_dtype)
             names.append(n)
             vectors.append(ColumnVector(data, dt, out.valid, dictionary))
-
-        if self.keys:
-            rv = xp.arange(capacity, dtype=np.int64) < num_groups
-        else:
-            rv = xp.arange(capacity, dtype=np.int64) < 1
-        return ColumnBatch(names, vectors, rv, capacity)
+        return ColumnBatch(names, vectors, _group_mask(xp, runs, capacity),
+                           capacity)
 
     @staticmethod
     def _buffer_kinds(func: AggregateFunction) -> List[str]:
@@ -773,31 +772,21 @@ class DMergePartial(DNode):
 
         key_refs = [Col(k.name) for k in self.keys]
         key_vals = [ectx.broadcast(k.eval(ectx)) for k in key_refs]
-        perm, seg_ids, is_start, num_groups = _group_by_keys(
-            xp, key_vals, live, capacity)
+        plain, firsts = _partial_buffers(xp, batch, live, self.partial,
+                                         self.slots)
+        runs, reduced, triples = _reduce_stage(
+            ctx, self, key_vals, live, capacity, plain, firsts)
         cs_child = self.partial.children[0].schema()
         names, vectors = _emit_group_keys(
             xp, self.keys, [k.data_type(cs_child) for k in self.keys],
-            key_vals, perm, seg_ids, is_start, capacity)
+            key_vals, runs, capacity)
 
-        from ..aggregates import IDENTITY
+        reduced, triples = iter(reduced), iter(triples)
         for i, (func, _n) in enumerate(self.slots):
             if isinstance(func, First):
-                is_last = getattr(func, "ARGREDUCE", "first") == "last"
-                dead_rank = np.int64(-1) if is_last else np.int64(1 << 62)
                 bn_rank, bn_val, bn_valid = self.partial.buffer_names(i, func)
-                rank_col = batch.column(bn_rank).data
                 val_col = batch.column(bn_val)
-                validplane = batch.column(bn_valid).data != 0
-                rank_m = xp.where(live, rank_col, dead_rank)
-                if perm is None:
-                    r_s, v_s, vp_s = rank_m, val_col.data, validplane
-                else:
-                    r_s, v_s, vp_s = rank_m[perm], val_col.data[perm], \
-                        validplane[perm]
-                r_red, v_red, valid_red = _first_last_reduce(
-                    xp, r_s, dead_rank, v_s, vp_s, seg_ids, is_last,
-                    capacity, global_mode=perm is None)
+                r_red, v_red, valid_red = next(triples)
                 names += [bn_rank, bn_val, bn_valid]
                 vectors.append(ColumnVector(r_red, T.int64, None, None))
                 vectors.append(ColumnVector(v_red, val_col.dtype, None,
@@ -805,23 +794,14 @@ class DMergePartial(DNode):
                 vectors.append(ColumnVector(valid_red.astype(np.int8),
                                             T.int8, None, None))
                 continue
-            for j, kind in enumerate(DFinalAggregate._buffer_kinds(func)):
+            for j, _kind in enumerate(DFinalAggregate._buffer_kinds(func)):
                 bname = self.partial.buffer_names(i, func)[j]
                 col = batch.column(bname)
-                np_dt = np.dtype(str(col.data.dtype))
-                ident = IDENTITY[kind](np_dt)
-                masked = xp.where(live, col.data, np.asarray(ident, np_dt))
-                reduced = _reduce_buf(xp, masked, perm, seg_ids, capacity,
-                                      kind)
                 names.append(bname)
-                vectors.append(ColumnVector(reduced, col.dtype, None,
+                vectors.append(ColumnVector(next(reduced), col.dtype, None,
                                             col.dictionary))
-
-        if self.keys:
-            rv = xp.arange(capacity, dtype=np.int64) < num_groups
-        else:
-            rv = xp.arange(capacity, dtype=np.int64) < 1
-        return ColumnBatch(names, vectors, rv, capacity)
+        return ColumnBatch(names, vectors, _group_mask(xp, runs, capacity),
+                           capacity)
 
     def __repr__(self):
         return (f"MergePartial keys=[{', '.join(map(repr, self.keys))}] "

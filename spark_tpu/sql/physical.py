@@ -59,6 +59,23 @@ def record_join_paths(int_flags, kinds, caps=()) -> None:
                 pass
 
 
+#: the operator metric a sort aggregate adds (``ExecContext.add_metric``):
+#: the rounds its segmented scan took, ``ceil(log2(longest live run))``
+SCAN_ROUNDS = "agg.scan_rounds"
+#: and the zero-length host span ``record_scan_rounds`` writes for it
+AGG_SCAN = "agg.scan"
+
+
+def record_scan_rounds(metrics) -> None:
+    """One ``agg.scan`` span (``op``, ``rounds``) for each ``agg.scan_rounds``
+    among the operator metrics just fetched (``{(op_id, label): value}``):
+    ``python -m spark_tpu.tracing`` tallies them beside the join paths."""
+    for (op_id, label), rounds in metrics.items():
+        if label == SCAN_ROUNDS:
+            with tracing.span(AGG_SCAN, op=op_id, rounds=rounds):
+                pass
+
+
 def all_shards_path(flag, pmax):
     """A ``JOIN_PATH`` flag over the mesh: a path reads taken only where
     every shard took it, so each bit is the minimum over shards."""
@@ -293,7 +310,14 @@ class PAggregate(PhysicalPlan):
 
     def run(self, ctx):
         batch = self.children[0].run(ctx)
-        return grouped_aggregate(ctx.xp, batch, self.keys, self.slots)
+        rounds: List[Array] = []
+        out = grouped_aggregate(ctx.xp, batch, self.keys, self.slots,
+                                scan_rounds=rounds)
+        if rounds:
+            # the rounds the sort path's segmented scan took: what the
+            # program read off its input (kernels.segmented_scan)
+            ctx.add_metric(self.op_id, SCAN_ROUNDS, rounds[0])
+        return out
 
     def __repr__(self):
         return (f"Aggregate keys=[{', '.join(repr(k) for k in self.keys)}] "

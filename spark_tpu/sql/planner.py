@@ -925,6 +925,7 @@ class QueryExecution:
             int_flags = [int(np.asarray(f)) for f in flags]
             self.metrics = {k: int(np.asarray(v))
                             for k, v in zip(metric_keys, metric_vals)}
+            P.record_scan_rounds(self.metrics)
             host = _slice_to_host(result, int(np.asarray(n_rows)))
             sp.attrs["bytes"] = _leaves_nbytes([host])
         return host, self.read_flags(int_flags, flag_caps, flag_kinds)

@@ -323,8 +323,10 @@ class _MappedStream(BatchStream):
                             c = compact(jnp, out)
                         # host-side capture at trace time, by capacities
                         meta[tuple(b.capacity for b in all_leaves)] = (
-                            list(ctx.flag_caps), list(ctx.flag_kinds))
-                        return c, c.num_rows(), ctx.flags
+                            list(ctx.flag_caps), list(ctx.flag_kinds),
+                            [(oid, lbl) for oid, lbl, _v in ctx.metrics])
+                        return c, c.num_rows(), ctx.flags, \
+                            [v for _o, _l, v in ctx.metrics]
                     finally:
                         E._slot_bindings.map = None
 
@@ -348,10 +350,14 @@ class _MappedStream(BatchStream):
                         out = phys.run(ctx)
                         c = compact(jnp, out)
                         meta[tuple(b.capacity for b in all_leaves)] = (
-                            list(ctx.flag_caps), list(ctx.flag_kinds))
+                            list(ctx.flag_caps), list(ctx.flag_kinds),
+                            [(oid, lbl) for oid, lbl, _v in ctx.metrics])
                         # worst per-shard overflow drives the adaptive retry
                         flags = [pmax(f) for f in ctx.flags]
-                        return c, lax.psum(c.num_rows(), DATA_AXIS), flags
+                        # (the one operator metric a stage has, a sort
+                        # aggregate's scan rounds, reads its slowest shard)
+                        return c, lax.psum(c.num_rows(), DATA_AXIS), flags, \
+                            [pmax(v) for _o, _l, v in ctx.metrics]
                 finally:
                     E._slot_bindings.map = None
 
@@ -361,7 +367,7 @@ class _MappedStream(BatchStream):
                           + [PartitionSpec()] * n_extra,
                           PartitionSpec()),
                 out_specs=(PartitionSpec(DATA_AXIS), PartitionSpec(),
-                           PartitionSpec()),
+                           PartitionSpec(), PartitionSpec()),
                 check_vma=False,
             )
             return wrapped, meta
@@ -418,13 +424,16 @@ class _MappedStream(BatchStream):
         jstep, extra, meta = compiled
         base_f = self.session.conf.get(C.JOIN_OUTPUT_FACTOR)
         for _attempt in range(6):
-            out, n, flags = jstep([self._leaf_to_device(b)] + extra)
-            caps, kinds = meta.get(self._meta_key(b, extra), ([], []))
+            out, n, flags, metrics = jstep([self._leaf_to_device(b)] + extra)
+            caps, kinds, metric_keys = meta.get(self._meta_key(b, extra),
+                                                ([], [], []))
             with tracing.span("d2h"):    # the flag fetch waits for the step
                 int_flags = [int(np.asarray(f)) for f in flags]
                 runs = None if any(f > 0 for f in int_flags) \
                     else self._to_runs(out, n)
             P.record_join_paths(int_flags, kinds, caps)
+            P.record_scan_rounds({k: int(np.asarray(v))
+                                  for k, v in zip(metric_keys, metrics)})
             if runs is not None:
                 return runs, (jstep, extra, meta)
             cur = list(self._factors) if self._factors else []

@@ -452,6 +452,8 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
     "unnamed_top": [[instruction, seconds], ...], "host_spans": {name:
     [count, seconds]}, "join_paths": [[{unique, dense, out_cap, probe_cap,
     string}, count], ...] (the ``join.path`` spans by their attributes),
+    "agg_scans": [[{op, rounds}, count], ...] (the ``agg.scan`` spans: the
+    rounds a sort aggregate's segmented scan took),
     "profile_start_ns", "annotations": [[name, start_ns (epoch), dur_ns],
     ...]}``
 
@@ -460,7 +462,8 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
     data = jax.profiler.ProfileData.from_file(xplane_path)
     op_names = _op_names(xplane_path)
     devices, host, annotations, start_ns = {}, {}, [], None
-    join_paths: "collections.Counter[str]" = collections.Counter()
+    records = {"join.path": collections.Counter(),
+               "agg.scan": collections.Counter()}
     for plane in data.planes:
         if plane.name == "Task Environment":
             start_ns = dict(plane.stats).get("profile_start_time", start_ns)
@@ -482,9 +485,9 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
                         got[0] += 1
                         got[1] += e.duration_ns / 1e9
                         annotations.append([name, e.start_ns, e.duration_ns])
-                        if name == "join.path":
-                            join_paths[json.dumps(dict(e.stats),
-                                                  sort_keys=True)] += 1
+                        if name in ("join.path", "agg.scan"):
+                            records[name][json.dumps(dict(e.stats),
+                                                     sort_keys=True)] += 1
     if start_ns is not None:
         for a in annotations:
             a[1] = int(a[1] + start_ns)
@@ -492,8 +495,10 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
            "unnamed_pct": None, "by_scope": [], "top_ops": [],
            "unnamed_top": [],
            "host_spans": host, "profile_start_ns": start_ns,
-           "join_paths": [[json.loads(a), n]
-                          for a, n in join_paths.most_common()],
+           "join_paths": [[json.loads(a), n] for a, n in
+                          records["join.path"].most_common()],
+           "agg_scans": [[json.loads(a), n] for a, n in
+                         records["agg.scan"].most_common()],
            "annotations": annotations}
     if not devices:
         return out
@@ -595,6 +600,8 @@ def _main(argv) -> int:
             print(f"  {n:6d} {s:10.4f} s  sql:{name}")
         for attrs, n in r["join_paths"]:
             print(f"  {n:6d} sql:join.path  {json.dumps(attrs)}")
+        for attrs, n in r["agg_scans"]:
+            print(f"  {n:6d} sql:agg.scan  {json.dumps(attrs)}")
         print(json.dumps({"profile_start_ns": r["profile_start_ns"],
                           "annotations": len(r["annotations"])}))
     return 0

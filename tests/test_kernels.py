@@ -739,3 +739,211 @@ def test_plane_kernels_jit_match_eager():
     for out in (eager, jitted):
         assert int(np.asarray(out.column("c").data)[0]) == want_c
         assert int(np.asarray(out.column("s").data)[0]) == want_s
+
+
+# ---------------------------------------------------------------------------
+# the sort aggregate after its argsort: runs, the segmented scan, the reads
+# ---------------------------------------------------------------------------
+
+def _run_shapes():
+    """name -> (run lengths of the live rows, dead rows after them)."""
+    powers = [n for k in range(1, 7) for n in (2 ** k - 1, 2 ** k, 2 ** k + 1)]
+    return {
+        "one_run": ([200], 0),
+        "singletons": ([1] * 130, 0),
+        "dead_tail": ([5, 1, 9, 2], 47),
+        "no_live": ([], 64),
+        "capacity_1": ([1], 0),
+        "powers_of_two": (powers, 11),
+        # a long run that starts at an odd position, between short ones:
+        # every stride's i - d falls inside it, before it and on its edge
+        "straddles": ([3, 333, 1, 2, 97, 1], 5),
+    }
+
+
+_RUN_CASES = [(kind, dt, shape) for kind in ("sum", "min", "max")
+              for dt in ("int64", "float64", "int8")
+              for shape in sorted(_run_shapes())] \
+    + [("sum", "float64", "mixed_65536")]
+
+
+@pytest.mark.parametrize("kind,dtype,shape", _RUN_CASES)
+def test_reduce_runs_equals_np_segment_reduce(kind, dtype, shape):
+    """``kernels.reduce_runs`` on the traced lane (the segmented scan and
+    the read at each run's end) against ``_np_segment_reduce`` over the
+    numpy lane's layout of the same rows: integers and min / max bit for
+    bit in EVERY slot (the identity past the groups), a float sum within
+    1e-12 of ``math.fsum``; and the rounds are ``ceil(log2(longest live
+    run))``."""
+    import math
+    from spark_tpu import kernels as K
+    from spark_tpu.aggregates import IDENTITY
+    from spark_tpu.expressions import ExprValue
+
+    rng = np.random.default_rng(abs(hash((kind, dtype, shape))) % (1 << 31))
+    if shape == "mixed_65536":
+        lengths, dead = [], 1 << 16
+        while dead > 4096:                    # runs of 1..1500 rows
+            lengths.append(int(rng.integers(1, 1500)))
+            dead -= lengths[-1]
+    else:
+        lengths, dead = _run_shapes()[shape]
+    n_live = sum(lengths)
+    capacity = n_live + dead
+    np_dt = np.dtype(dtype)
+    if np_dt.kind == "f":
+        data = rng.normal(size=capacity) * 10.0 ** rng.integers(
+            -6, 9, capacity)
+    else:
+        info = np.iinfo(np_dt)
+        data = rng.integers(info.min // 4, info.max // 4, capacity
+                            ).astype(np_dt)
+    ident = IDENTITY[kind](np_dt)
+    # rows in a shuffled order, so that the layout's perm does work
+    order = rng.permutation(capacity)
+    key = np.empty(capacity, np.int64)
+    live = np.empty(capacity, bool)
+    key[order] = np.concatenate([np.repeat(np.arange(len(lengths)), lengths),
+                                 rng.integers(0, 3, dead)]).astype(np.int64)
+    live[order] = np.arange(capacity) < n_live
+    buf = np.where(live, data, np.asarray(ident, np_dt)).astype(np_dt)
+
+    def reduce(xp, key, live, buf):
+        cols = K.group_sort_columns(xp, [ExprValue(key, None, None)], live)
+        runs, (moved,) = K.sorted_runs(xp, cols, live, capacity, [buf])
+        (red,), rounds = K.reduce_runs(xp, runs, [moved], [kind], capacity)
+        return red, runs.num_groups, rounds
+
+    want, n_groups, no_rounds = reduce(np, key, live, buf)
+    got, got_groups, rounds = jax.jit(
+        lambda *a: reduce(jnp, *a))(key, live, buf)
+    got = np.asarray(got)
+    assert no_rounds is None and int(got_groups) == int(n_groups) \
+        == len(lengths)
+    assert got.dtype == np_dt and got.shape == (capacity,)
+    longest = max(lengths, default=0)
+    assert int(rounds) == (math.ceil(math.log2(longest)) if longest > 1
+                           else 0)
+    if np_dt.kind == "f" and kind == "sum":
+        rows = np.argsort(key[live], kind="stable")
+        exact = np.asarray([math.fsum(r) for r in np.split(
+            data[live][rows], np.cumsum(lengths)[:-1])]) if lengths \
+            else np.zeros(0)
+        scale = np.asarray([math.fsum(np.abs(r)) for r in np.split(
+            data[live][rows], np.cumsum(lengths)[:-1])]) if lengths \
+            else np.zeros(0)
+        assert np.all(np.abs(got[:len(lengths)] - exact) <= 1e-12 * scale)
+        assert np.all(got[len(lengths):] == 0.0)
+        np.testing.assert_allclose(got, want, rtol=1e-12, atol=0)
+    else:
+        assert np.array_equal(got, want)
+
+
+def test_gather_columns_keeps_every_bit():
+    """``kernels.gather_columns`` on the traced lane returns ``c[idx]`` for
+    every dtype a column or a buffer has, bit for bit: 8-byte words split
+    and rejoined, small integers and bools packed four to a word, float64
+    on its own plane (-0.0, NaN payloads and infinities included)."""
+    from spark_tpu import kernels as K
+    rng = np.random.default_rng(5)
+    n = 257
+    f = rng.normal(size=n)
+    f[:6] = [-0.0, 0.0, np.inf, -np.inf, np.nan, 5e-324]
+    cols = [rng.integers(-1 << 62, 1 << 62, n), f,
+            rng.integers(-128, 128, n).astype(np.int8), rng.random(n) < 0.5,
+            rng.integers(-1 << 30, 1 << 30, n).astype(np.int32),
+            rng.integers(-1 << 14, 1 << 14, n).astype(np.int16),
+            rng.normal(size=n).astype(np.float32),
+            rng.integers(0, 256, n).astype(np.uint8), rng.random(n) < 0.5,
+            rng.integers(-128, 128, n).astype(np.int8),
+            rng.integers(0, 1 << 63, n).astype(np.uint64)]
+    idx = rng.integers(0, n, 400).astype(np.int32)
+    got = jax.jit(lambda i, *c: K.gather_columns(jnp, c, i))(idx, *cols)
+    for c, g in zip(cols, got):
+        g = np.asarray(g)
+        assert g.dtype == c.dtype
+        assert g.tobytes() == c[idx].tobytes()
+
+
+def _lane_cases():
+    """name -> (keys, slots, sorted path only)."""
+    from spark_tpu.aggregates import CollectList, PercentileApprox
+    plain = [(Sum(Col("v")), "s"), (CountStar(), "n"), (Min(Col("i")), "lo"),
+             (Max(Col("v")), "hi"), (Avg(Col("i")), "av")]
+    return {
+        "null_keys": ([Col("a")], plain, False),
+        "dictionary_key": ([Col("w")], plain, False),
+        "two_keys": ([Col("a"), Col("b")], plain, False),
+        "no_validity_key": ([Col("b")], plain, False),
+        "first_last": ([Col("a")], [(First(Col("c")), "f"),
+                                    (Last(Col("c")), "l"),
+                                    (Sum(Col("v")), "s")], False),
+        "percentile": ([Col("a")], [(PercentileApprox(Col("v"), 0.5), "p"),
+                                    (Sum(Col("v")), "s")], True),
+        "collect": ([Col("b")], [(CollectList(Col("i")), "xs"),
+                                 (Sum(Col("v")), "s")], True),
+    }
+
+
+def _lane_batch():
+    rng = np.random.default_rng(11)
+    n = 300
+    a = [None if rng.random() < 0.15 else int(rng.integers(-3, 9))
+         for _ in range(n)]
+    c = [None if rng.random() < 0.3 else int(rng.integers(0, 50))
+         for _ in range(n)]
+    batch = ColumnBatch.from_arrays({
+        "a": a, "b": rng.integers(0, 4, n).astype(np.int64),
+        "w": list(np.array(["pear", "fig", "kiwi", "plum"])[
+            rng.integers(0, 4, n)]),
+        "v": rng.normal(size=n) * 100, "c": c,
+        "i": rng.integers(-50, 50, n).astype(np.int64)})
+    live = np.asarray(batch.row_valid_or_true()) \
+        & (rng.random(batch.capacity) < 0.8)
+    return ColumnBatch(batch.names, batch.vectors, live, batch.capacity)
+
+
+def _rows_of(batch):
+    rows = compact(np, batch.to_host()).to_pylist()
+    return rows[:int(np.asarray(batch.num_rows()))]
+
+
+@pytest.mark.parametrize("case,operator", [
+    (case, operator) for case, (_k, _s, sorted_only) in sorted(
+        _lane_cases().items())
+    for operator in ("sorted", "dist") if not (sorted_only
+                                               and operator == "dist")])
+def test_sort_aggregate_lanes_agree(case, operator):
+    """``_sorted_grouped_aggregate`` and ``parallel/dist.py``'s partial ->
+    merge -> final stages give on the traced lane (runs, scan, reads) what
+    they give on the numpy lane (``lexsort``, ``np.add.at``, scatters):
+    NULL keys, a dictionary key, two keys, a key with no validity, first /
+    last, and a percentile and a collect slot beside a sum."""
+    from spark_tpu import kernels as K
+    from spark_tpu.parallel.dist import (DFinalAggregate, DMergePartial,
+                                         DPartialAggregate)
+    from spark_tpu.sql import physical as P
+    keys, slots, _sorted_only = _lane_cases()[case]
+    batch = _lane_batch()
+
+    def run(xp, b):
+        if operator == "sorted":
+            return K._sorted_grouped_aggregate(xp, b, keys, slots)
+        partial = DPartialAggregate(keys, slots, P.PScan(0, b.schema))
+        parts = partial.run(P.ExecContext(xp, [b]))
+        merged = DMergePartial(keys, slots, partial, P.PScan(0, parts.schema)
+                               ).run(P.ExecContext(xp, [parts]))
+        return DFinalAggregate(keys, slots, partial,
+                               P.PScan(0, merged.schema)
+                               ).run(P.ExecContext(xp, [merged]))
+
+    want = _rows_of(run(np, batch))
+    got = _rows_of(jax.jit(lambda b: run(jnp, b))(batch.to_device()))
+    assert len(got) == len(want) and len(want) > 2
+    for g, w in zip(got, want):
+        assert len(g) == len(w)
+        for x, y in zip(g, w):
+            if isinstance(y, float):
+                assert x == pytest.approx(y, rel=1e-12, abs=1e-12)
+            else:
+                assert x == y

@@ -87,10 +87,17 @@ def test_agg_program_traffic(one_shard):
     flops_per_row = d["flops"] / N
     # measured (XLA:CPU, r5 2026-07-31): ratio 12.6, flops/row 67 —
     # bounds anchored at ~1.35x measured (VERDICT r4 item 9: a 2x HBM
-    # regression must fail off-hardware)
-    assert ratio <= 17.0, f"agg HBM traffic regressed: {ratio:.1f}x input"
+    # regression must fail off-hardware).  Re-read with the sort aggregate's
+    # runs, segmented scan and reads (PR 32): ratio 38.0, flops/row 200.3.
+    # XLA:CPU writes every ``[k, n]`` plane before its gather and reads it
+    # back word by word, counts the scan's loop body once with its padded
+    # carry, and prices a scatter-add at one pass, which is what it is NOT
+    # on the chip (68 ns an element; PERF.md, PR 32): the static count rose
+    # where the device time fell.  Re-anchored at ~1.35x; a materialized
+    # one-hot (64x more) still fails.
+    assert ratio <= 51.0, f"agg HBM traffic regressed: {ratio:.1f}x input"
     assert ratio >= 1.0, "inputs not read? cost model broke"
-    assert flops_per_row <= 95.0, \
+    assert flops_per_row <= 270.0, \
         f"agg flops regressed: {flops_per_row:.0f}/row"
 
 
@@ -127,9 +134,11 @@ def test_q3_program_traffic(one_shard):
     # these anchors go on bounding the general path, and the unique path
     # shows only as its predicate and the branch outputs.  Re-read with the
     # general path's slot map as one scatter and one running sum (PR 28):
-    # ratio 46.8, flops/row 222.5.  The anchors stay.
-    assert ratio <= 71.0, f"q3 HBM traffic regressed: {ratio:.1f}x fact"
-    assert flops_per_row <= 305.0, \
+    # ratio 46.8, flops/row 222.5.  The anchors stay.  Re-read with the sort
+    # aggregate's runs, scan and reads (PR 32; see test_agg_program_traffic):
+    # ratio 62.4, flops/row 341.5; re-anchored at ~1.35x.
+    assert ratio <= 84.0, f"q3 HBM traffic regressed: {ratio:.1f}x fact"
+    assert flops_per_row <= 461.0, \
         f"q3 flops regressed: {flops_per_row:.0f}/row"
 
 

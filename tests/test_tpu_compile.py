@@ -123,6 +123,67 @@ def test_sorted_grouped_aggregate_compiles(one_chip, on_tpu):
     assert "sort" in compiled.as_text()
 
 
+def _runs_batch(n):
+    """Two nullable int64 keys, an int64 and a float64 value: what the
+    aggregate cell's statement groups and sums."""
+    ones = np.ones(n, bool)
+    return ColumnBatch(
+        ["k", "j", "v", "f"],
+        [ColumnVector(np.zeros(n, np.int64), T.LongType(), ones, None),
+         ColumnVector(np.zeros(n, np.int64), T.LongType(), ones, None),
+         ColumnVector(np.zeros(n, np.int64), T.LongType(), None, None),
+         ColumnVector(np.zeros(n, np.float64), T.DoubleType(), ones, None)],
+        ones, n)
+
+
+@pytest.mark.parametrize("form,log_rows", [("whole", 20), ("whole", 22)] + [
+    ("branch", k) for k in range(10, 23)])
+def test_sorted_runs_scan_and_reads_compile(one_chip, on_tpu, monkeypatch,
+                                            form, log_rows):
+    """What the sort aggregate does after its argsort (``kernels.
+    sorted_runs``, ``reduce_runs``, ``run_keys``: one plane through ``perm``,
+    the int32 running sum in two levels, the scatter of start positions,
+    the segmented scan's ``while_loop``, the reads at the runs' ends)
+    compiles for a v5e: ``whole`` as ``_sorted_grouped_aggregate`` with its
+    sorts, and ``branch`` INSIDE a ``lax.cond`` branch, where the MXU
+    aggregate's fallback puts it and where XLA:TPU refuses an int64
+    ``cumsum`` at 9 of 16 lengths, at every power of two (the sorts are
+    left out of the branch cases: their compile is minutes, and a sort in a
+    branch is what ``test_fused_mxu_aggregate_step_compiles`` holds)."""
+    n = 1 << log_rows
+    keys = [Col("k"), Col("j")]
+    aggs = [(CountStar(), "c"), (Sum(Col("v")), "s"), (Sum(Col("f")), "t")]
+
+    def whole(batch):
+        return K._sorted_grouped_aggregate(jnp, batch, keys, aggs)
+
+    def branch(batch, fits):
+        def slow(_):
+            out = K._sorted_grouped_aggregate(jnp, batch, keys, aggs)
+            return tuple(v.data for v in out.vectors), out.row_valid
+
+        def fast(_):
+            return tuple(jnp.zeros(n, d) for d in (
+                np.int64, np.int64, np.int64, np.int64, np.float64)), \
+                jnp.zeros(n, bool)
+
+        return jax.lax.cond(fits, fast, slow, None)
+
+    if form == "whole":
+        compiled = jax.jit(whole).lower(
+            _spec(_runs_batch(n), one_chip)).compile()
+        assert "sort" in compiled.as_text()
+    else:
+        monkeypatch.setattr(
+            K, "multi_key_argsort",
+            lambda xp, cols, capacity: xp.arange(capacity, dtype=np.int32))
+        compiled = jax.jit(branch).lower(
+            _spec(_runs_batch(n), one_chip),
+            jax.ShapeDtypeStruct((), jnp.bool_, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert "while" in text and "scatter" in text
+
+
 def test_tpu_sort_chain_equals_lexsort(on_tpu):
     """On a TPU ``multi_key_argsort`` is a chain of single-key stable sorts
     (a variadic sort costs the TPU compiler minutes); the permutation must

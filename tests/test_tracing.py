@@ -334,9 +334,9 @@ def test_agg_lowering_note(streamed):
     tracing.reset()
     streamed.sql(QUERIES["q3"]).collect()
     notes = tracing.last_statement()["notes"]
-    assert notes["agg_lowering"] == ["sort"]
+    assert notes["agg_lowering"] == ["sort.scan"]
     streamed.sql(AGG_CUSTOMER_TOP100).collect()
-    assert tracing.last_statement()["notes"]["agg_lowering"] == ["sort"]
+    assert tracing.last_statement()["notes"]["agg_lowering"] == ["sort.scan"]
 
 
 # -- one clock ---------------------------------------------------------------
@@ -382,6 +382,49 @@ def test_cli_prints_the_join_paths(in_memory, tmp_path, capsys):
     assert tracing._main([str(tmp_path)]) == 0
     assert '1 sql:join.path  {"dense": 1, "out_cap": 64, "probe_cap": 64, ' \
         '"string": 0, "unique": 1}' in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("lane", ["local", "stages"])
+def test_scan_rounds_ride_out_as_an_operator_metric(lane, request, tmp_path,
+                                                    capsys):
+    """The rounds a sort aggregate's segmented scan took leave the program
+    as the operator metric ``agg.scan_rounds``: ``SQLExecutionEnd.metrics``
+    carries it on the local lane, the local and the ``stages`` lanes write
+    one ``agg.scan`` span for each aggregate of each step they fetch, and
+    ``python -m spark_tpu.tracing`` tallies them.  The rounds are what the
+    input asked for, ``ceil(log2(longest run))``: a handful where the
+    aggregate statement's groups hold a handful of rows, never more than
+    ``log2(capacity)``."""
+    if lane == "local":
+        spark, text, most = request.getfixturevalue("in_memory"), \
+            AGG_CUSTOMER_TOP100, 6
+    else:
+        spark, text, most = request.getfixturevalue("streamed"), \
+            QUERIES["q3"], 12                          # log2(BATCH)
+    events = []
+    spark.listenerManager.register(events.append)
+    try:
+        spark.sql(text).collect()                      # warm
+        tracing.reset()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            spark.sql(text).collect()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        spark.listenerManager.unregister(events.append)
+    scans = [s.attrs for s in tracing.spans() if s.name == "agg.scan"]
+    assert len(scans) >= (1 if lane == "local" else 3)    # one a batch
+    assert all(0 <= a["rounds"] <= most for a in scans)
+    if lane == "local":
+        end = [e for e in events if e["event"] == "SQLExecutionEnd"][-1]
+        rounds = [v for k, v in end["metrics"].items()
+                  if k.endswith(":agg.scan_rounds")]
+        assert rounds == [scans[-1]["rounds"]]
+    read = tracing.device_time_by_scope(tracing._xplanes(str(tmp_path))[-1])
+    assert sum(n for _attrs, n in read["agg_scans"]) == len(scans)
+    assert tracing._main([str(tmp_path)]) == 0
+    assert "sql:agg.scan  {" in capsys.readouterr().out
 
 
 def test_check_clock_cli(tmp_path):
