@@ -40,9 +40,10 @@ class DistributedPlanner(Planner):
     def __init__(self, session, n_shards: int,
                  skew_override: Optional[float] = None,
                  join_factor_override: Optional[float] = None,
-                 agg_shrink_override: Optional[int] = None):
+                 agg_shrink_override: Optional[int] = None, shared=None):
         super().__init__(session, join_factor_override,
-                         agg_shrink_override=agg_shrink_override)
+                         agg_shrink_override=agg_shrink_override,
+                         shared=shared)
         self.n_shards = n_shards
         self.skew_override = skew_override
 
@@ -244,12 +245,14 @@ class DistributedExecution:
         shrink = adapted.get("shrink")
         grew = False
         ex_ratio = join_ratio = 0.0
+        shared: dict = {}          # the statement's Shared results
         for attempt in range(self.MAX_ADAPT + 1):
             with tracing.replan(attempt, max(ex_ratio, join_ratio),
                                 {"skew": skew, "join": jf,
                                  "shrink": shrink}):
                 result, ex_ratio, join_ratio, shrink_need = self._run_once(
-                    optimized, skew, jf, shrink, check_caps=grew)
+                    optimized, skew, jf, shrink, check_caps=grew,
+                    shared=shared)
             if ex_ratio <= 0.0 and join_ratio <= 0.0 and shrink_need <= 0:
                 if skew is not None or jf is not None or shrink is not None:
                     self.session._adapted_factors[base_key] = {
@@ -286,12 +289,13 @@ class DistributedExecution:
 
     def _run_once(self, optimized: LogicalPlan, skew: Optional[float],
                   jf: Optional[float], shrink: Optional[int] = None,
-                  check_caps: bool = False
+                  check_caps: bool = False, shared=None
                   ) -> Tuple[ColumnBatch, float, float, int]:
         planner = DistributedPlanner(self.session, self.n,
                                      skew_override=skew,
                                      join_factor_override=jf,
-                                     agg_shrink_override=shrink)
+                                     agg_shrink_override=shrink,
+                                     shared=shared)
         with tracing.span("plan"):
             pq = planner.plan(optimized)
         if check_caps:

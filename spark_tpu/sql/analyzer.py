@@ -17,6 +17,8 @@ plus these structural rewrites:
 
 from __future__ import annotations
 
+import functools
+import itertools
 from typing import Dict, List, Sequence, Tuple
 
 from .. import types as T
@@ -159,6 +161,163 @@ def rewrite_distinct_aggregates(plan: Aggregate) -> LogicalPlan:
     return Aggregate(outer_keys, outer_slots, inner)
 
 
+def _rewrite_distinct(node: LogicalPlan) -> LogicalPlan:
+    return rewrite_distinct_aggregates(node) \
+        if isinstance(node, Aggregate) else node
+
+
+def _set_substitution(node, s_idx, aggregate=None):
+    """The rewrite of one grouping set's expressions: grouping() and
+    grouping_id() as the set's literals, an absent key as a typed NULL.  An
+    aggregate goes to ``aggregate``; where that is None it stays as it is,
+    since its ARGUMENTS see the child's rows (the reference's Expand nulls
+    the key copies, never the aggregate inputs: SUM(k) over ROLLUP(k)
+    totals k), and a present key is left to ``build_aggregate``.  With
+    ``aggregate`` a present key becomes its output column."""
+    from ..expressions import GroupingCall
+    child_schema = node.children[0].schema()
+    key_reprs = [repr(k) for k in node.keys]
+    present = set(s_idx)
+    # grouping_id bitmask: bit i set when key i is AGGREGATED away
+    gid = 0
+    for i in range(len(node.keys)):
+        if i not in present:
+            gid |= 1 << (len(node.keys) - 1 - i)
+
+    def subst(e: Expression) -> Expression:
+        if isinstance(e, GroupingCall):
+            if not e.children:
+                return Literal(gid)
+            r = repr(e.children[0])
+            if r not in key_reprs:
+                raise AnalysisException(
+                    f"grouping() argument {e.children[0]!r} is not "
+                    "a grouping key")
+            return Literal(0 if key_reprs.index(r) in present else 1)
+        if isinstance(e, AggregateFunction):
+            return e if aggregate is None else aggregate(e)
+        r = repr(e)
+        if r in key_reprs:
+            i = key_reprs.index(r)
+            if i not in present:
+                return Literal(None, node.keys[i].data_type(child_schema))
+            if aggregate is not None:
+                return Col(node.keys[i].name)
+        return e.map_children(subst)
+
+    return subst
+
+
+def _select_of_set(node, subst) -> List[Expression]:
+    """The select list of one grouping set, each item under its own name."""
+    sel = []
+    for e in node.select_list:
+        if isinstance(e, Alias):
+            sel.append(Alias(subst(e.children[0]), e.name))
+        else:
+            ne = subst(e)
+            sel.append(ne if ne.name == e.name else Alias(ne, e.name))
+    return sel
+
+
+def _grouping_sets_from_finest(node, ordinal: int):
+    """Grouping sets whose aggregates all decompose (SUM, COUNT, MIN, MAX,
+    AVG as a sum and a count; none DISTINCT) as ONE aggregation of the
+    child by the finest set, each coarser set re-aggregating the buffers of
+    the smallest listed set that contains it (SUM of sums, SUM of counts,
+    MIN of mins, MAX of maxes).  Where no listed set holds every key, an
+    aggregation by all of them is the base.  Each aggregation is a
+    ``Shared`` node: the lanes compute it once a statement, however many
+    arms read it.  None where the rewrite does not apply."""
+    from ..aggregates import Avg, Max, Min
+    from ..expressions import Coalesce, Div
+    from .logical import Filter as LFilter, Shared, Union as LUnion
+    from .window import contains_window
+    child = node.children[0]
+    cs = child.schema()
+    exprs = list(node.select_list) + (
+        [node.having] if node.having is not None else [])
+    names = [k.name for k in node.keys]
+    if any(contains_window(e) for e in exprs) or len(set(names)) < len(names):
+        return None
+    found: Dict[str, AggregateFunction] = {}
+
+    def collect(e: Expression) -> None:
+        if isinstance(e, AggregateFunction):
+            found.setdefault(repr(e), e)
+        for c in () if isinstance(e, AggregateFunction) else e.children:
+            collect(c)
+
+    for e in exprs:
+        collect(e)
+    merge = {Sum: Sum, Count: Sum, CountStar: Sum, Min: Min, Max: Max}
+    for f in found.values():
+        if type(f) not in merge and not (
+                type(f) is Avg and not isinstance(
+                    f.children[0].data_type(cs), T.DecimalType)):
+            return None
+    bufs: Dict[str, Tuple[AggregateFunction, str]] = {}
+
+    def buffer(fn: AggregateFunction) -> Col:
+        if repr(fn) not in bufs:
+            bufs[repr(fn)] = (fn, fresh_name("gs", repr(fn), len(bufs)))
+        return Col(bufs[repr(fn)][1])
+
+    residual: Dict[str, Expression] = {}
+    for r, f in found.items():
+        if type(f) is Avg:
+            x = f.children[0]
+            residual[r] = Div(buffer(Sum(x)), buffer(Count(x)))
+        elif type(f) in (Count, CountStar):
+            # a keyless set over no rows sums no counts: COUNT is 0 there
+            residual[r] = Coalesce(buffer(f), Literal(0, T.int64))
+        else:
+            residual[r] = buffer(f)
+    merges = [(merge[type(fn)](Col(n)), n) for fn, n in bufs.values()]
+    sets = [frozenset(s) for s in node.sets]
+    every = frozenset(i for s in sets for i in s)
+
+    def from_child(arm=None):
+        keys = [node.keys[i] for i in sorted(every)]
+        return Shared(Aggregate(keys, list(bufs.values()), child),
+                      f"gs{ordinal}.{'base' if arm is None else arm[0]}",
+                      arm)
+
+    made: Dict[int, LogicalPlan] = {}
+    base = None if every in sets else from_child()
+    for i in sorted(range(len(sets)), key=lambda i: (-len(sets[i]), i)):
+        arm_keys = tuple(names[k] for k in node.sets[i])
+        if base is None:
+            made[i] = base = from_child((i, arm_keys, False))
+            continue
+        finer = [j for j in made if sets[i] < sets[j]]
+        src = made[min(finer, key=lambda j: (len(sets[j]), j))] \
+            if finer else base
+        keys = [Col(names[k]) for k in sorted(sets[i])]
+        made[i] = Shared(Aggregate(keys, merges, src), f"gs{ordinal}.{i}",
+                         (i, arm_keys, True))
+    outs = []
+    for i, s_idx in enumerate(node.sets):
+        subst = _set_substitution(node, s_idx, lambda f: residual[repr(f)])
+        sel = _select_of_set(node, subst)
+        src = made[i]
+        if node.having is not None:
+            # HAVING reads the keys, the aggregates and the select list's
+            # names: a name resolves to its item's expression
+            visible = set(src.schema().names)
+            defined = {e.name: e.children[0] for e in sel
+                       if isinstance(e, Alias)}
+
+            def item(e, visible=visible, defined=defined):
+                if isinstance(e, Col) and e.name not in visible \
+                        and e.name in defined:
+                    return defined[e.name]
+                return e.map_children(item)
+            src = LFilter(item(subst(node.having)), src)
+        outs.append(Project(sel, src))
+    return outs[0] if len(outs) == 1 else LUnion(outs)
+
+
 class _JoinSideRename(Project):
     """Marker Project inserted by join disambiguation: renames overlapping
     columns to their qualified names while passing other qualifiers through."""
@@ -212,7 +371,8 @@ class Analyzer:
         plan = plan.transform_up(self._replace_set_ops)
         plan = plan.transform_up(self._rewrite_node)
         plan = plan.transform_up(self._rewrite_explode)
-        plan = plan.transform_up(self._rewrite_grouping_sets)
+        plan = plan.transform_up(functools.partial(
+            self._rewrite_grouping_sets, ordinal=itertools.count()))
         plan = plan.transform_up(self._rewrite_sliding_window)
         self._validate(plan)
         return plan
@@ -269,59 +429,27 @@ class Analyzer:
         return Aggregate(new_keys, node.aggs, expansion)
 
     @staticmethod
-    def _rewrite_grouping_sets(node: LogicalPlan) -> LogicalPlan:
-        """GroupingSets → UNION ALL of one Aggregate per grouping set:
-        absent keys project as typed NULLs, grouping()/grouping_id() calls
-        become per-branch literals (Expand-free ROLLUP/CUBE)."""
-        from ..expressions import Cast, GroupingCall, Literal
+    def _rewrite_grouping_sets(node: LogicalPlan, ordinal) -> LogicalPlan:
+        """GroupingSets → UNION ALL of one arm per grouping set: absent keys
+        project as typed NULLs, grouping()/grouping_id() calls become
+        per-arm literals (Expand-free ROLLUP/CUBE).  Where every aggregate
+        decomposes, the arms share one aggregation of the child
+        (``_grouping_sets_from_finest``); else each arm aggregates the
+        child by itself.  ``ordinal`` counts the statement's rewrites."""
         from .logical import GroupingSets, Filter as LFilter, Union as LUnion
         if not isinstance(node, GroupingSets):
             return node
-        child_schema = node.children[0].schema()
-        key_reprs = [repr(k) for k in node.keys]
-        key_dts = [k.data_type(child_schema) for k in node.keys]
+        shared = _grouping_sets_from_finest(node, next(ordinal))
+        if shared is not None:
+            return shared
         branches = []
         for s_idx in node.sets:
-            present = set(s_idx)
-            # grouping_id bitmask: bit i set when key i is AGGREGATED away
-            gid = 0
-            for i in range(len(node.keys)):
-                if i not in present:
-                    gid |= 1 << (len(node.keys) - 1 - i)
-
-            def subst(e: Expression) -> Expression:
-                if isinstance(e, GroupingCall):
-                    if not e.children:
-                        return Literal(gid)
-                    r = repr(e.children[0])
-                    if r not in key_reprs:
-                        raise AnalysisException(
-                            f"grouping() argument {e.children[0]!r} is not "
-                            "a grouping key")
-                    return Literal(
-                        0 if key_reprs.index(r) in present else 1)
-                if isinstance(e, AggregateFunction):
-                    # aggregate ARGUMENTS see the original child rows —
-                    # only grouping OUTPUT columns become NULL (the
-                    # reference's Expand nulls the key copies, never the
-                    # aggregate inputs): SUM(k) over ROLLUP(k) totals k
-                    return e
-                r = repr(e)
-                if r in key_reprs and key_reprs.index(r) not in present:
-                    i = key_reprs.index(r)
-                    return Literal(None, key_dts[i])
-                return e.map_children(subst)
-
-            sel = []
-            for e in node.select_list:
-                if isinstance(e, Alias):
-                    sel.append(Alias(subst(e.children[0]), e.name))
-                else:
-                    ne = subst(e)
-                    sel.append(ne if ne.name == e.name
-                               else Alias(ne, e.name))
+            subst = _set_substitution(node, s_idx)
+            sel = _select_of_set(node, subst)
             keys_subset = [node.keys[i] for i in s_idx]
-            branch = build_aggregate(keys_subset, sel, node.children[0])
+            # the analyzer's distinct rewrite ran before this rule
+            branch = build_aggregate(keys_subset, sel, node.children[0]) \
+                .transform_up(_rewrite_distinct)
             # the aggregate also outputs its keys; keep ONLY the select list
             want = [e.name for e in node.select_list]
             if branch.schema().names != want:
@@ -335,7 +463,8 @@ class Analyzer:
                     # extra slots, filter, then project the select list
                     sel_h = sel + [Alias(f, n) for f, n in slots]
                     b2 = build_aggregate(keys_subset, sel_h,
-                                         node.children[0])
+                                         node.children[0]) \
+                        .transform_up(_rewrite_distinct)
                     branch = Project([Col(n) for n in want],
                                      LFilter(resid, b2))
                 else:

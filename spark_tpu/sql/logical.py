@@ -21,7 +21,7 @@ __all__ = [
     "LogicalPlan", "LocalRelation", "RangeRelation", "Project", "Filter",
     "Aggregate", "Sort", "SortOrder", "Limit", "Join", "Union", "Distinct",
     "SubqueryAlias", "cte_copies", "UnresolvedRelation", "FileRelation",
-    "Sample",
+    "Sample", "Shared",
 ]
 
 
@@ -668,6 +668,35 @@ class LazyCheckpoint(LogicalPlan):
 
     def __repr__(self):
         return f"LazyCheckpoint[{self.path}]"
+
+
+class Shared(LogicalPlan):
+    """A subplan that several parents read: each lane computes it ONCE a
+    statement and hands every parent the same materialized rows
+    (``stages.materialize_shared``).  Rewrites copy a plan's nodes, so the
+    parents hold equal copies; what makes two copies one computation is
+    ``tag`` (unique in the statement, the same in every statement of that
+    shape) with the copy's structure (``plan_cache_key``).
+
+    ``arm``: (set number, key names, from_finer) where the subplan is the
+    aggregate of one grouping set of a ROLLUP/CUBE/GROUPING SETS (the
+    ``grouping.arm`` span); None otherwise."""
+
+    def __init__(self, child: LogicalPlan, tag: str,
+                 arm: Optional[Tuple[int, Tuple[str, ...], bool]] = None):
+        self.tag = tag
+        self.arm = arm
+        self.children = (child,)
+
+    @property
+    def child(self):
+        return self.children[0]
+
+    def schema(self) -> T.StructType:
+        return self.children[0].schema()
+
+    def __repr__(self):
+        return f"Shared {self.tag}"
 
 
 class GroupingSets(LogicalPlan):

@@ -26,8 +26,8 @@ from ..expressions import AnalysisException
 from ..kernels import compact
 from .logical import (
     Aggregate, Distinct, FileRelation, Filter, Join, Limit, LocalRelation,
-    LogicalPlan, Project, RangeRelation, Sample, Sort, SubqueryAlias, Union,
-    cte_copies,
+    LogicalPlan, Project, RangeRelation, Sample, Shared, Sort, SubqueryAlias,
+    Union, cte_copies,
 )
 from . import physical as P
 
@@ -247,6 +247,36 @@ def _needs_local_fallback(plan: LogicalPlan) -> bool:
     return bool(found)
 
 
+def _refuse_eager_fallback(session, exc: Exception) -> None:
+    """Under ``spark.tpu.stages.enabled=required`` a plan the stage runner
+    cannot stream fails here instead of loading its oversized relation
+    onto the device whole."""
+    from .stages import NotStreamable, stages_mode
+    if stages_mode(session) == "required":
+        raise NotStreamable(f"{exc}; spark.tpu.stages.enabled=required "
+                            "refuses the eager fallback") from exc
+
+
+def _record_windows(plan: LogicalPlan) -> None:
+    """One zero-length ``window`` span for each window a one-device program
+    computes: its functions, partition and order keys and, where it reads
+    a materialized batch (the stage runner's window over an aggregate or a
+    union of them), that batch's rows."""
+    from .window import WindowNode
+    if isinstance(plan, WindowNode):
+        spec = plan.wexprs[0][0].spec
+        child = plan.children[0]
+        rows = int(np.asarray(child.batch.num_rows())) \
+            if isinstance(child, LocalRelation) else None
+        with tracing.span(
+                "window", funcs=[repr(we.func) for we, _n in plan.wexprs],
+                partition_keys=[repr(e) for e in spec.partition_by],
+                order_keys=[repr(o) for o in spec.order_by], rows=rows):
+            pass
+    for c in plan.children:
+        _record_windows(c)
+
+
 class PlannedQuery:
     def __init__(self, physical: P.PhysicalPlan, leaves: List[ColumnBatch],
                  leaf_recipes=None):
@@ -267,7 +297,7 @@ class Planner:
 
     def __init__(self, session, join_factor_override=None,
                  for_execution: bool = True, agg_shrink_override=None,
-                 shrink_aggs: bool = True):
+                 shrink_aggs: bool = True, shared=None):
         #: None | float (every join) | list (per join construction index —
         #: chained joins must not COMPOUND one overflowing join's growth)
         self.session = session
@@ -282,6 +312,11 @@ class Planner:
         #: False for explain/inspection: planning must not run side
         #: effects (lazy-checkpoint materialization)
         self.for_execution = for_execution
+        #: the statement's ``Shared`` results where it runs the plan:
+        #: each ``Shared`` node is computed once and enters the plan as an
+        #: opaque leaf (``stages.materialize_shared``); without it a
+        #: ``Shared`` node is planned in place, once for each parent
+        self.shared = shared
         self._join_seq = 0
         self._leaf_recipes: list = []
 
@@ -354,7 +389,16 @@ class Planner:
             from ..io import read_file_relation
             batch = read_file_relation(node, self.session)
             return self._scan(batch, leaves, source=node)
-        if isinstance(node, SubqueryAlias):
+        if isinstance(node, Shared) and self.shared is not None \
+                and self.for_execution:
+            # computed once a statement, its rows a leaf of no recipe: the
+            # plan cache keeps no rows one statement computed
+            from .stages import _eager, materialize_shared
+            return self._scan(materialize_shared(
+                self.session, node, self.shared,
+                lambda child: _eager(self.session, child, "stage.step")),
+                leaves)
+        if isinstance(node, (SubqueryAlias, Shared)):
             return self._to_physical(node.child, leaves)
         from .logical import FlatMapGroupsWithState
         if isinstance(node, FlatMapGroupsWithState):
@@ -453,12 +497,17 @@ class QueryExecution:
         #: the scope of what the stage program does outside any operator:
         #: ``stage.merge`` where the stage runner materializes a sub-plan
         self._stage_scope = "stage.step"
+        #: the keyed aggregates' first output capacity where the caller
+        #: knows a bound on their groups (None: spark.sql.agg.outputCapacity)
+        self._agg_rows: Optional[int] = None
         #: per-operator metrics of the last execution:
         #: {(op_id, operator label): output row count}
         self.metrics: Dict[Tuple[int, str], int] = {}
         #: the worst overflow ratio of the last attempt (``read_flags``)
         self._last_ratio = 0.0
         self._fingerprint = False        # not worked out yet (None: no key)
+        #: the statement's ``Shared`` results (``Planner.shared``)
+        self._shared: Dict = {}
 
     @property
     def analyzed(self) -> LogicalPlan:
@@ -503,7 +552,8 @@ class QueryExecution:
         if self._planned is None:
             optimized = self.optimized
             with tracing.span("plan"):
-                self._planned = Planner(self.session).plan(optimized)
+                self._planned = Planner(self.session,
+                                        shared=self._shared).plan(optimized)
         return self._planned
 
     # ------------------------------------------------------------------
@@ -654,6 +704,7 @@ class QueryExecution:
                 try:
                     return self._staged("stages", st.execute)
                 except NotStreamable as e:
+                    _refuse_eager_fallback(self.session, e)
                     _log.info("stage runner fallback to distributed "
                               "eager: %s", e)
             return self._staged(
@@ -678,8 +729,10 @@ class QueryExecution:
             try:
                 return self._staged("stages", st.execute)
             except NotStreamable as e:
+                _refuse_eager_fallback(self.session, e)
                 _log.info("stage runner fallback to eager: %s", e)
 
+        _record_windows(self.optimized)
         # serving plan cache (spark_tpu.serving.plancache): attached to
         # server sessions, shared across all of them, asked at a
         # statement's root.  A usable entry skips planning and runs its
@@ -700,7 +753,8 @@ class QueryExecution:
         # and executes once
         base_key = self._capacity_key()
         adapted = self.session._adapted_factors.get(base_key) or {}
-        factors, shrink = adapted.get("join"), adapted.get("shrink")
+        factors = adapted.get("join")
+        shrink = adapted.get("shrink", self._agg_rows)
         grew = False
         ratio = self._last_ratio if not adapted else 0.0
         for attempt in range(self.MAX_ADAPT + 1):
@@ -784,7 +838,8 @@ class QueryExecution:
         else:
             with tracing.span("plan"):
                 pq = Planner(self.session, join_factor_override=factors,
-                             agg_shrink_override=shrink).plan(self.optimized)
+                             agg_shrink_override=shrink,
+                             shared=self._shared).plan(self.optimized)
         if grew:
             # exact per-join allocation guard (replaces the old factor x
             # max-leaf estimate, which mis-blamed small joins in plans

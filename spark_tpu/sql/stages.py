@@ -66,11 +66,28 @@ GRACE_MAX_BUCKETS = C.conf("spark.tpu.join.graceMaxBuckets").doc(
     "hash, then fall back to a chunked probe/build loop."
 ).int(1024)
 
+_STAGES_MODES = {"true": "true", "1": "true", "yes": "true",
+                 "false": "false", "0": "false", "no": "false",
+                 "required": "required"}
+
+
+def _stages_mode(value) -> Optional[str]:
+    return _STAGES_MODES.get(str(value).strip().lower())
+
+
 STAGES_ENABLED = C.conf("spark.tpu.stages.enabled").doc(
     "Run multi-relation plans over oversized file relations through the "
     "streamed stage DAG (grace joins + broadcast-fused streams) instead "
-    "of one eager device batch."
-).boolean(True)
+    "of one eager device batch.  ``true``: a plan the DAG cannot stream "
+    "falls back to one eager program over the whole relation; ``false``: "
+    "never stream; ``required``: such a plan fails with NotStreamable, so "
+    "no statement loads an oversized relation onto the device whole."
+).check(lambda v: _stages_mode(v) is not None).string("true")
+
+
+def stages_mode(session) -> str:
+    """``true``, ``false`` or ``required`` (``spark.tpu.stages.enabled``)."""
+    return _stages_mode(session.conf.get(STAGES_ENABLED))
 
 #: recursion depth for salted re-partitioning of skewed grace buckets
 _MAX_SALT_DEPTH = 3
@@ -118,9 +135,36 @@ def _concat_live(batches: List[ColumnBatch]) -> Optional[ColumnBatch]:
     return lives[0] if len(lives) == 1 else union_all(lives)
 
 
+def _concat_arms(session, arms: List[ColumnBatch],
+                 schema: T.StructType) -> ColumnBatch:
+    """UNION ALL of materialized arms as one host batch: concatenated on
+    the host where every arm has the union's column types, else by one
+    eager program (which casts)."""
+    types = [f.dataType for f in schema.fields]
+    if any([v.dtype for v in a.vectors] != types for a in arms):
+        return _eager(session, L.Union([L.LocalRelation(a) for a in arms]))
+    lives = [_live(compact(np, a)) for a in arms]
+    lives = [ColumnBatch(list(schema.names), b.vectors, None, b.capacity)
+             for b in lives if b.capacity > 0]
+    if not lives:
+        return ColumnBatch.empty(schema)
+    return lives[0] if len(lives) == 1 else union_all(lives)
+
+
 def _padded(batch: ColumnBatch) -> ColumnBatch:
     return normalize_valids(
         pad_to_capacity(batch, pad_capacity(max(batch.capacity, 1))))
+
+
+def _bucketed(batch: ColumnBatch) -> ColumnBatch:
+    """A materialized batch padded to the next multiple of a sixteenth of
+    its next power of two (at most an eighth more rows): a stage is keyed
+    by its leaves' capacities, so one compiled program then serves every
+    row count of the bucket, where the exact count (which follows the
+    data) would compile anew."""
+    step = max(pad_capacity(max(batch.capacity, 1)) // 16, 1)
+    return normalize_valids(
+        pad_to_capacity(batch, -(-batch.capacity // step) * step))
 
 
 def _empty_side(schema: T.StructType, dicts: Dict[str, tuple]) -> ColumnBatch:
@@ -143,17 +187,61 @@ def _empty_side(schema: T.StructType, dicts: Dict[str, tuple]) -> ColumnBatch:
                        np.zeros(cap, bool), cap)
 
 
-def _eager(session, plan: L.LogicalPlan) -> ColumnBatch:
+def _eager(session, plan: L.LogicalPlan, scope: str = "stage.merge",
+           agg_rows: Optional[int] = None) -> ColumnBatch:
     """Execute an already-analyzed/optimized sub-plan through the eager
-    single-batch executor (jit + adaptive capacity retry + HBM reserve).
-    Sub-plans handed here never contain oversized file relations, so the
-    nested execution cannot recurse back into the stage runner."""
+    single-batch executor (jit + adaptive capacity retry + HBM reserve),
+    its program under the device scope ``scope``; ``agg_rows`` bounds the
+    groups of its keyed aggregates where the caller knows one, so their
+    first output capacity holds them (no overflow, no second compile).
+    Sub-plans the stage runner hands here never contain oversized file
+    relations, so the nested execution cannot recurse back into the stage
+    runner."""
     from .planner import QueryExecution
     qe = QueryExecution(session, plan)
     qe._analyzed = plan
     qe._optimized = plan
-    qe._stage_scope = "stage.merge"
+    qe._stage_scope = scope
+    qe._agg_rows = agg_rows
     return qe._execute_inner()
+
+
+#: the device scope of a grouping set re-aggregated from a finer one
+ROLLUP_SCOPE = "grouping.rollup"
+
+
+def materialize_shared(session, node: L.Shared, memo: Dict,
+                       run) -> ColumnBatch:
+    """The rows of a ``Shared`` node, computed ONCE a statement: its child,
+    with every ``Shared`` below it replaced by that node's rows, is run by
+    ``run`` (the lane's own execution of a sub-plan) -- or, for a grouping
+    set re-aggregated from a finer one, as one program over the finer
+    set's rows under the device scope ``grouping.rollup``.  ``memo`` is the
+    statement's: (tag, structure) -> host batch.  Each grouping set
+    computed records one zero-length ``grouping.arm`` span."""
+    child = node.child.transform_up(
+        lambda n: L.LocalRelation(materialize_shared(session, n, memo, run))
+        if isinstance(n, L.Shared) else n)
+    key = (node.tag, L.plan_cache_key(child))
+    out = memo.get(key)
+    if out is not None:
+        return out
+    from_finer = node.arm is not None and node.arm[2]
+    # the rows the set's aggregate read, where the lane holds them: a finer
+    # set's groups, which bound the coarser set's (the statement's own
+    # rows stream or stay inside one program)
+    finer = getattr(child.children[0], "batch", None) if from_finer \
+        else None
+    rows_in = None if finer is None else int(np.asarray(finer.num_rows()))
+    out = memo[key] = _eager(session, child, ROLLUP_SCOPE, rows_in) \
+        if from_finer else run(child)
+    if node.arm is not None:
+        with tracing.span(
+                "grouping.arm", set=node.arm[0], keys=list(node.arm[1]),
+                from_finer=from_finer, rows_in=rows_in,
+                rows_out=int(np.asarray(out.num_rows()))):
+            pass
+    return out
 
 
 def _batch_dicts(batch: ColumnBatch) -> Dict[str, tuple]:
@@ -1293,6 +1381,8 @@ class _Builder:
         self.session = session
         self.batch_rows = batch_rows
         self.mesh = mesh
+        #: the statement's ``Shared`` results (``materialize_shared``)
+        self._shared: Dict = {}
 
     # .. helpers ..........................................................
     def _oversized(self, node: L.LogicalPlan) -> bool:
@@ -1316,6 +1406,10 @@ class _Builder:
     # .. build ............................................................
     def build(self, node: L.LogicalPlan):
         """Returns a materialized host ColumnBatch or a BatchStream."""
+        if isinstance(node, L.Shared):
+            return materialize_shared(
+                self.session, node, self._shared,
+                lambda child: self._materialize(self.build(child)))
         if not self._oversized(node):
             return _eager(self.session, node)
         if isinstance(node, L.SubqueryAlias):
@@ -1352,10 +1446,23 @@ class _Builder:
             return self._join(node)
         if isinstance(node, L.Union):
             kids = [self.build(c) for c in node.children]
+            if all(isinstance(k, ColumnBatch) for k in kids):
+                # every arm materialized (a breaker each): one batch
+                return _concat_arms(self.session, kids, node.schema())
             streams = [k if isinstance(k, BatchStream)
                        else _SingletonStream(k, self.batch_rows)
                        for k in kids]
             return _UnionStream(self.session, streams, node.schema())
+        from .window import WindowNode
+        if isinstance(node, WindowNode):
+            # a window over a materialized input (an aggregate, a union of
+            # aggregates) runs eagerly over it; over rows still streaming
+            # it needs every row of a partition at once
+            self._det(node)
+            src = self.build(node.children[0])
+            if isinstance(src, ColumnBatch):
+                return _eager(self.session,
+                              _rebase(node, L.LocalRelation(_bucketed(src))))
         raise NotStreamable(f"{type(node).__name__} over an oversized "
                             "file relation is not streamable")
 
@@ -1496,7 +1603,7 @@ def plan_stages(session, optimized: L.LogicalPlan, mesh=None
     Linear single-relation chains stay on ``plan_multibatch`` (tried
     first); non-streamable shapes raise ``NotStreamable`` from
     ``execute()`` and the caller falls back to the eager path."""
-    if not session.conf.get(STAGES_ENABLED) \
+    if stages_mode(session) == "false" \
             or not session.conf.get(C.MULTIBATCH_ENABLED):
         return None
     batch_rows = session.conf.get(C.SCAN_MAX_BATCH_ROWS)

@@ -25,7 +25,10 @@ from .. import types as T
 from ..aggregates import AggregateFunction, Avg, Count, CountStar, Max, Min, Sum
 from ..columnar import ColumnBatch, ColumnVector
 from ..expressions import AnalysisException, Col, EvalContext, Expression
-from ..kernels import multi_key_argsort, sort_key_transform
+from .. import tracing
+from ..kernels import (
+    multi_key_argsort, running_max_i32, running_sum_i32, sort_key_transform,
+)
 from .logical import LogicalPlan, SortOrder
 
 __all__ = [
@@ -254,109 +257,109 @@ class WindowNode(LogicalPlan):
 # the kernel
 # ---------------------------------------------------------------------------
 
-def _cummax(xp, a):
-    if xp is np:
-        return np.maximum.accumulate(a)
-    import jax
-    return jax.lax.cummax(a)
-
-
-def _cummin(xp, a):
-    if xp is np:
-        return np.minimum.accumulate(a)
-    import jax
-    return jax.lax.cummin(a)
-
-
 def _next_flag_idx(xp, flags, idx, cap):
-    """For each row: smallest j >= i with flags[j] (reverse cummin scan)."""
-    marked = xp.where(flags, idx, np.int64(cap))
-    return _cummin(xp, marked[::-1])[::-1]
+    """For each row: smallest j >= i with flags[j] (a running maximum of the
+    negated positions, from the end)."""
+    marked = xp.where(flags, -idx, np.int32(-cap))
+    return -running_max_i32(xp, marked[::-1])[::-1]
 
 
 def _segment_scan_base(xp, values, is_start):
     """For each row (sorted space): value at its segment's start row."""
     n = values.shape[0]
-    idx = xp.arange(n, dtype=np.int64)
-    start_idx = _cummax(xp, xp.where(is_start, idx, np.int64(0)))
+    idx = xp.arange(n, dtype=np.int32)
+    start_idx = running_max_i32(xp, xp.where(is_start, idx, 0))
     return values[start_idx], start_idx
 
 
 def compute_windows(xp, batch: ColumnBatch,
                     spec: WindowSpec,
                     funcs: Sequence[Tuple[Any, str]]) -> ColumnBatch:
-    """Append window columns (same capacity, original row order)."""
+    """Append window columns (same capacity, original row order).
+
+    Positions (the permutation, segment and peer-group bounds, ranks) are
+    int32 (a capacity is under 2^31): XLA:TPU's int64 scans are emulated
+    in pairs of words, and its compiler refuses an int64 cumsum in some
+    places (PERF.md section 7).  The device scopes ``window.sort``,
+    ``window.segments``, ``window.rank`` and ``window.agg`` name the
+    phases."""
     ctx = EvalContext(batch, xp)
     cap = batch.capacity
     live = xp.broadcast_to(batch.row_valid_or_true(), (cap,))
     schema = batch.schema
 
     # ---- sort by (dead-last, partition keys, order keys) ----------------
-    sort_cols: List[Any] = [(~live).astype(np.int8)]
-    part_vals = [ctx.broadcast(e.eval(ctx)) for e in spec.partition_by]
-    for e, v in zip(spec.partition_by, part_vals):
-        dt = e.data_type(schema)
-        sort_cols += sort_key_transform(xp, v.data, v.valid, dt, True, True)
-    for o in spec.order_by:
-        v = ctx.broadcast(o.child.eval(ctx))
-        dt = o.child.data_type(schema)
-        sort_cols += sort_key_transform(xp, v.data, v.valid, dt,
-                                        o.ascending, o.nulls_first)
-    perm = multi_key_argsort(xp, sort_cols, cap)
-    inv = _invert_perm(xp, perm, cap)
-    live_s = live[perm]
-    idx = xp.arange(cap, dtype=np.int64)
+    with tracing.scope("window.sort"):
+        sort_cols: List[Any] = [(~live).astype(np.int8)]
+        part_vals = [ctx.broadcast(e.eval(ctx)) for e in spec.partition_by]
+        for e, v in zip(spec.partition_by, part_vals):
+            dt = e.data_type(schema)
+            sort_cols += sort_key_transform(xp, v.data, v.valid, dt, True,
+                                            True)
+        for o in spec.order_by:
+            v = ctx.broadcast(o.child.eval(ctx))
+            dt = o.child.data_type(schema)
+            sort_cols += sort_key_transform(xp, v.data, v.valid, dt,
+                                            o.ascending, o.nulls_first)
+        perm = multi_key_argsort(xp, sort_cols, cap).astype(np.int32)
+        inv = _invert_perm(xp, perm, cap)
+        live_s = live[perm]
+        idx = xp.arange(cap, dtype=np.int32)
 
-    # ---- segment starts (partition boundaries) in sorted space ----------
-    n_part_cols = 1 + 2 * len(spec.partition_by)
-    part_sorted = [c[perm] for c in sort_cols[:n_part_cols]]
-    is_start = xp.zeros(cap, bool)
-    for c in part_sorted:
-        shifted = xp.concatenate([c[:1], c[:-1]])
-        is_start = is_start | (c != shifted)
-    is_start = _set0_true(xp, is_start)
-
-    seg_start_idx = _cummax(xp, xp.where(is_start, idx, np.int64(0)))
-    pos = idx - seg_start_idx                       # 0-based row in partition
-
-    # seg_end_idx[i] = index of last row of i's segment (reverse scan to the
-    # nearest following boundary)
-    next_start = xp.concatenate([is_start[1:], xp.ones(1, bool)])
-    seg_end_idx = _next_flag_idx(xp, next_start, idx, cap)
-    seg_len = seg_end_idx - seg_start_idx + 1
-
-    # ---- order-key value groups (peers) ---------------------------------
-    order_sorted = [c[perm] for c in sort_cols[n_part_cols:]]
-    if order_sorted:
-        vg_change = is_start
-        for c in order_sorted:
+    with tracing.scope("window.segments"):
+        # ---- segment starts (partition boundaries) in sorted space ------
+        n_part_cols = 1 + 2 * len(spec.partition_by)
+        part_sorted = [c[perm] for c in sort_cols[:n_part_cols]]
+        is_start = xp.zeros(cap, bool)
+        for c in part_sorted:
             shifted = xp.concatenate([c[:1], c[:-1]])
-            vg_change = vg_change | (c != shifted)
-        vg_change = _set0_true(xp, vg_change)
-        vg_start_idx = _cummax(xp, xp.where(vg_change, idx, np.int64(0)))
-        next_vg = xp.concatenate([vg_change[1:], xp.ones(1, bool)])
-        vg_end_idx = _next_flag_idx(xp, next_vg, idx, cap)
-    else:
-        vg_change = is_start
-        vg_start_idx, vg_end_idx = seg_start_idx, seg_end_idx
+            is_start = is_start | (c != shifted)
+        is_start = _set0_true(xp, is_start)
+
+        seg_start_idx = running_max_i32(xp, xp.where(is_start, idx, 0))
+        pos = idx - seg_start_idx                   # 0-based row in partition
+
+        # seg_end_idx[i] = index of last row of i's segment (reverse scan
+        # to the nearest following boundary)
+        next_start = xp.concatenate([is_start[1:], xp.ones(1, bool)])
+        seg_end_idx = _next_flag_idx(xp, next_start, idx, cap)
+        seg_len = seg_end_idx - seg_start_idx + 1
+
+        # ---- order-key value groups (peers) -----------------------------
+        order_sorted = [c[perm] for c in sort_cols[n_part_cols:]]
+        if order_sorted:
+            vg_change = is_start
+            for c in order_sorted:
+                shifted = xp.concatenate([c[:1], c[:-1]])
+                vg_change = vg_change | (c != shifted)
+            vg_change = _set0_true(xp, vg_change)
+            vg_start_idx = running_max_i32(
+                xp, xp.where(vg_change, idx, 0))
+            next_vg = xp.concatenate([vg_change[1:], xp.ones(1, bool)])
+            vg_end_idx = _next_flag_idx(xp, next_vg, idx, cap)
+        else:
+            vg_change = is_start
+            vg_start_idx, vg_end_idx = seg_start_idx, seg_end_idx
 
     names = list(batch.names)
     vectors = list(batch.vectors)
 
     for func, out_name in funcs:
         if isinstance(func, WindowFunction):
-            data_s, valid_s, dt = _rank_family(
-                xp, func, ctx, perm, pos, seg_len, seg_start_idx, seg_end_idx,
-                vg_change, vg_start_idx, vg_end_idx, idx, live_s, schema, cap)
+            with tracing.scope("window.rank"):
+                data_s, valid_s, dt = _rank_family(
+                    xp, func, ctx, perm, pos, seg_len, seg_start_idx,
+                    seg_end_idx, vg_change, vg_start_idx, vg_end_idx, idx,
+                    live_s, schema, cap)
+                data, valid = _unsorted(data_s, valid_s, inv, live)
         elif isinstance(func, AggregateFunction):
-            data_s, valid_s, dt = _window_aggregate(
-                xp, func, ctx, spec, perm, pos, seg_start_idx, seg_end_idx,
-                vg_end_idx, idx, live_s, schema, cap)
+            with tracing.scope("window.agg"):
+                data_s, valid_s, dt = _window_aggregate(
+                    xp, func, ctx, spec, perm, pos, seg_start_idx,
+                    seg_end_idx, vg_end_idx, idx, live_s, schema, cap)
+                data, valid = _unsorted(data_s, valid_s, inv, live)
         else:
             raise AnalysisException(f"not a window function: {func!r}")
-        data = data_s[inv]
-        valid = None if valid_s is None else valid_s[inv]
-        valid = valid if valid is not None else live
         names.append(out_name)
         dictionary = None
         if isinstance(func, (Lag, Lead)) or (isinstance(func, (Min, Max))
@@ -370,6 +373,12 @@ def compute_windows(xp, batch: ColumnBatch,
     return ColumnBatch(names, vectors, batch.row_valid, cap)
 
 
+def _unsorted(data_s, valid_s, inv, live):
+    """A sorted-space result back in the rows' own order."""
+    data = data_s[inv]
+    return data, live if valid_s is None else valid_s[inv]
+
+
 def _set0_true(xp, arr):
     if xp is np:
         out = arr.copy()
@@ -379,14 +388,12 @@ def _set0_true(xp, arr):
 
 
 def _invert_perm(xp, perm, cap):
-    idx = xp.arange(cap, dtype=perm.dtype if hasattr(perm, "dtype")
-                    else np.int64)
+    idx = xp.arange(cap, dtype=np.int32)
     if xp is np:
-        inv = np.empty(cap, np.int64)
-        inv[perm] = np.arange(cap, dtype=np.int64)
+        inv = np.empty(cap, np.int32)
+        inv[perm] = idx
         return inv
-    inv = xp.zeros(cap, np.int64)
-    return inv.at[perm].set(idx.astype(np.int64))
+    return xp.zeros(cap, np.int32).at[perm].set(idx)
 
 
 def _rank_family(xp, func, ctx, perm, pos, seg_len, seg_start_idx,
@@ -397,7 +404,7 @@ def _rank_family(xp, func, ctx, perm, pos, seg_len, seg_start_idx,
     if isinstance(func, Rank):
         return vg_start_idx - seg_start_idx + 1, live_s, T.int64
     if isinstance(func, DenseRank):
-        cs = xp.cumsum(vg_change.astype(np.int64))
+        cs = running_sum_i32(xp, vg_change)
         base, _ = _segment_scan_base(xp, cs, _first_flag(xp, seg_start_idx,
                                                          idx))
         return cs - base + 1, live_s, T.int64
@@ -411,7 +418,7 @@ def _rank_family(xp, func, ctx, perm, pos, seg_len, seg_start_idx,
         return (covered.astype(np.float64)
                 / seg_len.astype(np.float64)), live_s, T.float64
     if isinstance(func, NTile):
-        n = np.int64(func.n)
+        n = np.int32(func.n)
         # Spark: first `rem` buckets get (len/n)+1 rows
         base = seg_len // n
         rem = seg_len % n
@@ -506,7 +513,7 @@ def _window_aggregate(xp, func, ctx, spec, perm, pos, seg_start_idx,
         else:
             raise AnalysisException(
                 f"unsupported window aggregate {func!r}")
-    cnt_buf = valid_in.astype(np.int64)
+    cnt_buf = valid_in
 
     has_order = bool(spec.order_by)
     frame = spec.frame
@@ -516,7 +523,7 @@ def _window_aggregate(xp, func, ctx, spec, perm, pos, seg_start_idx,
 
     if kind in ("sum",) or isinstance(func, (Sum, Avg, Count, CountStar)):
         cs = prefix(buf)
-        ccnt = prefix(cnt_buf)
+        ccnt = running_sum_i32(xp, cnt_buf)
         # sentinel in the ACCUMULATOR dtype: a float64 zero would promote
         # the whole prefix array and lose int64 exactness beyond 2^53
         cs0 = xp.concatenate([xp.zeros(1, cs.dtype), cs])  # sum of rows < i
@@ -527,7 +534,9 @@ def _window_aggregate(xp, func, ctx, spec, perm, pos, seg_start_idx,
         elif frame is None:
             lo_idx, hi_idx = seg_start_idx, vg_end_idx   # range: incl. peers
         else:
-            lo, hi = frame
+            # offsets past the capacity clamp alike, and fit int32
+            lo, hi = (None if b is None else max(-cap, min(cap, b))
+                      for b in frame)
             lo_idx = seg_start_idx if lo is None else \
                 xp.clip(idx + lo, seg_start_idx, seg_end_idx + 1)
             hi_idx = seg_end_idx if hi is None else \
@@ -551,9 +560,9 @@ def _window_aggregate(xp, func, ctx, spec, perm, pos, seg_start_idx,
         # running min/max with per-segment reset: vectorized Hillis-Steele
         # segmented scan (log2(cap) doubling passes; same code on numpy and
         # jax — no sequential lax.scan, no per-row Python)
-        seg_id = xp.cumsum(base_flag.astype(np.int64)) - 1
+        seg_id = running_sum_i32(xp, base_flag) - 1
         run = _segmented_running_scan(xp, buf, seg_id, kind, cap)
-        cnt_run = xp.cumsum(cnt_buf)
+        cnt_run = running_sum_i32(xp, cnt_buf)
         c0 = xp.concatenate([xp.zeros(1, cnt_run.dtype), cnt_run])
         if frame is None:
             # default RANGE frame: the current row's ORDER BY peers are IN
@@ -566,9 +575,9 @@ def _window_aggregate(xp, func, ctx, spec, perm, pos, seg_start_idx,
         return run, live_s & (count > 0), dt_out
     # whole partition
     from ..kernels import segment_reduce
-    seg_id = xp.cumsum(base_flag.astype(np.int64)) - 1
+    seg_id = running_sum_i32(xp, base_flag) - 1
     reduced = segment_reduce(xp, buf, seg_id, cap, kind)
-    cnts = segment_reduce(xp, cnt_buf, seg_id, cap, "sum")
+    cnts = segment_reduce(xp, cnt_buf.astype(np.int32), seg_id, cap, "sum")
     out = reduced[seg_id]
     count = cnts[seg_id]
     return out, live_s & (count > 0), dt_out

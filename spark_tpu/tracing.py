@@ -75,7 +75,18 @@ KERNEL_SCOPES = frozenset({
     "sort_batch", "argsort", "take_batch", "compact", "partition_bucket",
     "exchange.pack", "exchange.all_to_all", "exchange.all_gather",
     "exchange.psum",
+    "window.sort", "window.segments", "window.rank", "window.agg",
+    "grouping.rollup",
 })
+#: stage scopes that say what their whole program is for: kept in front of
+#: the operator a device op came from (``named_scope_of``)
+STAGE_NAMES = frozenset({"grouping.rollup"})
+#: the zero-length spans ``device_time_by_scope`` tallies: span -> (the
+#: key of its tally, the attributes it is tallied by; None: all of them)
+TALLIED = {"join.path": ("join_paths", None),
+           "agg.scan": ("agg_scans", None),
+           "grouping.arm": ("grouping_arms", ("from_finer",)),
+           "window": ("windows", ("funcs", "partition_keys", "order_keys"))}
 
 
 class Span(NamedTuple):
@@ -342,11 +353,13 @@ _SCOPE_STAT = "tf_op"
 def named_scope_of(op_name: str) -> Optional[str]:
     """``PJoin#4/join.probe`` from ``jit(run)/stage.step/PSort#1/PJoin#4/
     join.probe/gather:``: the innermost operator and every kernel scope
-    this module names below it; None where the path holds neither."""
+    this module names below it, after the program's ``STAGE_NAMES`` scope
+    where it has one (``grouping.rollup/PAggregate#1/agg.sort``); None
+    where the path holds neither."""
     named: List[str] = []
     for part in op_name.split("/"):
         if _OPERATOR.match(part):
-            named = [part]
+            named = [p for p in named if p in STAGE_NAMES] + [part]
         elif part in KERNEL_SCOPES or _ARGSORT_PASS.match(part):
             named.append(part)
     return "/".join(named) or None
@@ -453,7 +466,10 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
     [count, seconds]}, "join_paths": [[{unique, dense, out_cap, probe_cap,
     string}, count], ...] (the ``join.path`` spans by their attributes),
     "agg_scans": [[{op, rounds}, count], ...] (the ``agg.scan`` spans: the
-    rounds a sort aggregate's segmented scan took),
+    rounds a sort aggregate's segmented scan took), "grouping_arms":
+    [[{from_finer}, count], ...] (the ``grouping.arm`` spans: sets read
+    from a finer set or from the statement's rows), "windows": [[{funcs,
+    partition_keys, order_keys}, count], ...] (the ``window`` spans),
     "profile_start_ns", "annotations": [[name, start_ns (epoch), dur_ns],
     ...]}``
 
@@ -462,8 +478,7 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
     data = jax.profiler.ProfileData.from_file(xplane_path)
     op_names = _op_names(xplane_path)
     devices, host, annotations, start_ns = {}, {}, [], None
-    records = {"join.path": collections.Counter(),
-               "agg.scan": collections.Counter()}
+    records = {name: collections.Counter() for name in TALLIED}
     for plane in data.planes:
         if plane.name == "Task Environment":
             start_ns = dict(plane.stats).get("profile_start_time", start_ns)
@@ -485,9 +500,12 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
                         got[0] += 1
                         got[1] += e.duration_ns / 1e9
                         annotations.append([name, e.start_ns, e.duration_ns])
-                        if name in ("join.path", "agg.scan"):
-                            records[name][json.dumps(dict(e.stats),
-                                                     sort_keys=True)] += 1
+                        if name in TALLIED:
+                            stats, keep = dict(e.stats), TALLIED[name][1]
+                            if keep is not None:
+                                stats = {k: stats.get(k) for k in keep}
+                            records[name][json.dumps(
+                                stats, sort_keys=True, default=str)] += 1
     if start_ns is not None:
         for a in annotations:
             a[1] = int(a[1] + start_ns)
@@ -495,11 +513,10 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
            "unnamed_pct": None, "by_scope": [], "top_ops": [],
            "unnamed_top": [],
            "host_spans": host, "profile_start_ns": start_ns,
-           "join_paths": [[json.loads(a), n] for a, n in
-                          records["join.path"].most_common()],
-           "agg_scans": [[json.loads(a), n] for a, n in
-                         records["agg.scan"].most_common()],
            "annotations": annotations}
+    for name, got in records.items():
+        out[TALLIED[name][0]] = [[json.loads(a), n]
+                                 for a, n in got.most_common()]
     if not devices:
         return out
     selfs = {d: _self_times(evs) for d, evs in devices.items()}
@@ -598,10 +615,9 @@ def _main(argv) -> int:
         for name, (n, s) in sorted(r["host_spans"].items(),
                                    key=lambda kv: -kv[1][1])[:24]:
             print(f"  {n:6d} {s:10.4f} s  sql:{name}")
-        for attrs, n in r["join_paths"]:
-            print(f"  {n:6d} sql:join.path  {json.dumps(attrs)}")
-        for attrs, n in r["agg_scans"]:
-            print(f"  {n:6d} sql:agg.scan  {json.dumps(attrs)}")
+        for name, (key, _attrs) in TALLIED.items():
+            for attrs, n in r[key]:
+                print(f"  {n:6d} sql:{name}  {json.dumps(attrs)}")
         print(json.dumps({"profile_start_ns": r["profile_start_ns"],
                           "annotations": len(r["annotations"])}))
     return 0

@@ -421,3 +421,59 @@ def test_exchange_lowering_error_propagates(monkeypatch):
     with pytest.raises(Boom):
         ici.device_exchange(svc, None, plan, "xq-test", {0: [b]}, b)
     assert svc.counters["ici_exchanges"] == 0
+
+
+# -- q67's rollup and window ------------------------------------------------
+
+def test_window_rank_compiles(one_chip, on_tpu):
+    """``compute_windows``: RANK() OVER (PARTITION BY a string ORDER BY a
+    float64 DESC) at 2^20 rows, the size of TPC-DS q67's union of grouping
+    sets at SF1 (789,028 rows); the positions are int32 running maxima."""
+    from spark_tpu.sql.logical import SortOrder
+    from spark_tpu.sql.window import Rank, WindowSpec, compute_windows
+    n = 1 << 20
+    words = tuple(f"category{i}" for i in range(10))
+    batch = ColumnBatch(["c", "s"], [
+        ColumnVector(np.zeros(n, np.int32), T.StringType(),
+                     np.ones(n, bool), words),
+        ColumnVector(np.zeros(n, np.float64), T.DoubleType(),
+                     np.ones(n, bool), None)], np.ones(n, bool), n)
+    spec = WindowSpec([Col("c")], [SortOrder(Col("s"), False)])
+    text = jax.jit(lambda b: compute_windows(jnp, b, spec, [(Rank(), "rk")])) \
+        .lower(_spec(batch, one_chip)).compile().as_text()
+    assert "window.sort" in text and "window.segments" in text
+    assert "window.rank" in text
+
+
+def test_rollup_coarser_set_compiles(one_chip, on_tpu, spark):
+    """One grouping set re-aggregated from the next finer one, as q67's
+    ROLLUP runs it at SF1: GROUP BY category, class, brand, product name and
+    year over the 18,000 groups of the set below (2^15 rows), the SUM of its
+    sums, under ``grouping.rollup``."""
+    from spark_tpu import tracing
+    from spark_tpu.sql import logical as L
+    from spark_tpu.sql import physical as P
+    from spark_tpu.sql.planner import Planner
+    n = 1 << 15
+    strings = ["i_category", "i_class", "i_brand", "i_product_name"]
+    batch = ColumnBatch(strings + ["d_year", "__gs_0"], [
+        ColumnVector(np.zeros(n, np.int32), T.StringType(),
+                     np.ones(n, bool), tuple(f"{c}{i}" for i in range(50)))
+        for c in strings] + [
+        ColumnVector(np.zeros(n, np.int32), T.IntegerType(),
+                     np.ones(n, bool), None),
+        ColumnVector(np.zeros(n, np.float64), T.DoubleType(),
+                     np.ones(n, bool), None)], np.ones(n, bool), n)
+    plan = L.Aggregate([Col(c) for c in strings + ["d_year"]],
+                       [(Sum(Col("__gs_0")), "__gs_0")], L.LocalRelation(batch))
+    pq = Planner(spark).plan(plan)
+
+    def step(leaves):
+        with tracing.scope("grouping.rollup"):
+            ctx = P.ExecContext(jnp, list(leaves))
+            return K.compact(jnp, pq.physical.run(ctx)), ctx.flags
+
+    text = jax.jit(step).lower(
+        _spec(tuple(b.to_device() for b in pq.leaves), one_chip)) \
+        .compile().as_text()
+    assert "grouping.rollup" in text and "agg.sort" in text

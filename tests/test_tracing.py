@@ -272,6 +272,23 @@ def test_kernel_scopes_in_lowered_text(in_memory, monkeypatch):
     texts.append(SC.stage_cache().peek(key).fn.lower(
         tuple(b.to_device() for b in leaves), SC.param_values(slots))
         .as_text(debug_info=True))
+    # a grouping set re-aggregated from a finer one runs under
+    # grouping.rollup; a window's phases are scopes of their own
+    qe = QueryExecution(in_memory, in_memory.sql(
+        "SELECT s_store_sk, COUNT(*) AS n FROM store GROUP BY s_store_sk")
+        ._plan)
+    qe._stage_scope = "grouping.rollup"
+    qe._execute_inner()
+    key, slots, leaves = local_stage_key(in_memory, qe.planned)
+    texts.append(SC.stage_cache().peek(key).fn.lower(
+        tuple(b.to_device() for b in leaves), SC.param_values(slots))
+        .as_text(debug_info=True))
+    from spark_tpu.sql.logical import SortOrder
+    from spark_tpu.sql.window import Rank, WindowSpec, compute_windows
+    spec = WindowSpec([Col("k")], [SortOrder(Col("v"), False)])
+    texts.append(jax.jit(lambda b: compute_windows(
+        jnp, b, spec, [(Rank(), "r"), (Sum(Col("v")), "s")])).lower(batch)
+        .as_text(debug_info=True))
     # a join of two string columns with dictionaries of their own gathers
     # each side's codes through the id table the trace built for it
     from spark_tpu.sql.joins import _exact_encode_pair
@@ -586,3 +603,57 @@ def test_join_path_pct_on_the_recording(metric, cells, ring, want):
         ctx = Context(trace=trace, ring=json.load(fh)["ring"])
     reader = importlib.import_module("benchmark.readers." + spec["reader"])
     assert reader.read(ctx, **spec["args"]) == pytest.approx(want)
+
+
+ROLLUP_RANK = (
+    "SELECT i_category, i_class, i_brand, s, "
+    "RANK() OVER (PARTITION BY i_category ORDER BY s DESC) AS rk "
+    "FROM (SELECT i_category, i_class, i_brand, "
+    "SUM(ss_ext_sales_price) AS s FROM store_sales, item "
+    "WHERE ss_item_sk = i_item_sk "
+    "GROUP BY ROLLUP(i_category, i_class, i_brand)) t")
+
+
+@pytest.mark.parametrize("lane", ["local", "stages"])
+def test_grouping_arms_and_windows_are_spans(lane, request, tmp_path,
+                                             capsys):
+    """A ROLLUP of three keys under a RANK: one ``grouping.arm`` span for
+    each of its four sets -- the finest read from the statement's rows, each
+    other from the next finer set, whose groups are its ``rows_in`` -- and
+    one ``window`` span with the window's functions and keys (and, on the
+    ``stages`` lane, the rows of the union it ranks); ``python -m
+    spark_tpu.tracing`` tallies both."""
+    spark = request.getfixturevalue("in_memory" if lane == "local"
+                                    else "streamed")
+    spark.sql(ROLLUP_RANK).collect()                   # warm
+    tracing.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        spark.sql(ROLLUP_RANK).collect()
+    finally:
+        jax.profiler.stop_trace()
+    spans = tracing.spans()
+    root, = [s for s in spans if s.name == "statement"]
+    assert root.attrs["path"] == lane
+    arms = [s.attrs for s in spans if s.name == "grouping.arm"]
+    keys = ["i_category", "i_class", "i_brand"]
+    assert [(a["set"], a["keys"], a["from_finer"]) for a in arms] == \
+        [(i, keys[:3 - i], i > 0) for i in range(4)]
+    assert arms[0]["rows_in"] is None
+    assert [a["rows_in"] for a in arms[1:]] == \
+        [a["rows_out"] for a in arms[:-1]]
+    window, = [s.attrs for s in spans if s.name == "window"]
+    assert window["funcs"] == ["rank()"]
+    assert window["partition_keys"] == ["i_category"]
+    assert window["order_keys"] == ["s DESC NULLS LAST"]
+    assert window["rows"] == (None if lane == "local"
+                              else sum(a["rows_out"] for a in arms))
+    read = tracing.device_time_by_scope(tracing._xplanes(str(tmp_path))[-1])
+    assert sorted(n for _attrs, n in read["grouping_arms"]) == [1, 3]
+    assert {json.dumps(a) for a, _n in read["grouping_arms"]} == {
+        '{"from_finer": 0}', '{"from_finer": 1}'}
+    assert sum(n for _attrs, n in read["windows"]) == 1
+    assert tracing._main([str(tmp_path)]) == 0
+    out = capsys.readouterr().out
+    assert '3 sql:grouping.arm  {"from_finer": 1}' in out
+    assert "1 sql:window  {" in out
