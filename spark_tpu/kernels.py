@@ -364,19 +364,54 @@ def slice_rows(batch: ColumnBatch, start: int, count: int) -> ColumnBatch:
 
 @_scoped("take_batch")
 def take_batch(xp, batch: ColumnBatch, perm: Array) -> ColumnBatch:
-    """Gather all columns (and masks) through an index array.
+    """Gather all columns (and masks) through an index array: on the jax
+    lane as ONE ``take_columns`` read, on the numpy lane (the tests'
+    independent form) one gather an array.
 
     ``perm`` may be longer/shorter than the input capacity (join expansion);
     the output capacity is ``len(perm)``.
     """
     out_cap = int(perm.shape[0])
-    vectors = []
-    for v in batch.vectors:
-        data = v.data[perm]
-        valid = None if v.valid is None else v.valid[perm]
-        vectors.append(ColumnVector(data, v.dtype, valid, v.dictionary))
-    rv = None if batch.row_valid is None else batch.row_valid[perm]
+    if _is_np(xp):
+        vectors = []
+        for v in batch.vectors:
+            data = v.data[perm]
+            valid = None if v.valid is None else v.valid[perm]
+            vectors.append(ColumnVector(data, v.dtype, valid, v.dictionary))
+        rv = None if batch.row_valid is None else batch.row_valid[perm]
+        return ColumnBatch(batch.names, vectors, rv, out_cap)
+    vectors, rv, _ = take_columns(xp, batch.vectors, batch.row_valid, perm)
     return ColumnBatch(batch.names, vectors, rv, out_cap)
+
+
+def take_columns(xp, vectors: Sequence[ColumnVector], row_valid: Optional[Array],
+                 idx: Array, extra: Sequence[Array] = ()
+                 ) -> Tuple[List[ColumnVector], Optional[Array], List[Array]]:
+    """Every vector's data and validity, the row mask and ``extra`` read
+    through ``idx``: on a TPU by ONE ``gather_columns`` call, one index
+    pass for all of them (a plane of one int32 word already beats its
+    column's gather there: PERF.md section 6, ``tools/prof_take.py``).  A
+    column whose data is not 1-D (an ``ArrayType`` plane) is gathered by
+    itself.  Returns (vectors, row mask, extra), each as ``xp``'s own
+    gather would; the trace-time note ``take_lowering`` says which form
+    the call took.  Elsewhere one gather an array: XLA:CPU has no index
+    pass to save and compiles each plane's packing into every program that
+    moves rows (``tests/test_tpcds.py`` ran a third slower and its worker
+    ran out of LLVM code space)."""
+    flat = [v.data for v in vectors if v.data.ndim == 1] \
+        + [v.valid for v in vectors if v.valid is not None] \
+        + ([] if row_valid is None else [row_valid]) + list(extra)
+    plane = not _is_np(xp) and _on_tpu_device()
+    if not _is_np(xp):
+        tracing.note("take_lowering", "plane" if plane else "columns")
+    got = iter(gather_columns(xp, flat, idx) if plane
+               else [c[idx] for c in flat])
+    datas = [next(got) if v.data.ndim == 1 else None for v in vectors]
+    out = [ColumnVector(v.data[idx] if d is None else d, v.dtype,
+                        None if v.valid is None else next(got), v.dictionary)
+           for v, d in zip(vectors, datas)]
+    rv = None if row_valid is None else next(got)
+    return out, rv, list(got)
 
 
 @_scoped("compact")
@@ -1003,9 +1038,9 @@ MXU_AGG_ENABLED: "bool | None" = None
 
 
 def _on_tpu_device() -> bool:
-    """THE device gate: the MXU aggregate, its Mosaic kernel and the
-    chained single-key sort are chosen where the process runs on a TPU,
-    and nowhere else."""
+    """THE device gate: the MXU aggregate, its Mosaic kernel, the chained
+    single-key sort and the plane reads of ``take_columns`` are chosen
+    where the process runs on a TPU, and nowhere else."""
     import jax
     return jax.default_backend() == "tpu"
 

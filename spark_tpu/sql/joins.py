@@ -55,7 +55,7 @@ from ..columnar import (ColumnBatch, ColumnVector, PlaneColumnVector,
 from ..expressions import AnalysisException, Col, EQ, EvalContext, Expression, Hash64
 from ..kernels import (_POSITIONAL_EXPRS, _scope, keys_span_under,
                        multi_key_argsort, searchsorted, slot_owner,
-                       table_search, take_batch)
+                       table_search, take_batch, take_columns)
 from .logical import Join
 from . import physical as P
 
@@ -435,12 +435,18 @@ class PJoin(P.PhysicalPlan):
         bctx = EvalContext(build, xp)
         probe_live = probe.row_valid_or_true()
         build_live = build.row_valid_or_true()
+        # a semi / anti join with no residual returns its keep mask alone:
+        # no read through perm, i or b_row carries a column of either side
+        reads_columns = how not in ("left_semi", "left_anti") \
+            or self.residual is not None
 
         # the phases below are the device scopes of a join (tracing.py):
         # join.keys, join.build_sort, join.probe (the searches and the match
         # counts) or join.dense (the table in their place), then join.expand
-        # + join.gather on the general path or join.unique on the
-        # unique-build path
+        # (the slot map and the two reads, through the probe row and the
+        # build row, each ONE ``take_columns``) + join.gather (the residual
+        # and the existence scatter) on the general path, or join.unique on
+        # the unique-build path
         with _scope(xp, "join.keys"):
             # exact int64 encodings per key pair (None → hashB fallback for
             # that pair's verification).  A single probe key riding an
@@ -491,6 +497,18 @@ class PJoin(P.PhysicalPlan):
                 ba, _bb = _join_keys(bctx, rks, _NULL_BUILD, _DEAD_BUILD)
                 sort_keys = [ba]
 
+            hashb = None
+            if any(e is None for e in encs):
+                # unencodable pairs: fall back to the independent second
+                # hash over exactly those pairs (collision ~2^-64,
+                # documented)
+                exprs_l = [l for (l, _), e in zip(self.key_pairs, encs)
+                           if e is None]
+                exprs_r = [r for (_, r), e in zip(self.key_pairs, encs)
+                           if e is None]
+                hashb = (pctx.broadcast(_Hash64B(*exprs_l).eval(pctx)).data,
+                         bctx.broadcast(_Hash64B(*exprs_r).eval(bctx)).data)
+
         with _scope(xp, "join.build_sort"):
             if exact and self.presorted_build:
                 # range exchange delivered the build side already merged
@@ -498,12 +516,29 @@ class PJoin(P.PhysicalPlan):
                 perm = xp.arange(build.capacity, dtype=np.int32)
             else:
                 perm = multi_key_argsort(xp, sort_keys, build.capacity)
+            # the build side in sorted order: its columns (where the join
+            # reads them), its liveness (which is its row mask), its search
+            # key and the verification arrays of 5. (each pair's encoding
+            # and validity, the second hash), ONE read through perm
+            b_checks = [a for e in encs if e is not None
+                        for a in (e[2], e[3]) if a is not None]
+            if hashb is not None:
+                b_checks.append(hashb[1])
+            b_key = [b_flag] if exact else [ba]
+            vectors_s, _, got = take_columns(
+                xp, build.vectors if reads_columns else [], None, perm,
+                [build_live] + b_key + b_checks)
+            build_live_s, key_s, b_checks_s = got[0], got[1], got[2:]
+            build_s = ColumnBatch(
+                build.names if reads_columns else [], vectors_s,
+                None if build.row_valid is None else build_live_s,
+                build.capacity)
             if exact:
-                b_flag_s = b_flag[perm]
-                ba_s = xp.where(b_flag_s == 0, b_enc[perm], _DEAD_BUILD)
+                # the first check is the search key's encoding: b_enc
+                b_flag_s = key_s
+                ba_s = xp.where(b_flag_s == 0, b_checks_s[0], _DEAD_BUILD)
             else:
-                ba_s = ba[perm]
-            build_s = take_batch(xp, build, perm)
+                ba_s = key_s
 
         out_cap = pad_capacity(int(probe.capacity * max(self.factor, 0.1)))
         # the slot map below exists for builds that repeat a key; where
@@ -527,19 +562,6 @@ class PJoin(P.PhysicalPlan):
                 pe0, pv0, be0, bv0 = encs[0]
                 encs[0] = (pe0[run_rid],
                            None if pv0 is None else pv0[run_rid], be0, bv0)
-            hashb = None
-            if any(e is None for e in encs):
-                # unencodable pairs: fall back to the independent second
-                # hash over exactly those pairs (collision ~2^-64,
-                # documented)
-                exprs_l = [l for (l, _), e in zip(self.key_pairs, encs)
-                           if e is None]
-                exprs_r = [r for (_, r), e in zip(self.key_pairs, encs)
-                           if e is None]
-                hashb = (pctx.broadcast(_Hash64B(*exprs_l).eval(pctx)).data,
-                         bctx.broadcast(
-                             _Hash64B(*exprs_r).eval(bctx)).data[perm])
-            build_live_s = build_live[perm]
             build_unique = _build_unique(
                 xp, ba_s, b_flag_s if exact else None) if skippable else False
             # an exact build whose matchable keys span fewer integers than
@@ -629,6 +651,17 @@ class PJoin(P.PhysicalPlan):
             def phase(name):
                 return _scope(xp, "join.unique" if unique else name)
 
+            # what each slot reads of its probe row: the match, the probe
+            # side's verification arrays (each pair's encoding and
+            # validity, the second hash), its liveness where unmatched
+            # rows are kept, then (join.gather) its columns
+            p_checks = [a for e in encs if e is not None
+                        for a in (e[0], e[1]) if a is not None]
+            if hashb is not None:
+                p_checks.append(hashb[0])
+            keeps = how in ("left", "full")
+            p_reads = [matched_hash] + p_checks \
+                + ([probe_live] if keeps else [])
             with phase("join.expand"):
                 # output slot j → probe row i and duplicate index d
                 if unique:
@@ -637,6 +670,10 @@ class PJoin(P.PhysicalPlan):
                     in_range = counts_eff > 0
                     first = in_range         # d is 0 in every slot
                     b_row = xp.clip(lo, 0, build.capacity - 1)
+                    left_vectors = [
+                        ColumnVector(v.data, v.dtype, v.valid, v.dictionary)
+                        for v in probe.vectors]
+                    p_got = p_reads
                 else:
                     offsets = ends - counts_eff     # exclusive prefix
                     slot = xp.arange(out_cap, dtype=np.int64)
@@ -645,41 +682,48 @@ class PJoin(P.PhysicalPlan):
 
                     def at(a):
                         return a[i]
-                    d = slot - offsets[i]
+                    # everything read through i, ONE read: the slot's
+                    # run and the probe row's arrays and columns
+                    left_vectors, _, p_got = take_columns(
+                        xp, probe.vectors if reads_columns else [], None, i,
+                        [offsets, lo] + p_reads)
+                    offsets_i, lo_i, p_got = p_got[0], p_got[1], p_got[2:]
+                    d = slot - offsets_i
                     in_range = slot < total
                     first = d == 0
-                    b_row = xp.clip(lo[i] + d, 0, build.capacity - 1)
-                has_match = at(matched_hash)
+                    b_row = xp.clip(lo_i + d, 0, build.capacity - 1)
+                has_match = p_got[0]
+                live_i = p_got[-1] if keeps else None
+
+                # everything read through b_row, ONE read: the build row's
+                # liveness, verification arrays and (join.gather) columns
+                right_vectors, _, b_got = take_columns(
+                    xp, build_s.vectors, None, b_row,
+                    [build_live_s] + b_checks_s)
 
                 # EXACT per-pair verification (null-aware): a pair survives
                 # only if every key column compares equal with both sides
                 # valid
-                verify = in_range & has_match & build_live_s[b_row]
+                verify = in_range & has_match & b_got[0]
+                p_at, b_at = iter(p_got[1:]), iter(b_got[1:])
                 for e in encs:
                     if e is not None:
-                        pe, pv, be, bv = e
-                        be_s = be[perm]
-                        ok = at(pe) == be_s[b_row]
-                        if pv is not None:
-                            ok = ok & at(pv)
-                        if bv is not None:
-                            ok = ok & bv[perm][b_row]
+                        ok = next(p_at) == next(b_at)
+                        if e[1] is not None:
+                            ok = ok & next(p_at)
+                        if e[3] is not None:
+                            ok = ok & next(b_at)
                         verify = verify & ok
                 if hashb is not None:
-                    verify = verify & (at(hashb[0]) == hashb[1][b_row])
+                    verify = verify & (next(p_at) == next(b_at))
 
             with phase("join.gather"):
                 # assemble the combined (probe row, build row) batch for
                 # each slot; needed before existence when a residual ON
                 # conjunct participates in the match decision
-                left_vectors = [
-                    ColumnVector(v.data, v.dtype, v.valid, v.dictionary)
-                    for v in probe.vectors] if unique \
-                    else take_batch(xp, probe, i).vectors
-                right_out = take_batch(xp, build_s, b_row)
-                names: List[str] = list(probe.names) + list(right_out.names)
+                names: List[str] = list(probe.names) + list(build_s.names)
                 raw_vectors: List[ColumnVector] = \
-                    list(left_vectors) + list(right_out.vectors)
+                    list(left_vectors) + list(right_vectors)
 
                 if self.residual is not None:
                     # non-equi ON conjuncts are part of the MATCH CONDITION
@@ -709,7 +753,7 @@ class PJoin(P.PhysicalPlan):
                 # null-extended row on their first slot (covers
                 # zero-hash-match rows, all-pairs-refuted collisions, and
                 # residual-refuted matches)
-                null_slot = in_range & first & ~at(exact_m) & at(probe_live)
+                null_slot = in_range & first & ~at(exact_m) & live_i
                 pair_ok = verify | null_slot
                 null_right = verify
             else:

@@ -577,6 +577,7 @@ class QueryExecution:
                 finally:
                     self._root_span = None
             end_event["phases"] = tracing.statement_phases(sid)
+            end_event["notes"] = tracing.statement_notes(sid)
             self.session._post_event(end_event)
             return result
 

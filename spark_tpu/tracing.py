@@ -54,7 +54,8 @@ __all__ = [
     "Span", "span", "scope", "count", "fresh_jit", "replan", "note",
     "statement",
     "adopt", "current_statement", "collecting", "spans", "summary",
-    "last_statement", "statement_phases", "reset", "device_time_by_scope",
+    "last_statement", "statement_phases", "statement_notes", "reset",
+    "device_time_by_scope",
     "KERNEL_SCOPES",
 ]
 
@@ -81,6 +82,11 @@ KERNEL_SCOPES = frozenset({
 #: stage scopes that say what their whole program is for: kept in front of
 #: the operator a device op came from (``named_scope_of``)
 STAGE_NAMES = frozenset({"grouping.rollup"})
+#: the scopes whose gathers ``device_time_by_scope`` counts a statement: a
+#: set of arrays read through one index is one gather on a TPU
+#: (``kernels.take_columns``), one an array elsewhere
+GATHER_SCOPES = ("take_batch", "join.unique", "join.expand", "join.gather")
+_GATHER_OP = re.compile(r"\bgather\(")
 #: the zero-length spans ``device_time_by_scope`` tallies: span -> (the
 #: key of its tally, the attributes it is tallied by; None: all of them)
 TALLIED = {"join.path": ("join_paths", None),
@@ -316,10 +322,21 @@ def statement_phases(sid: int) -> Dict[str, float]:
         return {} if st is None else st.phases_ms()
 
 
+def statement_notes(sid: int) -> Dict[str, List]:
+    """Note key -> values of the statement so far (``SQLExecutionEnd``
+    ``notes``: ``agg_lowering``, ``take_lowering``); empty for a statement
+    no longer kept."""
+    with _lock:
+        st = _statements.get(sid)
+        return {} if st is None else {k: list(v) for k, v in st.notes.items()}
+
+
 def last_statement() -> Optional[dict]:
     """The statement allotted last: its id, phase self times (ms), span
     count and notes (``notes["agg_lowering"]``: the keyed-aggregate
-    lowerings its stages took, e.g. ``["sort"]``)."""
+    lowerings its stages took, e.g. ``["sort.scan"]``; ``notes[
+    "take_lowering"]``: how its gathers through one index went, ``plane``
+    and / or ``columns``)."""
     with _lock:
         if not _statements:
             return None
@@ -457,6 +474,35 @@ def _self_times(events):
     return out
 
 
+def gather_scope(event: str, op_name: str) -> Optional[str]:
+    """The innermost of ``GATHER_SCOPES`` that a device op runs under where
+    the op is a gather (its HLO opcode, or the root of its fusion, a
+    ``gather``), else None."""
+    last = op_name.rstrip(":").rsplit("/", 1)[-1]
+    if not (last.startswith("gather")
+            or _GATHER_OP.search(event.partition(" = ")[2])):
+        return None
+    return next((p for p in reversed(op_name.split("/"))
+                 if p in GATHER_SCOPES), None)
+
+
+def gathers_by_statement(events, statements) -> List[Dict[str, int]]:
+    """For each statement ``(start_ns, dur_ns)`` the gathers of ``events``
+    (``(start_ns, event name, op_name)`` of one device) that start inside
+    it, counted by ``gather_scope``: what one index pass a set of arrays
+    lowers."""
+    out = [dict.fromkeys(GATHER_SCOPES, 0) for _ in statements]
+    for start, name, op in events:
+        scope_name = gather_scope(name, op)
+        if scope_name is None:
+            continue
+        for got, (lo, dur) in zip(out, statements):
+            if lo <= start < lo + dur:
+                got[scope_name] += 1
+                break
+    return out
+
+
 def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
     """Reduce one ``.xplane.pb``:
 
@@ -470,8 +516,9 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
     [[{from_finer}, count], ...] (the ``grouping.arm`` spans: sets read
     from a finer set or from the statement's rows), "windows": [[{funcs,
     partition_keys, order_keys}, count], ...] (the ``window`` spans),
-    "profile_start_ns", "annotations": [[name, start_ns (epoch), dur_ns],
-    ...]}``
+    "gathers": [{scope: count}, ...] (``gathers_by_statement``: one entry
+    for each ``statement`` span, on the busiest device), "profile_start_ns",
+    "annotations": [[name, start_ns (epoch), dur_ns], ...]}``
 
     Device seconds are self times on the busiest device's ``XLA Ops``
     line; ``unnamed`` is the part under no scope this module names."""
@@ -506,12 +553,13 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
                                 stats = {k: stats.get(k) for k in keep}
                             records[name][json.dumps(
                                 stats, sort_keys=True, default=str)] += 1
+    statements = [(s, d) for name, s, d in annotations if name == "statement"]
     if start_ns is not None:
         for a in annotations:
             a[1] = int(a[1] + start_ns)
     out = {"device": None, "busy_s": 0.0, "unnamed_s": 0.0,
            "unnamed_pct": None, "by_scope": [], "top_ops": [],
-           "unnamed_top": [],
+           "unnamed_top": [], "gathers": [],
            "host_spans": host, "profile_start_ns": start_ns,
            "annotations": annotations}
     for name, got in records.items():
@@ -521,6 +569,9 @@ def device_time_by_scope(xplane_path: str, top: int = 10) -> dict:
         return out
     selfs = {d: _self_times(evs) for d, evs in devices.items()}
     dev = max(selfs, key=lambda d: sum(ns for _p, ns in selfs[d]))
+    out["gathers"] = gathers_by_statement(
+        [(s, name, path) for s, _e, (name, path) in devices[dev]],
+        statements)
     by, unnamed, ops = {}, {}, {}
     for (name, path), ns in selfs[dev]:
         scope_name = named_scope_of(path)
@@ -618,6 +669,9 @@ def _main(argv) -> int:
         for name, (key, _attrs) in TALLIED.items():
             for attrs, n in r[key]:
                 print(f"  {n:6d} sql:{name}  {json.dumps(attrs)}")
+        for k, got in enumerate(r["gathers"]):
+            print(f"  statement {k}: {sum(got.values())} gathers "
+                  f"{json.dumps(got)}")
         print(json.dumps({"profile_start_ns": r["profile_start_ns"],
                           "annotations": len(r["annotations"])}))
     return 0

@@ -678,3 +678,84 @@ def test_fanout_self_join_lanes_agree(spark, how):
     else:
         keep = pairs.any(axis=1) == (how == "left_semi")
         assert got == [(int(i),) for i in np.nonzero(keep)[0]]
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "left_semi", "left_anti",
+                                 "left_anti_no_residual"])
+@pytest.mark.parametrize("keys", ["unique_exact", "dup_two_key"])
+def test_join_reads_lanes_agree(spark, keys, how, monkeypatch):
+    """What ``PJoin`` reads through a probe row and a build row (the
+    verification arrays, the liveness, every column: int16 to int64,
+    float64 with NaN and -0.0, strings, bools, NULLs) comes out of the jax
+    lane's plane gathers as out of the numpy lane's one gather an array:
+    the same rows, on the unique path (one exact key over a unique build)
+    and on the general path (two keys, hash-verified, over a build that
+    repeats them), with NULL keys on both sides and a residual ``ON``
+    conjunct (and an anti join without one, whose reads carry no column).
+    The jax lane takes its TPU form (the plane reads, and the build sort's
+    chained passes)."""
+    import jax.numpy as jnp
+    from spark_tpu import kernels as K
+    from spark_tpu.sql import physical as P
+    from spark_tpu.sql.planner import Planner, QueryExecution
+    monkeypatch.setattr(K, "_on_tpu_device", lambda: True)
+    rng = np.random.default_rng(34)
+    n, m = 200, 40
+    f = rng.normal(size=n)
+    f[:3] = [-0.0, np.nan, np.inf]
+    words = np.array(["ant", "bee", "cat", "dog", None], dtype=object)
+    spark.createDataFrame(pd.DataFrame({
+        "lid": np.arange(n, dtype=np.int64),
+        "lk": rng.integers(0, m + 5, n).astype(np.int64),
+        "lk2": (np.arange(n) % 2).astype(np.int64),
+        "lv": rng.integers(0, 6, n).astype(np.int32),
+        "lf": f, "ls": words[rng.integers(0, 5, n)],
+        "lb": rng.random(n) < 0.5})).createOrReplaceTempView("jr_probe")
+    rk = np.arange(m) if keys == "unique_exact" else np.arange(m) // 2
+    spark.createDataFrame(pd.DataFrame({
+        "rid": np.arange(m, dtype=np.int64), "rk": rk.astype(np.int64),
+        "rk2": (np.arange(m) % 2).astype(np.int64),
+        "rv": rng.integers(0, 6, m).astype(np.int16),
+        "rf": rng.normal(size=m), "rs": words[rng.integers(0, 5, m)]})) \
+        .createOrReplaceTempView("jr_build")
+    # NULL keys on both sides, and NULLs in the columns read
+    probe = ("SELECT lid, IF(lid % 11 = 0, NULL, lk) AS lk, lk2, "
+             "IF(lid % 7 = 0, NULL, lv) AS lv, lf, ls, lb FROM jr_probe")
+    build = ("SELECT rid, IF(rid % 13 = 5, NULL, rk) AS rk, rk2, rv, "
+             "IF(rid % 5 = 0, NULL, rf) AS rf, rs FROM jr_build")
+    on = "l.lk = r.rk" + (
+        "" if how == "left_anti_no_residual" else " AND l.lv <> r.rv") + (
+        " AND l.lk2 = r.rk2" if keys == "dup_two_key" else "")
+    how = how.replace("_no_residual", "")
+    if how in ("left_semi", "left_anti"):
+        text = (f"SELECT l.* FROM ({probe}) l WHERE "
+                f"{'' if how == 'left_semi' else 'NOT '}EXISTS "
+                f"(SELECT * FROM ({build}) r WHERE {on})")
+    else:
+        text = (f"SELECT l.*, r.* FROM ({probe}) l "
+                f"{'LEFT ' if how == 'left' else ''}JOIN ({build}) r ON {on}")
+    q = spark.sql(text)
+    unique = keys == "unique_exact"
+    pq = Planner(spark, join_factor_override=[1.0 if unique else 4.0]).plan(
+        QueryExecution(spark, q._plan).optimized)
+    for view in ("jr_probe", "jr_build"):
+        spark.catalog.dropTempView(view)
+    assert f"HashJoin {how}" in pq.physical.tree_string()
+
+    def run(xp, leaves):
+        ctx = P.ExecContext(xp, leaves)
+        out = K.compact(xp, pq.physical.run(ctx))
+        flags = [int(f) for f in ctx.flags]
+        paths = [f for k, f in zip(ctx.flag_kinds, flags)
+                 if k == P.JOIN_PATH]
+        assert paths and all(bool(-f & 1) == unique for f in paths)
+        assert not any(f > 0 for k, f in zip(ctx.flag_kinds, flags)
+                       if k != P.JOIN_PATH)
+        return out.to_host().to_pylist()
+
+    def key(t):
+        return tuple((v is None, repr(v)) for v in t)
+
+    got = run(jnp, [b.to_device() for b in pq.leaves])
+    want = run(np, [b.to_host() for b in pq.leaves])
+    assert got and sorted(got, key=key) == sorted(want, key=key)

@@ -285,40 +285,56 @@ def test_keyless_first_last_capacity_zero():
     assert out.capacity == 0
 
 
-def test_compact_jax_path_matches_numpy():
-    """The DEVICE compact (single-operand bit-packed uint32 sort) must
-    agree row-for-row with the numpy reference, including all-dead,
-    all-live and interleaved masks."""
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["columns", "plane"])
+def test_compact_jax_path_matches_numpy(on_tpu, monkeypatch):
+    """The DEVICE compact (single-operand bit-packed uint32 sort, then one
+    gather an array, or on a TPU ONE plane gather of every column, validity
+    and the row mask) must agree row-for-row and bit for bit with the numpy
+    reference on a batch of several columns, its row mask over the whole
+    capacity, including all-dead, all-live and interleaved masks."""
     import numpy as np
     import jax.numpy as jnp
+    from spark_tpu import kernels as K
     from spark_tpu import types as T
     from spark_tpu.columnar import ColumnBatch, ColumnVector
     from spark_tpu.kernels import compact
+    monkeypatch.setattr(K, "_on_tpu_device", lambda: on_tpu)
     rng = np.random.default_rng(13)
+    words = ("x", "y", "z")
     for mask in (rng.random(257) < 0.4,
                  np.zeros(257, bool),
                  np.ones(257, bool)):
-        data = rng.integers(0, 1000, 257).astype(np.int64)
-        valid = rng.random(257) < 0.9
-        b = ColumnBatch(["x"],
-                        [ColumnVector(data, T.int64, valid, None)],
-                        mask.copy(), 257)
-        ref = compact(np, b)
-        dev = compact(jnp, ColumnBatch(
-            ["x"], [ColumnVector(jnp.asarray(data), T.int64,
-                                 jnp.asarray(valid), None)],
-            jnp.asarray(mask), 257))
+        cols = [(rng.integers(0, 1000, 257).astype(np.int64), T.int64,
+                 rng.random(257) < 0.9, None),
+                (rng.integers(-5, 5, 257).astype(np.int32), T.int32, None,
+                 None),
+                (rng.normal(size=257), T.float64, rng.random(257) < 0.8,
+                 None),
+                (rng.random(257) < 0.5, T.boolean, None, None),
+                (rng.integers(0, 3, 257).astype(np.int32), T.string,
+                 rng.random(257) < 0.7, words)]
+        names = [f"c{i}" for i in range(len(cols))]
+
+        def batch(xp):
+            return ColumnBatch(names, [
+                ColumnVector(xp.asarray(d), dt,
+                             None if v is None else xp.asarray(v), dic)
+                for d, dt, v, dic in cols], xp.asarray(mask.copy()), 257)
+
+        ref = compact(np, batch(np))
+        dev = jax.jit(lambda b: compact(jnp, b))(batch(jnp))
         n = int(np.asarray(ref.num_rows()))
         assert int(np.asarray(dev.num_rows())) == n
-        np.testing.assert_array_equal(
-            np.asarray(dev.vectors[0].data)[:n],
-            np.asarray(ref.vectors[0].data)[:n])
-        np.testing.assert_array_equal(
-            np.asarray(dev.vectors[0].valid)[:n],
-            np.asarray(ref.vectors[0].valid)[:n])
-        np.testing.assert_array_equal(
-            np.asarray(dev.row_valid_or_true())[:n],
-            np.asarray(ref.row_valid_or_true())[:n])
+        np.testing.assert_array_equal(np.asarray(dev.row_valid_or_true()),
+                                      np.asarray(ref.row_valid_or_true()))
+        for r, d in zip(ref.vectors, dev.vectors):
+            assert d.dictionary == r.dictionary
+            assert np.asarray(d.data)[:n].tobytes() \
+                == np.asarray(r.data)[:n].tobytes()
+            assert (d.valid is None) == (r.valid is None)
+            if r.valid is not None:
+                np.testing.assert_array_equal(np.asarray(d.valid)[:n],
+                                              np.asarray(r.valid)[:n])
 
 
 def test_radix_argsort_matches_lax_sort():
@@ -837,6 +853,97 @@ def test_reduce_runs_equals_np_segment_reduce(kind, dtype, shape):
         np.testing.assert_allclose(got, want, rtol=1e-12, atol=0)
     else:
         assert np.array_equal(got, want)
+
+
+def _take_case(name, rng):
+    """(columns as (data, dtype, validity, dictionary), row mask, perm,
+    the lowering ``take_batch`` takes) of ``test_take_batch_jax_matches_
+    numpy``'s case ``name``; the batch holds 1,000 rows."""
+    n = 1000
+    f = rng.normal(size=n)
+    f[:5] = [-0.0, np.inf, -np.inf, np.nan, 5e-324]
+    every = [(rng.random(n) < 0.5, T.boolean),
+             (rng.integers(-128, 128, n).astype(np.int8), T.int8),
+             (rng.integers(-1 << 15, 1 << 15, n).astype(np.int16), T.int16),
+             (rng.integers(-1 << 31, 1 << 31, n).astype(np.int32), T.int32),
+             (rng.integers(-1 << 63, 1 << 63, n, dtype=np.int64), T.int64),
+             (rng.normal(size=n).astype(np.float32), T.float32),
+             (f, T.float64)]
+    some = rng.random(n) < 0.8
+    perm = rng.permutation(n).astype(np.int32)
+    if name == "every_dtype_valid":
+        return ([(d, dt, some, None) for d, dt in every],
+                rng.random(n) < 0.6, perm, "plane")
+    if name == "every_dtype_no_validity":
+        return [(d, dt, None, None) for d, dt in every], None, perm, "plane"
+    if name == "dictionary":
+        words = tuple(f"w{i}" for i in range(40))
+        return ([(rng.integers(0, 40, n).astype(np.int32), T.string, some,
+                  words), every[4] + (None, None)],
+                rng.random(n) < 0.6, perm, "plane")
+    if name == "array_2d":
+        return ([(rng.normal(size=(n, 3)), T.ArrayType(T.float64), None,
+                  None), every[3] + (some, None)], None, perm, "plane")
+    if name == "join_expansion":
+        return ([every[4] + (some, None), every[6] + (None, None)],
+                rng.random(n) < 0.6,
+                rng.integers(0, n, 4096).astype(np.int32), "plane")
+    if name == "shrink":
+        return [every[0] + (None, None), every[3] + (some, None)], \
+            None, perm[:300], "plane"
+    if name == "one_int32":                 # a plane of one word pays
+        return [every[3] + (None, None)], None, perm, "plane"
+    if name == "one_float64":               # the float64 plane alone
+        return [every[6] + (None, None)], None, perm, "plane"
+    assert name == "off_tpu"                # XLA:CPU: one gather an array
+    return [(d, dt, some, None) for d, dt in every], \
+        rng.random(n) < 0.6, perm, "columns"
+
+
+@pytest.mark.parametrize("name", [
+    "every_dtype_valid", "every_dtype_no_validity", "dictionary",
+    "array_2d", "join_expansion", "shrink", "one_int32", "one_float64",
+    "off_tpu"])
+def test_take_batch_jax_matches_numpy(name, monkeypatch):
+    """``take_batch`` on the jax lane (every 1-D column, validity and the
+    row mask through ONE plane gather on a TPU) equals the numpy lane
+    (one gather an array) bit for bit: every dtype, a dictionary column, a
+    2-D ``ArrayType`` column (gathered by itself), columns with and without
+    validity, a row mask or none, a ``perm`` longer or shorter than the
+    capacity, one narrow column alone; and the trace notes which form it
+    took (``plane`` on a TPU, ``columns`` off it)."""
+    from spark_tpu import kernels as K
+    from spark_tpu import tracing
+    from spark_tpu.columnar import ColumnVector
+    from spark_tpu.kernels import take_batch
+    monkeypatch.setattr(K, "_on_tpu_device", lambda: name != "off_tpu")
+    cols, mask, perm, lowering = _take_case(name, np.random.default_rng(34))
+    names = [f"c{i}" for i in range(len(cols))]
+
+    def batch(xp):
+        return ColumnBatch(names, [
+            ColumnVector(xp.asarray(d), dt,
+                         None if v is None else xp.asarray(v), dic)
+            for d, dt, v, dic in cols],
+            None if mask is None else xp.asarray(mask), 1000)
+
+    ref = take_batch(np, batch(np), perm)
+    notes = {}
+    with tracing.collecting(notes):
+        dev = jax.jit(lambda b, p: take_batch(jnp, b, p))(batch(jnp), perm)
+    assert notes["take_lowering"] == [lowering]
+    assert dev.capacity == ref.capacity == len(perm)
+    assert (dev.row_valid is None) == (ref.row_valid is None)
+    if ref.row_valid is not None:
+        assert np.array_equal(np.asarray(dev.row_valid), ref.row_valid)
+    for r, d in zip(ref.vectors, dev.vectors):
+        assert d.dtype == r.dtype and d.dictionary == r.dictionary
+        got = np.asarray(d.data)
+        assert got.dtype == r.data.dtype and got.shape == r.data.shape
+        assert got.tobytes() == r.data.tobytes()
+        assert (d.valid is None) == (r.valid is None)
+        if r.valid is not None:
+            assert np.array_equal(np.asarray(d.valid), r.valid)
 
 
 def test_gather_columns_keeps_every_bit():

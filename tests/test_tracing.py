@@ -356,6 +356,68 @@ def test_agg_lowering_note(streamed):
     assert tracing.last_statement()["notes"]["agg_lowering"] == ["sort.scan"]
 
 
+def test_take_lowering_note(streamed, monkeypatch):
+    """Gathers through one index say how they went: on a TPU ``plane``
+    where the columns of a stage's ``compact`` (several columns, their
+    validity and the row mask) take one index pass together, kept with the
+    stage (the statement that finds its stages compiled shows it too) and
+    carried by ``SQLExecutionEnd``; ``plane`` for a one-int32-column batch
+    too (one word already beats its gather), ``columns`` off a TPU."""
+    from spark_tpu import types as T
+    from spark_tpu.columnar import ColumnBatch, ColumnVector
+    monkeypatch.setattr(K, "_on_tpu_device", lambda: True)
+    text = ("SELECT ss_ticket_number, ss_item_sk, ss_net_paid, ss_quantity "
+            "FROM store_sales WHERE ss_quantity > 77 "
+            "ORDER BY ss_net_paid DESC, ss_ticket_number LIMIT 9")
+    streamed.sql(text).collect()               # its stages are traced here
+    events = []
+    streamed.listenerManager.register(events.append)
+    try:
+        tracing.reset()
+        streamed.sql(text).collect()
+    finally:
+        streamed.listenerManager.unregister(events.append)
+    assert "plane" in tracing.last_statement()["notes"]["take_lowering"]
+    end = [e for e in events if e["event"] == "SQLExecutionEnd"][-1]
+    assert "plane" in end["notes"]["take_lowering"]
+    one = ColumnBatch(["x"], [ColumnVector(
+        jnp.arange(64, dtype=jnp.int32), T.int32, None, None)], None, 64)
+    two = ColumnBatch(["x", "y"], [ColumnVector(
+        jnp.arange(64, dtype=jnp.int32), T.int32, None, None)] * 2, None, 64)
+    for batch, tpu, want in ((one, True, "plane"), (two, True, "plane"),
+                             (one, False, "columns"), (two, False, "columns")):
+        monkeypatch.setattr(K, "_on_tpu_device", lambda: tpu)
+        notes = {}
+        with tracing.collecting(notes):
+            jax.jit(lambda b, p: K.take_batch(jnp, b, p))(
+                batch, jnp.arange(64, dtype=jnp.int32)[::-1])
+        assert notes == {"take_lowering": [want]}
+
+
+def test_gathers_are_counted_by_statement_and_scope():
+    """``python -m spark_tpu.tracing`` counts, for each traced statement,
+    the gather ops under ``take_batch`` and the join's three scopes: a
+    gather by its HLO opcode or by its fusion's root, the innermost of
+    those scopes, nothing for other ops or other scopes."""
+    ev = [(5, "%gather.1 = u32[8,4] gather(...)",
+           "jit(step)/stage.step/compact/take_batch/gather"),
+          (6, "%fusion.2 = s32[4] fusion(...), kind=kCustom",
+           "jit(step)/PJoin#3/join.unique/take_batch/gather:"),
+          (7, "%fusion.3 = s32[4] fusion(...), kind=kCustom",
+           "jit(step)/PJoin#3/join.expand/gather"),
+          (8, "%sort.4 = s32[4] sort(...)", "jit(step)/PJoin#3/join.expand"),
+          (9, "%gather.5 = f64[4] gather(...)",
+           "jit(step)/PAggregate#2/agg.sort/agg.sort.permute/gather"),
+          (25, "%gather.6 = s32[4] gather(...)",
+           "jit(step)/PJoin#4/join.gather/gather"),
+          (99, "%gather.7 = s32[4] gather(...)", "jit(step)/take_batch/gather")]
+    got = tracing.gathers_by_statement(ev, [(0, 10), (20, 10)])
+    assert got == [{"take_batch": 2, "join.unique": 0, "join.expand": 1,
+                    "join.gather": 0},
+                   {"take_batch": 0, "join.unique": 0, "join.expand": 0,
+                    "join.gather": 1}]
+
+
 # -- one clock ---------------------------------------------------------------
 
 def test_annotations_share_the_ring_clock(in_memory, tmp_path):
