@@ -8,6 +8,9 @@ distributed code paths are exercised in-process on N virtual devices.
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+# the compile tests describe a v5e in two xdist workers at once: without this
+# the second to load the TPU library fails on its multi-process lockfile
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
@@ -16,6 +19,7 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 import jax  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 # TPU float64 is emulated (~1e-15 error), which breaks exact dual-path
 # tests: the suite runs on the CPU backend, pinned by JAX_PLATFORMS above.
@@ -63,6 +67,39 @@ def spark():
     s = SparkSession.builder.appName("tests").getOrCreate()
     s.conf.set("spark.tpu.mesh.shards", "1")
     return s
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A TPU v5e 2x2 described, not attached, for the compile tests.  Only
+    a module that asks for it loads the TPU library."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it out
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def on_tpu(monkeypatch):
+    """Steer the engine's device gate to its TPU branch for one test."""
+    monkeypatch.setattr(_kernels, "_on_tpu_device", lambda: True)
+    monkeypatch.setattr(_kernels, "MXU_AGG_ENABLED", None)
 
 
 @pytest.fixture(autouse=True, scope="module")
